@@ -11,9 +11,5 @@ and *where tensors live*:
   for params, inputs and decode state on the ``(data, model)`` (and
   ``(pod, data, model)``) meshes, including the three resident-expert
   serve layouts (``ep2`` / ``ep_data`` / ``etp2``).
-
-:mod:`repro.dist.compat` papers over jax-version API drift (``shard_map``
-location, static axis-size queries) so the same model code runs on the
-pinned CI jax and newer releases.
 """
-from repro.dist import collectives, compat, sharding  # noqa: F401
+from repro.dist import collectives, sharding  # noqa: F401

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.htree import tree_depth
-from repro.dist.compat import axis_size
 
 
 def htree_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
@@ -30,7 +29,7 @@ def htree_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     same way a die with a non-power-of-two plane count pads its H-tree).
     Must be called inside ``shard_map``/``pmap`` with ``axis_name`` bound.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)

@@ -1,19 +1,16 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Shared Pallas/TPU compatibility helpers for the kernel suite."""
+"""Shared Pallas/TPU helpers for the kernel suite."""
 from __future__ import annotations
 
+import jax
 
-def tpu_compiler_params(**kwargs):
-    """Build pltpu compiler params across JAX versions.
 
-    ``pltpu.TPUCompilerParams`` was renamed to ``pltpu.CompilerParams`` in
-    newer JAX releases; the pinned toolchain may carry either name.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` picks the mode from the platform: Mosaic-compiled on the
+    TPU, the Pallas interpreter everywhere else (the CPU test backend).
+    Called at trace time, so a jitted kernel never asks again."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 BLOCK_M = 128
 BLOCK_K = 512
@@ -30,9 +30,10 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot(
-        x_ref[...].astype(jnp.int32), w_ref[...].astype(jnp.int32),
-        preferred_element_type=jnp.int32)
+    # int8 x int8 straight into the MXU (s8 x s8 -> s32); Mosaic rejects a
+    # dot over int32 operands
+    acc_ref[...] += jax.lax.dot(x_ref[...], w_ref[...],
+                                preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
@@ -44,7 +45,7 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
                                              "interpret"))
 def int8_matmul_pallas(x_q, x_s, w_q, w_s, *, bm: int = BLOCK_M,
                        bk: int = BLOCK_K, bn: int = BLOCK_N,
-                       out_dtype=jnp.float32, interpret: bool = True):
+                       out_dtype=jnp.float32, interpret: bool | None = None):
     M, K = x_q.shape
     N = w_q.shape[1]
     n_m, n_n, n_k = pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk)
@@ -61,7 +62,7 @@ def int8_matmul_pallas(x_q, x_s, w_q, w_s, *, bm: int = BLOCK_M,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x_q, w_q, x_s, ws2)

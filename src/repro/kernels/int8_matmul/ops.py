@@ -9,7 +9,8 @@ from repro.kernels.int8_matmul import kernel as K
 
 
 def int8_matmul(x_q: jax.Array, x_s: jax.Array, lin: quant.QuantizedLinear,
-                out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
+                out_dtype=jnp.float32,
+                interpret: bool | None = None) -> jax.Array:
     lead = x_q.shape[:-1]
     Kdim = x_q.shape[-1]
     x2 = x_q.reshape(-1, Kdim)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 # PIM plane-op tile (Size A): 128 rows x 512 cols
 BLOCK_M = 8
@@ -62,7 +62,8 @@ def _kernel(x_ref, hi_ref, lo_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
 def pim_mvm_pallas(x_q: jax.Array, x_s: jax.Array, w_hi: jax.Array,
                    w_lo: jax.Array, w_s: jax.Array, *, bm: int = BLOCK_M,
                    bk: int = BLOCK_K, bn: int = BLOCK_N, bits: int = BITS,
-                   out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
+                   out_dtype=jnp.float32,
+                   interpret: bool | None = None) -> jax.Array:
     """x_q: [M, K] int8; x_s: [M, 1] f32; w_hi/w_lo: [K, N] int8 nibbles;
     w_s: [N] f32  ->  [M, N] out_dtype."""
     M, K = x_q.shape
@@ -82,7 +83,7 @@ def pim_mvm_pallas(x_q: jax.Array, x_s: jax.Array, w_hi: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x_q, w_hi, w_lo, x_s, ws2)

@@ -9,7 +9,8 @@ from repro.kernels.pim_mvm import kernel as K
 
 
 def pim_mvm(x_q: jax.Array, x_s: jax.Array, lin: quant.QuantizedLinear,
-            out_dtype=jnp.float32, interpret: bool = True) -> jax.Array:
+            out_dtype=jnp.float32,
+            interpret: bool | None = None) -> jax.Array:
     """x_q: [..., K] int8 with per-token scales x_s: [..., 1]."""
     lead = x_q.shape[:-1]
     Kdim = x_q.shape[-1]

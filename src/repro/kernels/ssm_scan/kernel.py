@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import resolve_interpret
 
 BLOCK_H = 4          # heads per grid step
 
@@ -59,7 +59,7 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, h_ref,
 
 @functools.partial(jax.jit, static_argnames=("bh", "interpret"))
 def ssd_chunk_pallas(x, B, C, dt, A, D, h_in, *, bh: int = BLOCK_H,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """x: [N,Q,H,dh]; B,C: [N,Q,H,S]; dt: [N,Q,H]; A,D: [H]; h_in: [N,H,dh,S]
     -> (y [N,Q,H,dh], S_out [N,H,dh,S], decay [N,H]).  N = batch*chunks."""
     N, Q, H, dh = x.shape
@@ -88,7 +88,7 @@ def ssd_chunk_pallas(x, B, C, dt, A, D, h_in, *, bh: int = BLOCK_H,
             jax.ShapeDtypeStruct((N, H, dh, S), jnp.float32),
             jax.ShapeDtypeStruct((N, H), jnp.float32),
         ],
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        interpret=resolve_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x, B, C, dt, A, D, h_in)

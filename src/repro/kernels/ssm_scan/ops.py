@@ -12,7 +12,7 @@ from repro.kernels.ssm_scan.kernel import ssd_chunk_pallas
 
 
 def ssd_forward(x, B, C, dt, A, D, *, chunk: int = 128, h0=None,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """x: [Bt,T,H,dh]; B,C: [Bt,T,H,S]; dt: [Bt,T,H]; A,D: [H].
     Returns (y [Bt,T,H,dh], h_last [Bt,H,dh,S])."""
     Bt, T, H, dh = x.shape

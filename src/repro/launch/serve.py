@@ -63,6 +63,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.models.transformer import Runtime
@@ -80,6 +81,17 @@ def make_serve_runtime(spec: str | None) -> Runtime:
     except ValueError as e:
         raise SystemExit(str(e))
     return Runtime(mesh=mesh, data_axes=("data",), serve_resident_moe=True)
+
+
+def _exit_on_failures(reqs) -> None:
+    """The engine isolates a failed request (compile error, HBM OOM at
+    admission) and keeps serving the rest; the CLI must not report such a
+    run as a success."""
+    failed = [r for r in reqs if r.error is not None]
+    if failed:
+        for r in failed:
+            print(f"request {r.rid} failed: {r.error}")
+        raise SystemExit(f"{len(failed)}/{len(reqs)} requests failed")
 
 
 def _run_fixed(cfg, params, args):
@@ -248,12 +260,14 @@ def _run_continuous(cfg, params, args):
           f"device {1e3 * eng.stats['device_s'] / steps:.2f} ms/step  "
           f"decode xfer {eng.stats['decode_xfer_bytes'] / max(1, eng.stats['decode_steps']):.0f} B/decode-step")
     print("sample tokens:", reqs[0].output[:10])
+    _exit_on_failures(reqs)
 
 
 def _run_serve(cfg, params, args):
     """Async streaming demo: submit ``--requests`` live, stream them
     concurrently, cancel the second one after its first two tokens, and
     shut down cleanly.  Doubles as the CI smoke for the serve loop."""
+    from repro.serve.engine import RequestFailedError
     from repro.serve.server import AsyncServer, RequestTimedOut
 
     rng = np.random.default_rng(0)
@@ -290,8 +304,8 @@ def _run_serve(cfg, params, args):
                 toks.append(tok)
                 if i == cancel_at and len(toks) >= 2:
                     stream.cancel()
-        except RequestTimedOut:
-            pass                      # deadline hit; partial tokens stand
+        except (RequestTimedOut, RequestFailedError):
+            pass                      # partial tokens stand; judged below
         return toks
 
     async def demo():
@@ -318,6 +332,7 @@ def _run_serve(cfg, params, args):
     _print_prefix_stats(eng)
     _print_swap_stats(eng)
     _print_fault_stats(eng)
+    _exit_on_failures([s.request for s in streams])
     assert all(s.request.done for s in streams)
     assert not eng.scheduler.has_work() and not eng._carries
     if cancel_at is not None:
@@ -424,6 +439,7 @@ def main():
                     help='serve over a (data, model) mesh, e.g. "2x4"')
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = registry.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -160,8 +160,7 @@ def moe_apply(p: Params, x: jax.Array, cfg: ModelConfig,
     if axis_name is None:
         out, aux = moe_local(p, xf, cfg)
     else:
-        from repro.dist.compat import axis_size
-        ax = axis_size(axis_name)
+        ax = jax.lax.axis_size(axis_name)
         idx = jax.lax.axis_index(axis_name)
         if ep_capable(cfg, ax):
             n_local = cfg.n_experts // ax

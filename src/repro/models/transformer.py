@@ -171,13 +171,9 @@ def _moe_block(p: Params, x: jax.Array, cfg: ModelConfig, rt: Runtime):
             aux = jax.lax.pmean(aux, tuple(rt.mesh.axis_names))
             return out, aux
 
-    out, aux = _shard_map(f, rt.mesh, (pspec, x_spec), (x_spec, P()))(p, x)
+    out, aux = jax.shard_map(f, mesh=rt.mesh, in_specs=(pspec, x_spec),
+                             out_specs=(x_spec, P()), check_vma=False)(p, x)
     return out, aux
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    from repro.dist.compat import shard_map
-    return shard_map(f, mesh, in_specs, out_specs)
 
 
 def apply_layer_train(p: Params, cfg: ModelConfig, slot: int, x, positions,
@@ -214,13 +210,14 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     groups = []
     for gi, (start, count, period) in enumerate(layer_groups(cfg)):
         gkeys = jax.random.split(ks[2 + gi], count)
-        n_p = count // period
-        slots = []
-        for s in range(period):
-            slots.append(tree_stack(
-                [init_layer(gkeys[pi * period + s], cfg, start + pi * period + s, dtype)
-                 for pi in range(n_p)]))
-        groups.append(tuple(slots))
+        # one batched init per period slot builds the stacked [n_p, ...]
+        # leaves directly — no per-layer copies held for a stack (every
+        # layer of a slot shares its structure, see layer_groups)
+        slots = tuple(
+            jax.vmap(lambda k, s=s: init_layer(k, cfg, start + s, dtype))(
+                gkeys[s::period])
+            for s in range(period))
+        groups.append(slots)
     p["groups"] = tuple(groups)
     if cfg.mtp:
         p["mtp_proj"] = L.dense_init(ks[6], 2 * cfg.d_model, cfg.d_model, dtype)

@@ -7,6 +7,7 @@ SSM B/C/dt, embeddings) stay in floating point.  2-D linears become
 pim_bitserial backends); 3-D expert stacks become weight-only int8."""
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -23,16 +24,38 @@ _SMVM_3D = {"w_up", "w_gate", "w_down"}
 _KEEP = {"router", "w_B", "w_C", "w_dt", "conv_x", "conv_B", "conv_C"}
 
 
+# Each [..., in, out] leaf quantizes per output channel through two jitted
+# passes, so the f32 temporaries fuse away instead of materialising (a
+# stacked [L, 3072, 8192] leaf would otherwise hold several 3 GB f32 copies
+# at once).  The [..., out] scale between them is computed eagerly: under
+# jit XLA turns the division by 127 into an inexact reciprocal multiply,
+# and the scales must stay the eager path's bit for bit.
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _channel_amax(w: jax.Array, dtype) -> jax.Array:
+    return jnp.max(jnp.abs(w.astype(dtype)), axis=-2)
+
+
+@jax.jit
+def _to_int8(w: jax.Array, scale: jax.Array) -> jax.Array:
+    q = jnp.round(w.astype(scale.dtype) / scale[..., None, :])
+    return jnp.clip(q, -127, 127).astype(jnp.int8)
+
+
+def _quantize(w: jax.Array, dtype):
+    scale = jnp.maximum(_channel_amax(w, dtype), 1e-8) / quant.INT8_MAX
+    return _to_int8(w, scale), scale.astype(jnp.float32)
+
+
 def _quantize_2d(w: jax.Array):
-    lin = quant.make_quantized_linear(w.astype(jnp.float32))
-    return lin.w_q, lin.w_scale
+    """W8A8 linear (or a layer stack of them): f32 scales, as
+    :func:`repro.core.quant.make_quantized_linear`."""
+    return _quantize(w, jnp.float32)
 
 
 def _quantize_3d(w: jax.Array):
-    amax = jnp.max(jnp.abs(w), axis=1)                      # [E, out]
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(w / scale[:, None, :]), -127, 127).astype(jnp.int8)
-    return q, scale.astype(jnp.float32)
+    """Weight-only int8 expert stack ``[..., E, in, out]``, scaled in the
+    weights' own dtype."""
+    return _quantize(w, w.dtype)
 
 
 def quantize_tree(params: Any, quantize_embed: bool = False) -> Any:
@@ -63,14 +86,14 @@ def quantize_tree(params: Any, quantize_embed: bool = False) -> Any:
                 if "moe" in path:
                     q, s = _quantize_3d(v)
                 else:
-                    q, s = jax.vmap(_quantize_2d)(v)
+                    q, s = _quantize_2d(v)
                 out[k + "_q"], out[k + "_s"] = q, s
             elif hasattr(v, "ndim") and v.ndim == 4 and k in _SMVM_3D and "moe" in path:
                 # stacked-over-layers expert stack [L, E, in, out]
-                q, s = jax.vmap(_quantize_3d)(v)
+                q, s = _quantize_3d(v)
                 out[k + "_q"], out[k + "_s"] = q, s
             elif hasattr(v, "ndim") and v.ndim == 3 and k in _SMVM_2D:
-                q, s = jax.vmap(_quantize_2d)(v)            # [L, in, out]
+                q, s = _quantize_2d(v)                      # [L, in, out]
                 out[k + "_q"], out[k + "_s"] = q, s
             elif hasattr(v, "ndim") and v.ndim == 2 and k in _SMVM_2D and k != "w":
                 q, s = _quantize_2d(v)

@@ -14,11 +14,25 @@ ART = ROOT / "artifacts" / "dryrun"
 MANIFEST = ART / "quick_manifest.json"
 
 
+# every snippet gets the repo's Auto-axis make_mesh and a shard_map with
+# replication checking off (the collectives under test are hand-written)
+_PRELUDE = """\
+import jax
+from repro.launch.mesh import make_mesh
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+"""
+
+
 def _run_with_devices(n: int, code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = str(ROOT / "src")
-    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    r = subprocess.run([sys.executable, "-c",
+                        _PRELUDE + textwrap.dedent(code)],
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     return r.stdout
@@ -30,8 +44,7 @@ class TestCollectives:
             import jax, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
             from repro.dist.collectives import htree_allreduce
-            from repro.dist.compat import shard_map
-            mesh = jax.make_mesh((8,), ("model",))
+            mesh = make_mesh((8,), ("model",))
             x = jnp.arange(32.0).reshape(8, 4)
             def f(x):
                 return htree_allreduce(x, "model")
@@ -56,7 +69,7 @@ class TestCollectives:
             p = MoE.moe_init(jax.random.key(0), cfg)
             x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model))
             ref, _ = MoE.moe_apply(p, x, cfg, axis_name=None)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rt = Runtime(mesh=mesh, data_axes=("data",))
             got, _ = jax.jit(lambda pp, xx: _moe_block(pp, xx, cfg, rt))(p, x)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -85,7 +98,7 @@ class TestCollectives:
             s0 = jax.jit(make_train_step(cfg, Runtime(), opt))
             p0, _, m0 = s0(params, opt.init(params), batch)
             # 2x4 mesh with real shardings
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rt = Runtime(mesh=mesh, data_axes=("data",))
             psh = SH.param_shardings(cfg, jax.eval_shape(lambda: params), mesh)
             params_sharded = jax.device_put(params, psh)
@@ -111,9 +124,8 @@ class TestHtreeProperty:
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
             from repro.dist.collectives import htree_allreduce
-            from repro.dist.compat import shard_map
             n = {n}
-            mesh = jax.make_mesh((n,), ("model",))
+            mesh = make_mesh((n,), ("model",))
             for shape in [(n, 7), (n, 5, 3), (n, 1), (n, 2, 3, 5)]:
                 x = (jax.random.normal(jax.random.key(shape[-1]), shape)
                      * 100.0).astype(jnp.float32)
@@ -139,7 +151,6 @@ class TestHtreeProperty:
             from jax.sharding import Mesh, PartitionSpec as P
             from repro.core.htree import tree_depth
             from repro.dist.collectives import htree_allreduce
-            from repro.dist.compat import shard_map
             for n in (2, 3, 5, 6, 8):
                 mesh = Mesh(np.asarray(jax.devices()[:n]), ("model",))
                 f = shard_map(lambda v: htree_allreduce(v, "model"),
@@ -242,7 +253,7 @@ class TestResidentMoE:
             p = MoE.moe_init(jax.random.key(0), cfg)
             x = jax.random.normal(jax.random.key(1), (8, 4, cfg.d_model))
             ref, _ = MoE.moe_apply(p, x, cfg, axis_name=None)
-            mesh = jax.make_mesh({mesh_shape}, {axes})
+            mesh = make_mesh({mesh_shape}, {axes})
             strat = SH.moe_serve_strategy(cfg, mesh)
             rt = Runtime(mesh=mesh, data_axes=("data",),
                          serve_resident_moe=True)
@@ -268,7 +279,7 @@ class TestResidentMoE:
             ref, _ = MoE.moe_apply(p, x, cfg, axis_name=None)
             seen = set()
             for mesh_shape in [(2, 4), (8, 1), (2, 2)]:
-                mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+                mesh = make_mesh(mesh_shape, ("data", "model"))
                 seen.add(SH.moe_serve_strategy(cfg, mesh))
                 for coll in ("psum", "htree"):
                     rt = Runtime(mesh=mesh, data_axes=("data",),
@@ -309,7 +320,7 @@ class TestShardedServe:
                 ref = ContinuousBatchingEngine(
                     cfg, params, n_slots=4, max_len=48,
                     quantize=quantize).generate_all(prompts, budgets)
-                mesh = jax.make_mesh((2, 4), ("data", "model"))
+                mesh = make_mesh((2, 4), ("data", "model"))
                 rt = Runtime(mesh=mesh, data_axes=("data",),
                              serve_resident_moe=True)
                 got = ContinuousBatchingEngine(
@@ -343,7 +354,7 @@ class TestShardedServe:
             ref = ContinuousBatchingEngine(
                 cfg, params, n_slots=2, max_len=32, chunk=4,
                 policy="fair:3").generate_all(prompts, budgets)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rt = Runtime(mesh=mesh, data_axes=("data",),
                          serve_resident_moe=True)
             eng = ContinuousBatchingEngine(
@@ -383,7 +394,7 @@ class TestShardedServe:
                 ref = ContinuousBatchingEngine(
                     cfg, params, n_slots=4, max_len=32,
                     quantize=quantize).generate_all(prompts, budgets)
-                mesh = jax.make_mesh((2, 4), ("data", "model"))
+                mesh = make_mesh((2, 4), ("data", "model"))
                 rt = Runtime(mesh=mesh, data_axes=("data",),
                              serve_resident_moe=True)
                 eng = ContinuousBatchingEngine(
@@ -426,7 +437,7 @@ class TestShardedServe:
                 ref = ContinuousBatchingEngine(
                     cfg, params, n_slots=4, max_len=32,
                     quantize=quantize).generate_all(prompts, budgets)
-                mesh = jax.make_mesh((2, 4), ("data", "model"))
+                mesh = make_mesh((2, 4), ("data", "model"))
                 rt = Runtime(mesh=mesh, data_axes=("data",),
                              serve_resident_moe=True)
                 eng = ContinuousBatchingEngine(
@@ -466,7 +477,7 @@ class TestShardedServe:
             ref = ContinuousBatchingEngine(
                 cfg, params, n_slots=4,
                 max_len=32).generate_all(prompts, budgets)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rt = Runtime(mesh=mesh, data_axes=("data",),
                          serve_resident_moe=True)
             for chunk in (None, 4):
@@ -506,7 +517,7 @@ class TestShardedServe:
                 ref = ContinuousBatchingEngine(
                     cfg, params, n_slots=4, max_len=32,
                     quantize=quantize).generate_all(prompts, budgets)
-                mesh = jax.make_mesh((2, 4), ("data", "model"))
+                mesh = make_mesh((2, 4), ("data", "model"))
                 rt = Runtime(mesh=mesh, data_axes=("data",),
                              serve_resident_moe=True)
                 eng = ContinuousBatchingEngine(
@@ -544,7 +555,7 @@ class TestShardedServe:
             ref = ContinuousBatchingEngine(
                 cfg, params, n_slots=2, max_len=48,
                 chunk=4).generate_all(prompts, [6] * 4)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rt = Runtime(mesh=mesh, data_axes=("data",),
                          serve_resident_moe=True)
             eng = ContinuousBatchingEngine(
@@ -582,7 +593,7 @@ class TestShardedServe:
             ref = ContinuousBatchingEngine(
                 cfg, params, n_slots=2, max_len=48,
                 chunk=4).generate_all(prompts, [8] * 4)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             rt = Runtime(mesh=mesh, data_axes=("data",),
                          serve_resident_moe=True)
             eng = ContinuousBatchingEngine(
@@ -622,7 +633,7 @@ class TestMeshRope:
             for arch in ("llama3-8b", "deepseek-v3-671b"):
                 cfg = ARCHS[arch].reduced()
                 params = M.init_params(jax.random.key(0), cfg)
-                mesh = jax.make_mesh((2, 4), ("data", "model"))
+                mesh = make_mesh((2, 4), ("data", "model"))
                 rt = Runtime(mesh=mesh, data_axes=("data",),
                              serve_resident_moe=True)
                 params_m = jax.device_put(params, SH.param_shardings(
@@ -675,7 +686,7 @@ class TestMeshRope:
                 ref = ContinuousBatchingEngine(
                     cfg, params, n_slots=4, max_len=32,
                     quantize=quantize).generate_all(prompts, budgets)
-                mesh = jax.make_mesh((2, 4), ("data", "model"))
+                mesh = make_mesh((2, 4), ("data", "model"))
                 rt = Runtime(mesh=mesh, data_axes=("data",),
                              serve_resident_moe=True)
                 for chunk in (None, 4):

@@ -48,6 +48,12 @@ def _trace(cfg, n=6, seed=11):
     budgets = [int(b) for b in rng.integers(2, 9, size=n)]
     return prompts, budgets
 
+def _eos_at(full, start=2):
+    """First index >= ``start`` whose token has not appeared earlier in
+    ``full``: an eos there stops the plain engine exactly at that index."""
+    return next(i for i in range(start, len(full)) if full[i] not in full[:i])
+
+
 
 # ---------------------------------------------------------------------------
 # model level
@@ -152,12 +158,13 @@ class TestEngineMultiStepParity:
                 [prompts[0]], [8])[0]
         eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=32,
                                        multi_step=4)
-        r_eos = eng.submit(prompts[0], 8, eos_id=full[2])
+        i = _eos_at(full)
+        r_eos = eng.submit(prompts[0], 8, eos_id=full[i])
         eng.drain()                     # queue must be empty for fusion
         assert eng.stats["multi_blocks"] > 0
         r_next = eng.submit(list(reversed(prompts[0])), 3)
         eng.drain()
-        assert r_eos.output == full[:3]
+        assert r_eos.output == full[:i + 1]
         assert len(r_next.output) == 3
 
     def test_budget_overshoot_unwound(self, gqa_setup):

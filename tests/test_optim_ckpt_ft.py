@@ -14,6 +14,7 @@ from repro.data.pipeline import SyntheticTokens
 from repro.configs.shapes import ShapeConfig
 from repro.ft import compress as FC
 from repro.ft.failures import FailureInjector, ResilientRunner, StragglerWatchdog
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.transformer import Runtime
 from repro.optim.adamw import AdamW
@@ -117,7 +118,7 @@ class TestCheckpoint:
         from jax.sharding import NamedSharding, PartitionSpec as P
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         C.save(tmp_path, 1, tree)
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         sh = {"w": NamedSharding(mesh, P(None, None))}
         got, _ = C.restore(tmp_path, tree, shardings=sh)
         np.testing.assert_array_equal(np.asarray(got["w"]), np.asarray(tree["w"]))

@@ -124,6 +124,12 @@ def _trace(cfg, n=6, seed=11):
     budgets = [int(b) for b in rng.integers(2, 9, size=n)]
     return prompts, budgets
 
+def _eos_at(full, start=2):
+    """First index >= ``start`` whose token has not appeared earlier in
+    ``full``: an eos there stops the plain engine exactly at that index."""
+    return next(i for i in range(start, len(full)) if full[i] not in full[:i])
+
+
 
 class TestVerifyStep:
     @pytest.mark.parametrize("arch", ["llama3-8b", "deepseek-v3-671b"])
@@ -406,10 +412,11 @@ class TestSpecParity:
         eng = ContinuousBatchingEngine(
             cfg, params, n_slots=1, max_len=32, spec_k=4,
             drafter=_OracleDrafter([(prompts[0], full)]))
-        r_eos = eng.submit(prompts[0], 8, eos_id=full[2])
+        i = _eos_at(full)
+        r_eos = eng.submit(prompts[0], 8, eos_id=full[i])
         r_next = eng.submit(list(reversed(prompts[0])), 3)
         eng.drain()
-        assert r_eos.output == full[:3]
+        assert r_eos.output == full[:i + 1]
         assert len(r_next.output) == 3
 
     def test_spec_k_ignored_for_ssm(self):
@@ -588,10 +595,11 @@ class TestTreeSpecParity:
         eng = ContinuousBatchingEngine(
             cfg, params, n_slots=1, max_len=32, spec_tree=4,
             drafter=_OracleDrafter([(prompts[0], full)]))
-        r_eos = eng.submit(prompts[0], 8, eos_id=full[2])
+        i = _eos_at(full)
+        r_eos = eng.submit(prompts[0], 8, eos_id=full[i])
         r_next = eng.submit(list(reversed(prompts[0])), 3)
         eng.drain()
-        assert r_eos.output == full[:3]
+        assert r_eos.output == full[:i + 1]
         assert len(r_next.output) == 3
 
     def test_spec_tree_ignored_for_ssm(self):

@@ -1,5 +1,8 @@
 """End-to-end behaviour tests for the paper's system: the full offload
 pipeline (prefill -> KV handoff -> quantized decode) on a small model."""
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,3 +88,50 @@ class TestEncDecServing:
         toks, times = eng.generate(batch, steps=6)
         assert toks.shape == (2, 6)
         assert bool((toks >= 0).all()) and bool((toks < cfg.vocab_size).all())
+
+
+class TestServeCli:
+    """``repro.launch.serve`` must not report a run in which a request
+    failed as a success: the engine isolates the failure and keeps serving
+    the other requests, so only the exit code tells."""
+
+    @pytest.mark.parametrize("mode", ["--continuous", "--serve"])
+    def test_failed_admission_exits_nonzero(self, monkeypatch, capsys, mode):
+        from repro.launch import serve
+        real, calls = M.prefill, []
+
+        def flaky_prefill(*args, **kwargs):
+            calls.append(1)
+            if len(calls) == 1:
+                raise RuntimeError("injected admission failure")
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(M, "prefill", flaky_prefill)
+        monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+        monkeypatch.setattr(sys, "argv", [
+            "serve", "--arch", "llama3-8b", "--reduced", mode,
+            "--requests", "3", "--slots", "2", "--prompt-len", "8",
+            "--steps", "4"])
+        with pytest.raises(SystemExit) as exc:
+            serve.main()
+        assert exc.value.code not in (None, 0)
+        assert "injected admission failure" in capsys.readouterr().out
+
+
+class TestCompileCache:
+    def test_placement(self, monkeypatch, tmp_path):
+        """``JAX_COMPILATION_CACHE_DIR`` wins untouched; without it the
+        cache sits at the checkout's fixed ``.jax_cache``."""
+        from repro.launch import compile_cache as CC
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert CC.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        try:
+            path = CC.enable_compile_cache()
+            assert path == str(Path(__file__).resolve().parents[1]
+                               / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
