@@ -28,9 +28,11 @@ With ``--multi-step 1,2,4`` the sweep also covers the fused multi-step
 decode lane (m greedy iterations per jitted call, argmax fed back on
 device): the speedup column for an ``m>1`` record is relative to the same
 policy's (k=0, m=1) baseline.  Every record carries the per-iteration
-host/device wall-time breakdown (``host_ms`` / ``device_ms``) and the
-per-decode-step host transfer volume (``xfer_bytes``) — the transfer-
-discipline trajectory (O(slots*m) greedy, O(slots*k) sampled).
+host-clock breakdown (``host_ms``: the engine's own host work, dispatch
+included; ``dispatch_ms``: enqueueing jitted programs; ``wait_ms``: blocked
+on device results) and the per-decode-step host transfer volume
+(``xfer_bytes``) — the transfer-discipline trajectory (O(slots*m) greedy,
+O(slots*k) sampled).  Device time itself comes from a profiler trace.
 
 ``--serve`` swaps the in-process replay for the *live* async front-end:
 per-request coroutines sleep until their Poisson arrival and submit to a
@@ -331,11 +333,14 @@ def summarize(policy, eng, reqs, wall):
         # eng.multi_step (like eng.spec_k): 1 for SSM stacks
         "multi_step": eng.multi_step,
         "multi_blocks": eng.stats["multi_blocks"],
-        # per-iteration host/device wall breakdown + per-decode-step host
-        # transfer volume — the device-resident-lane trajectory metrics
-        "host_ms": 1e3 * (eng.stats["step_s"] - eng.stats["device_s"])
+        # per-iteration host-clock breakdown (engine host work, of it
+        # dispatch; blocked on the device) + per-decode-step host transfer
+        # volume — the device-resident-lane trajectory metrics
+        "host_ms": 1e3 * (eng.stats["step_s"] - eng.stats["wait_s"])
         / max(1, eng.stats["steps"]),
-        "device_ms": 1e3 * eng.stats["device_s"] / max(1, eng.stats["steps"]),
+        "dispatch_ms": 1e3 * eng.stats["dispatch_s"]
+        / max(1, eng.stats["steps"]),
+        "wait_ms": 1e3 * eng.stats["wait_s"] / max(1, eng.stats["steps"]),
         "xfer_bytes": eng.stats["decode_xfer_bytes"]
         / max(1, eng.stats["decode_steps"]),
         "xfer_bytes_total": eng.stats["xfer_bytes"],
@@ -376,12 +381,14 @@ COLS = [("policy", "%-16s"), ("spec_k", "%6d"), ("spec_tree", "%5d"),
         ("latency_p99_ms", "%9.1f"), ("queue_delay_p50_ms", "%9.1f"),
         ("queue_delay_p99_ms", "%9.1f"), ("preemptions", "%5d"),
         ("max_step_prefill_tokens", "%11d"),
-        ("host_ms", "%8.2f"), ("device_ms", "%8.2f"), ("xfer_bytes", "%7.0f"),
+        ("host_ms", "%8.2f"), ("dispatch_ms", "%8.2f"), ("wait_ms", "%8.2f"),
+        ("xfer_bytes", "%7.0f"),
         ("acceptance_rate", "%7.2f"), ("tpot_speedup", "%8.2f"),
         ("tpot_speedup_vs_linear", "%8.2f")]
 HEAD = ("policy            spec_k   tree  mstep     tok/s  ttft-p50  "
         "ttft-p99  tpot-p50  tpot-p99   lat-p99  qdel-p50  qdel-p99  prmpt  "
-        "max_pf/step   host_ms   dev_ms  xfer_B   accept  speedup   vs-lin")
+        "max_pf/step   host_ms  disp_ms  wait_ms  xfer_B   accept  speedup   "
+        "vs-lin")
 # appended only when --prefix-cache is on (fields are absent otherwise)
 PREFIX_COLS = [("prefix_hits", "%6d"), ("prefill_tokens_saved", "%8d")]
 PREFIX_HEAD = "  pfhits   pfsaved"

@@ -173,13 +173,17 @@ class MTPDrafter(Drafter):
                 "use the ngram drafter")
         from repro.models import model as M
         from repro.models import transformer as T
-        self._fn = jax.jit(
-            lambda p, h, t, pos: M.mtp_draft(p, cfg, h, t, pos, k, rt))
+
+        def mtp_draft(p, h, t, pos):
+            return M.mtp_draft(p, cfg, h, t, pos, k, rt)
+
+        self._fn = jax.jit(mtp_draft)
         self.tree_parents: list[int] | None = None
         if tree_branch is not None:
-            self._tree_fn = jax.jit(
-                lambda p, h, t, pos: M.mtp_draft_tree(p, cfg, h, t, pos, k,
-                                                      tree_branch, rt))
+            def mtp_draft_tree(p, h, t, pos):
+                return M.mtp_draft_tree(p, cfg, h, t, pos, k, tree_branch, rt)
+
+            self._tree_fn = jax.jit(mtp_draft_tree)
             parents = []
             for clen in T.mtp_chain_lengths(k, tree_branch):
                 prev = -1

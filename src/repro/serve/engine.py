@@ -61,11 +61,13 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from types import SimpleNamespace
 from typing import Any, Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import ShapeConfig
@@ -121,13 +123,17 @@ class Engine:
         if self.rt.mesh is not None:
             self.params, self.qparams, _ = _place_on_mesh(
                 self.cfg, self.params, self.qparams, self.rt)
-        self._prefill = jax.jit(
-            lambda p, b: M.prefill(p, self.cfg, b, self.max_len, self.rt))
+
+        def prefill(p, b):
+            return M.prefill(p, self.cfg, b, self.max_len, self.rt)
+
+        def decode_step(p, s, t):
+            return M.decode_step(p, self.cfg, s, t, self.rt)
+
+        self._prefill = jax.jit(prefill)
         # the decode state is donated: each step's int8 SLC pool updates in
         # place instead of being copied per token (the caller reassigns)
-        self._decode = jax.jit(
-            lambda p, s, t: M.decode_step(p, self.cfg, s, t, self.rt),
-            donate_argnums=(1,))
+        self._decode = jax.jit(decode_step, donate_argnums=(1,))
 
     def generate(self, batch: dict, steps: int, greedy: bool = True,
                  rng: jax.Array | None = None):
@@ -370,7 +376,16 @@ class ContinuousBatchingEngine:
                       "verify_steps": 0, "spec_drafted": 0,
                       "spec_accepted": 0, "multi_blocks": 0,
                       "multi_tokens": 0, "xfer_bytes": 0,
-                      "decode_xfer_bytes": 0, "device_s": 0.0, "step_s": 0.0,
+                      "decode_xfer_bytes": 0, "step_s": 0.0,
+                      # host seconds at the step's boundaries, each beside
+                      # its profiler span: enqueueing jitted programs
+                      # (engine.dispatch), blocked on device results
+                      # (engine.fetch; decode_wait_s the decode lanes'
+                      # share), admission work incl. swap restores
+                      # (engine.prefill); prefills counts the prefills
+                      # that reached their first token
+                      "dispatch_s": 0.0, "wait_s": 0.0, "decode_wait_s": 0.0,
+                      "prefill_s": 0.0, "prefills": 0,
                       # recovery machinery is always armed (a donated step
                       # can genuinely fail with no injector), so these
                       # counters always exist
@@ -416,20 +431,15 @@ class ContinuousBatchingEngine:
         # prefill's float carry) update in place instead of being copied
         # per call.  Each call site reassigns the engine's reference, so
         # the donated (deleted) buffer is never touched again.
-        self._prefill = jax.jit(
-            lambda p, b: M.prefill(p, cfg, b, max_len, self.rt))
+        fn = self._step_fns()
+        self._prefill = jax.jit(fn.prefill)
         if self.chunk:
             # a fresh carry per admission: donation consumes the previous
             # one, so a shared zero template would die on first use
-            self._carry_init = jax.jit(
-                lambda: M.init_prefill_carry(cfg, max_len + self.chunk))
-            self._chunk_fn = jax.jit(
-                lambda p, c, t, n: M.prefill_chunk(p, cfg, c, t, n, self.rt),
-                donate_argnums=(1,))
-            self._finalize_write = jax.jit(
-                lambda s, slot, c: T.write_slot(
-                    s, slot, M.finalize_prefill_carry(cfg, c, max_len)),
-                donate_argnums=(0,))
+            self._carry_init = jax.jit(fn.init_prefill_carry)
+            self._chunk_fn = jax.jit(fn.prefill_chunk, donate_argnums=(1,))
+            self._finalize_write = jax.jit(fn.finalize_write,
+                                           donate_argnums=(0,))
         if self._pcache is not None:
             # warm admission pair: the row gather copies the matched leaf's
             # rows into the new slot (donated pool, in-place), and the warm
@@ -437,9 +447,7 @@ class ContinuousBatchingEngine:
             # prefill resumes at the cached cursor.  The carry read is NOT
             # donated — the pool stays live for the step's other slots.
             self._gather = jax.jit(T.copy_slot_prefix, donate_argnums=(0,))
-            self._warm_carry = jax.jit(
-                lambda s, slot, n: M.warm_prefill_carry(
-                    cfg, s, slot, n, max_len + self.chunk))
+            self._warm_carry = jax.jit(fn.warm_prefill_carry)
         if self.spec_k or self.spec_tree:
             # the tree lane takes precedence over the linear lane, so the
             # drafter's budget is whichever window actually runs
@@ -450,32 +458,64 @@ class ContinuousBatchingEngine:
             self._h_last = (np.zeros((n_slots, cfg.d_model), np.float32)
                             if self._drafter.kind == "model" else None)
         if self.spec_k and not self.spec_tree:
-            self._verify = jax.jit(
-                lambda p, s, t: M.verify_step(p, cfg, s, t, self.rt),
-                donate_argnums=(1,))
+            self._verify = jax.jit(fn.verify_step, donate_argnums=(1,))
         if self.spec_tree:
-            self._verify_tree = jax.jit(
-                lambda p, s, t, dep, a: M.verify_step(
-                    p, cfg, s, t, self.rt, depth=dep, anc=a),
-                donate_argnums=(1,))
+            self._verify_tree = jax.jit(fn.verify_tree, donate_argnums=(1,))
             self._tree_commit = jax.jit(M.tree_commit, donate_argnums=(0,))
         if self.multi_step > 1:
-            self._multi = jax.jit(
-                lambda p, s, t: M.multi_decode_step(
-                    p, cfg, s, t, self.multi_step, self.rt),
-                donate_argnums=(1,))
+            self._multi = jax.jit(fn.multi_decode_step, donate_argnums=(1,))
         if self.rt.mesh is None:
-            self._decode = jax.jit(
-                lambda p, s, t: M.decode_step(p, cfg, s, t, self.rt),
-                donate_argnums=(1,))
+            self._decode = jax.jit(fn.decode_step, donate_argnums=(1,))
             self._write = jax.jit(T.write_slot, donate_argnums=(0,))
             if self._swap is not None:
                 self._read_slot = jax.jit(T.read_slot)
         else:
-            self._shard_over_mesh()
+            self._shard_over_mesh(fn)
+
+    def _step_fns(self) -> SimpleNamespace:
+        """The serve path's jitted step functions, each a named ``def`` so
+        that its compiled module, and the device trace, read
+        ``jit_<name>``.  The slot row moves (``T.write_slot``,
+        ``T.read_slot``, ``T.copy_slot_prefix``) and ``M.tree_commit`` are
+        jitted under their own names."""
+        cfg, rt, max_len = self.cfg, self.rt, self.max_len
+
+        def prefill(p, b):
+            return M.prefill(p, cfg, b, max_len, rt)
+
+        def init_prefill_carry():
+            return M.init_prefill_carry(cfg, max_len + self.chunk)
+
+        def prefill_chunk(p, c, t, n):
+            return M.prefill_chunk(p, cfg, c, t, n, rt)
+
+        def finalize_write(s, slot, c):
+            return T.write_slot(s, slot,
+                                M.finalize_prefill_carry(cfg, c, max_len))
+
+        def warm_prefill_carry(s, slot, n):
+            return M.warm_prefill_carry(cfg, s, slot, n,
+                                        max_len + self.chunk)
+
+        def decode_step(p, s, t):
+            return M.decode_step(p, cfg, s, t, rt)
+
+        def multi_decode_step(p, s, t):
+            return M.multi_decode_step(p, cfg, s, t, self.multi_step, rt)
+
+        def verify_step(p, s, t):
+            return M.verify_step(p, cfg, s, t, rt)
+
+        def verify_tree(p, s, t, dep, a):
+            return M.verify_step(p, cfg, s, t, rt, depth=dep, anc=a)
+
+        return SimpleNamespace(**{f.__name__: f for f in (
+            prefill, init_prefill_carry, prefill_chunk, finalize_write,
+            warm_prefill_carry, decode_step, multi_decode_step, verify_step,
+            verify_tree)})
 
     # -- sharded-serve path -----------------------------------------------
-    def _shard_over_mesh(self) -> None:
+    def _shard_over_mesh(self, fn: SimpleNamespace) -> None:
         """Place params, QLC weights and the slot pool on ``rt.mesh`` and
         pin every serve step's in/out shardings to the pool layout.
 
@@ -507,13 +547,12 @@ class ContinuousBatchingEngine:
                 out_shardings=rsh["row"])
             self._io["swap_row"] = rsh["row"]
         self._decode = jax.jit(
-            lambda p, s, t: M.decode_step(p, cfg, s, t, self.rt),
+            fn.decode_step,
             in_shardings=(qsh, ssh, self._io["tokens"]),
             out_shardings=(self._io["logits"], ssh), donate_argnums=(1,))
         if self.multi_step > 1:
             self._multi = jax.jit(
-                lambda p, s, t: M.multi_decode_step(
-                    p, cfg, s, t, self.multi_step, self.rt),
+                fn.multi_decode_step,
                 in_shardings=(qsh, ssh, self._io["tokens"]),
                 out_shardings=(self._io["block"], ssh), donate_argnums=(1,))
         if self.spec_k or self.spec_tree:
@@ -523,7 +562,7 @@ class ContinuousBatchingEngine:
             self._io["verify_tokens"] = vsh["tokens"]
         if self.spec_k and not self.spec_tree:
             self._verify = jax.jit(
-                lambda p, s, t: M.verify_step(p, cfg, s, t, self.rt),
+                fn.verify_step,
                 in_shardings=(qsh, ssh, vsh["tokens"]),
                 out_shardings=(vsh["logits"], vsh["hidden"], ssh),
                 donate_argnums=(1,))
@@ -535,8 +574,7 @@ class ContinuousBatchingEngine:
             self._io["tree_window"] = tsh["window"]
             self._io["tree_commit"] = tsh["commit"]
             self._verify_tree = jax.jit(
-                lambda p, s, t, dep, a: M.verify_step(
-                    p, cfg, s, t, self.rt, depth=dep, anc=a),
+                fn.verify_tree,
                 in_shardings=(qsh, ssh, vsh["tokens"], tsh["window"],
                               tsh["window"]),
                 out_shardings=(vsh["logits"], vsh["hidden"], ssh),
@@ -552,20 +590,17 @@ class ContinuousBatchingEngine:
         if self.chunk:
             csh = SH.prefill_carry_shardings(
                 cfg, jax.eval_shape(self._carry_init), mesh)
-            self._carry_init = jax.jit(
-                lambda: M.init_prefill_carry(cfg, self.max_len + self.chunk),
-                out_shardings=csh)
+            self._carry_init = jax.jit(fn.init_prefill_carry,
+                                       out_shardings=csh)
             # pin the carry's layout across chunk steps (heads stay over
             # `model`, matching the pool so finalize->write never reshards;
             # matching in/out is also the donation-alias condition)
             self._chunk_fn = jax.jit(
-                lambda p, c, t, n: M.prefill_chunk(p, cfg, c, t, n, self.rt),
+                fn.prefill_chunk,
                 out_shardings=(NamedSharding(mesh, P()), csh),
                 donate_argnums=(1,))
             self._finalize_write = jax.jit(
-                lambda s, slot, c: T.write_slot(
-                    s, slot, M.finalize_prefill_carry(cfg, c, self.max_len)),
-                out_shardings=ssh, donate_argnums=(0,))
+                fn.finalize_write, out_shardings=ssh, donate_argnums=(0,))
         if self._pcache is not None:
             # the gather is pinned beside the pool: in/out = the pool's
             # shardings (the donation-alias condition) with replicated
@@ -577,8 +612,7 @@ class ContinuousBatchingEngine:
                 in_shardings=(ssh, gsh["slot"], gsh["slot"], gsh["rows"]),
                 out_shardings=ssh, donate_argnums=(0,))
             self._warm_carry = jax.jit(
-                lambda s, slot, n: M.warm_prefill_carry(
-                    cfg, s, slot, n, self.max_len + self.chunk),
+                fn.warm_prefill_carry,
                 in_shardings=(ssh, gsh["slot"], gsh["rows"]),
                 out_shardings=csh)
 
@@ -633,14 +667,24 @@ class ContinuousBatchingEngine:
     # counts everything, `decode_xfer_bytes` only the decode lane, which
     # the transfer-discipline regression test pins to O(n_slots * m) for
     # greedy and O(n_slots * k) for sampled decode.
+    #
+    # Spans (`jax.profiler.TraceAnnotation`) sit at the step's boundaries
+    # and record only while a profiler runs, on the device trace's clock:
+    # engine.step, .schedule, .prefill, .push, .dispatch, .fetch, .emit.
+    # Names stay bare (per-event values go in keyword arguments) so a trace
+    # reduction can group by name.
     def _fetch(self, x, decode: bool = False):
-        """Explicit device->host fetch (counted; timed as device wait)."""
+        """Explicit device->host fetch (counted): the host blocks until the
+        device has produced ``x`` and copied it back (``wait_s``)."""
         t0 = time.perf_counter()
-        out = jax.device_get(x)
-        self.stats["device_s"] += time.perf_counter() - t0
+        with TraceAnnotation("engine.fetch"):
+            out = jax.device_get(x)
+        dt = time.perf_counter() - t0
+        self.stats["wait_s"] += dt
         n = sum(a.nbytes for a in jax.tree.leaves(out))
         self.stats["xfer_bytes"] += n
         if decode:
+            self.stats["decode_wait_s"] += dt
             self.stats["decode_xfer_bytes"] += n
         return out
 
@@ -649,16 +693,18 @@ class ContinuousBatchingEngine:
         self.stats["xfer_bytes"] += arr.nbytes
         if decode:
             self.stats["decode_xfer_bytes"] += arr.nbytes
-        if sharding is not None:
-            return jax.device_put(arr, sharding)
-        return jax.device_put(arr)
+        with TraceAnnotation("engine.push"):
+            if sharding is not None:
+                return jax.device_put(arr, sharding)
+            return jax.device_put(arr)
 
     def _dev(self, fn, *args):
-        """Dispatch a jitted step under the device-time clock (the
-        host/device breakdown the serve benchmark reports)."""
+        """Enqueue a jitted program (``dispatch_s``); returns before the
+        device has run it."""
         t0 = time.perf_counter()
-        out = fn(*args)
-        self.stats["device_s"] += time.perf_counter() - t0
+        with TraceAnnotation("engine.dispatch"):
+            out = fn(*args)
+        self.stats["dispatch_s"] += time.perf_counter() - t0
         return out
 
     def _device_topk(self, logits, k: int):
@@ -670,8 +716,10 @@ class ContinuousBatchingEngine:
         bit-identical to the full-vocab path."""
         fn = self._topk_fns.get(k)
         if fn is None:
-            fn = self._topk_fns[k] = jax.jit(
-                lambda lg: jax.lax.top_k(lg, k))
+            def topk(lg):
+                return jax.lax.top_k(lg, k)
+
+            fn = self._topk_fns[k] = jax.jit(topk)
         return self._dev(fn, logits)
 
     # -- per-request sampling ---------------------------------------------
@@ -754,12 +802,15 @@ class ContinuousBatchingEngine:
             kmax = max(ks)
             vals, idx = self._fetch(self._device_topk(logits, kmax),
                                     decode=True)
-            for slot, req in dec:
-                out[slot] = self._sample_candidates(req, vals[slot], idx[slot])
+            with TraceAnnotation("engine.emit", slots=len(dec)):
+                for slot, req in dec:
+                    out[slot] = self._sample_candidates(req, vals[slot],
+                                                        idx[slot])
             return out
         rows = self._fetch(logits, decode=True).astype(np.float32)
-        for slot, req in dec:
-            out[slot] = self._sample_token(req, rows[slot])
+        with TraceAnnotation("engine.emit", slots=len(dec)):
+            for slot, req in dec:
+                out[slot] = self._sample_token(req, rows[slot])
         return out
 
     # -- admission: prefill into a slot -----------------------------------
@@ -789,6 +840,7 @@ class ContinuousBatchingEngine:
         # the draw always runs so a resumed request's sampling stream stays
         # aligned with its original run
         tok = self._first_token(req, logits)
+        self.stats["prefills"] += 1
         if req.output:                     # resumed: recorded token wins
             tok = req.output[0]
             req.replay_pos = 1
@@ -1241,69 +1293,89 @@ class ContinuousBatchingEngine:
         iterations in ``stats["slow_steps"]``."""
         t0 = time.perf_counter()
         try:
-            attempt = 0
-            while True:
-                try:
-                    return self._step()
-                except Exception as e:                # noqa: BLE001
-                    if not (isinstance(e, InjectedStepFailure)
-                            or self._pool_consumed()):
-                        raise
-                    self.stats["step_failures"] += 1
-                    if attempt >= self.max_step_retries:
-                        raise RuntimeError(
-                            f"engine step failed {attempt + 1} time(s); "
-                            "retry budget exhausted") from e
-                    if self.retry_backoff_s > 0:
-                        time.sleep(self.retry_backoff_s * (2.0 ** attempt))
-                    attempt += 1
-                    self.stats["step_retries"] += 1
-                    self._rebuild_pool()
+            with TraceAnnotation("engine.step", step=self.stats["steps"] + 1):
+                attempt = 0
+                while True:
+                    try:
+                        return self._step()
+                    except Exception as e:            # noqa: BLE001
+                        if not (isinstance(e, InjectedStepFailure)
+                                or self._pool_consumed()):
+                            raise
+                        self.stats["step_failures"] += 1
+                        if attempt >= self.max_step_retries:
+                            raise RuntimeError(
+                                f"engine step failed {attempt + 1} time(s); "
+                                "retry budget exhausted") from e
+                        if self.retry_backoff_s > 0:
+                            time.sleep(self.retry_backoff_s
+                                       * (2.0 ** attempt))
+                        attempt += 1
+                        self.stats["step_retries"] += 1
+                        self._rebuild_pool()
         finally:
             dt = time.perf_counter() - t0
             self.stats["step_s"] += dt
             if self._watchdog.observe(self.stats["steps"], dt):
                 self.stats["slow_steps"] += 1
 
+    def _prefill_work(self, req: Request, tokens: int, work, *args) -> int:
+        """``work(req, *args)``, one admission's prefill work, under the
+        ``engine.prefill`` span; its host seconds add to ``prefill_s``."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.prefill", rid=req.rid, tokens=tokens):
+            got = work(req, *args)
+        self.stats["prefill_s"] += time.perf_counter() - t0
+        return got
+
+    def _admit(self, req: Request) -> int:
+        """Admit one request the scheduler granted a slot; returns the
+        prompt tokens prefilled now (chunked admission prefills later)."""
+        if req.swapped_rows:
+            # swap-preempted victim: restore its rows from the cold tier
+            # and resume decoding — both engine flavours.  False = the
+            # block was uncorrectably corrupt; fall through to the
+            # recompute admission below (token-identical replay)
+            if self._admit_swapped(req) or req.done:
+                return 0
+        if self.chunk:
+            # exception-safe like _admit_atomic: a failed carry allocation
+            # fails one request, never leaks the slot
+            try:
+                self._admit_chunked(req)
+            except Exception as e:                    # noqa: BLE001
+                self._fail(req, f"{type(e).__name__}: {e}")
+                self._check_pool_alive(e)
+            return 0
+        return self._admit_atomic(req)
+
     def _step(self) -> bool:
-        now = self._now()
-        self.stats["steps"] += 1
-        step_pf = 0
-        cancelled = self._apply_cancels(now)
-        for slot, req in list(self.scheduler.active.items()):
-            if (req.state is RequestState.DECODING
-                    and req.replay_pos >= len(req.output)
-                    and req.should_stop()):
-                self._retire(req, now)
-        self._apply_deadlines(now)
-        if self._injector is not None:
-            for slot in self._injector.lost_slots(self.stats["steps"]):
-                self._lose_slot(slot, now)
-        # preemption: only meaningful when the queue is blocked on slots —
-        # and a reclaimable prefix-cache leaf means it is not blocked
-        # (admission evicts LRU cache rows before any resident is bumped)
-        if not self.scheduler.free_slots and not (
-                self._pcache is not None and self._pcache.has_reclaimable()):
-            for req in self.scheduler.preemption_victims(now):
-                self._preempt(req, now)
-        for req in self.scheduler.admit(now):
-            if req.swapped_rows:
-                # swap-preempted victim: restore its rows from the cold
-                # tier and resume decoding — both engine flavours.  False
-                # = the block was uncorrectably corrupt; fall through to
-                # the recompute admission below (token-identical replay)
-                if self._admit_swapped(req) or req.done:
-                    continue
-            if self.chunk:
-                # exception-safe like _admit_atomic: a failed carry
-                # allocation fails one request, never leaks the slot
-                try:
-                    self._admit_chunked(req)
-                except Exception as e:                # noqa: BLE001
-                    self._fail(req, f"{type(e).__name__}: {e}")
-                    self._check_pool_alive(e)
-            else:
-                step_pf += self._admit_atomic(req)
+        with TraceAnnotation("engine.schedule"):
+            now = self._now()
+            self.stats["steps"] += 1
+            step_pf = 0
+            cancelled = self._apply_cancels(now)
+            for slot, req in list(self.scheduler.active.items()):
+                if (req.state is RequestState.DECODING
+                        and req.replay_pos >= len(req.output)
+                        and req.should_stop()):
+                    self._retire(req, now)
+            self._apply_deadlines(now)
+            if self._injector is not None:
+                for slot in self._injector.lost_slots(self.stats["steps"]):
+                    self._lose_slot(slot, now)
+            # preemption: only meaningful when the queue is blocked on
+            # slots — and a reclaimable prefix-cache leaf means it is not
+            # blocked (admission evicts LRU cache rows before any resident
+            # is bumped)
+            if not self.scheduler.free_slots and not (
+                    self._pcache is not None
+                    and self._pcache.has_reclaimable()):
+                for req in self.scheduler.preemption_victims(now):
+                    self._preempt(req, now)
+            admitted = self.scheduler.admit(now)
+        for req in admitted:
+            step_pf += self._prefill_work(req, req.prompt_len, self._admit)
         if self.chunk:
             budget = self.max_step_tokens - sum(
                 1 for r in self.scheduler.active.values()
@@ -1321,7 +1393,7 @@ class ContinuousBatchingEngine:
                             n = budget - 1
                         if n <= 0:
                             break
-                    got = self._run_chunk(req, n)
+                    got = self._prefill_work(req, n, self._run_chunk, n)
                     if not got:
                         break
                     budget -= got + (1 if req.state is RequestState.DECODING
@@ -1360,23 +1432,25 @@ class ContinuousBatchingEngine:
             self._push(self._last_tok,
                        self._io and self._io["tokens"], decode=True))
         nxt = self._next_tokens(logits, dec)
-        now = self._now()
-        for slot, req in dec:
-            self._slot_pos[slot] += 1      # host mirror of the device cursor
-            if req.replay_pos < len(req.output):
-                # resuming after preemption: this decode recomputed a token
-                # we already emitted — re-feed the recorded one, no append
-                tok = req.output[req.replay_pos]
-                req.replay_pos += 1
+        with TraceAnnotation("engine.emit", slots=len(dec)):
+            now = self._now()
+            for slot, req in dec:
+                self._slot_pos[slot] += 1  # host mirror of the device cursor
+                if req.replay_pos < len(req.output):
+                    # resuming after preemption: this decode recomputed a
+                    # token we already emitted — re-feed the recorded one,
+                    # no append
+                    tok = req.output[req.replay_pos]
+                    req.replay_pos += 1
+                    self._last_tok[slot] = tok
+                    continue
+                tok = int(nxt[slot])
+                req.output.append(tok)
+                req.replay_pos = len(req.output)
                 self._last_tok[slot] = tok
-                continue
-            tok = int(nxt[slot])
-            req.output.append(tok)
-            req.replay_pos = len(req.output)
-            self._last_tok[slot] = tok
-            self.policy.on_tokens(req, 1)
-            if req.should_stop():
-                self._retire(req, now)
+                self.policy.on_tokens(req, 1)
+                if req.should_stop():
+                    self._retire(req, now)
         return True
 
     # -- fused multi-step decode lane ---------------------------------------
@@ -1410,32 +1484,34 @@ class ContinuousBatchingEngine:
             self._push(self._last_tok,
                        self._io and self._io["tokens"], decode=True))
         blk = self._fetch(blk_dev, decode=True)   # [n_slots, m] int32
-        now = self._now()
-        stopped_early = False
-        block_tokens = 0
-        for slot, req in dec:
-            emitted = 0
-            for i in range(m):
-                tok = int(blk[slot, i])
-                req.output.append(tok)
-                req.replay_pos = len(req.output)
-                self._last_tok[slot] = tok
-                self.policy.on_tokens(req, 1)
-                emitted += 1
-                if req.should_stop():
-                    self._retire(req, now)
-                    break
-            self._slot_pos[slot] += emitted
-            self.stats["multi_tokens"] += emitted
-            block_tokens += emitted
-            if emitted < m:
-                stopped_early = True
-        # a fused iteration emits up to len(dec) * m tokens: keep the
-        # per-iteration stat honest (fusion never competes with prefill
-        # work — it only runs when no PREFILLING slot or queue exists, so
-        # the chunked token budget's decode-vs-prefill packing is unaffected)
-        self.stats["max_step_total_tokens"] = max(
-            self.stats["max_step_total_tokens"], block_tokens)
+        with TraceAnnotation("engine.emit", slots=len(dec)):
+            now = self._now()
+            stopped_early = False
+            block_tokens = 0
+            for slot, req in dec:
+                emitted = 0
+                for i in range(m):
+                    tok = int(blk[slot, i])
+                    req.output.append(tok)
+                    req.replay_pos = len(req.output)
+                    self._last_tok[slot] = tok
+                    self.policy.on_tokens(req, 1)
+                    emitted += 1
+                    if req.should_stop():
+                        self._retire(req, now)
+                        break
+                self._slot_pos[slot] += emitted
+                self.stats["multi_tokens"] += emitted
+                block_tokens += emitted
+                if emitted < m:
+                    stopped_early = True
+            # a fused iteration emits up to len(dec) * m tokens: keep the
+            # per-iteration stat honest (fusion never competes with
+            # prefill work — it only runs when no PREFILLING slot or queue
+            # exists, so the chunked token budget's decode-vs-prefill
+            # packing is unaffected)
+            self.stats["max_step_total_tokens"] = max(
+                self.stats["max_step_total_tokens"], block_tokens)
         if stopped_early:
             # commit each stopped slot's emitted prefix; rows past it are
             # dead in-place entries until the next admission overwrites them
@@ -1522,44 +1598,47 @@ class ContinuousBatchingEngine:
         row_token = self._row_token_fn(logits, dec)
         hid = (self._fetch(hidden, decode=True).astype(np.float32)
                if self._drafter.kind == "model" else None)
-        now = self._now()
-        for slot, req in dec:
-            fed = drafts[slot]
-            committed = 0                 # accepted K/V rows past toks[:, 0]
-            for i in range(k + 1):
-                # row i of `rows` is the model's next-token distribution
-                # after consuming toks[slot, :i+1] — valid because reaching
-                # row i means every earlier draft was accepted
-                replaying = req.replay_pos < len(req.output)
-                if replaying:
-                    # the draw still runs (discarded) so a resumed sampled
-                    # request re-consumes one draw per recorded token and
-                    # its stream stays aligned — same rule as _next_tokens
-                    if req.temperature > 0:
-                        row_token(req, slot, i)
-                    tok = req.output[req.replay_pos]
-                    req.replay_pos += 1
-                else:
-                    tok = row_token(req, slot, i)
-                    req.output.append(tok)
-                    req.replay_pos = len(req.output)
-                    self.policy.on_tokens(req, 1)
-                self._last_tok[slot] = tok
-                if hid is not None:
-                    self._h_last[slot] = hid[slot, i]
-                accepted = i < k and tok == fed[i]
-                if not replaying and i < k:
-                    self.stats["spec_drafted"] += 1
-                    self.stats["spec_accepted"] += int(accepted)
-                if req.replay_pos >= len(req.output) and req.should_stop():
-                    committed += int(accepted)
-                    self._retire(req, now)
-                    break
-                if not accepted:
-                    break
-                committed += 1
-            self.stats["spec_accept_hist"][committed] += 1
-            self._slot_pos[slot] += 1 + committed
+        with TraceAnnotation("engine.emit", slots=len(dec)):
+            now = self._now()
+            for slot, req in dec:
+                fed = drafts[slot]
+                committed = 0         # accepted K/V rows past toks[:, 0]
+                for i in range(k + 1):
+                    # row i of `rows` is the model's next-token
+                    # distribution after consuming toks[slot, :i+1] — valid
+                    # because reaching row i means every earlier draft was
+                    # accepted
+                    replaying = req.replay_pos < len(req.output)
+                    if replaying:
+                        # the draw still runs (discarded) so a resumed
+                        # sampled request re-consumes one draw per recorded
+                        # token and its stream stays aligned — same rule as
+                        # _next_tokens
+                        if req.temperature > 0:
+                            row_token(req, slot, i)
+                        tok = req.output[req.replay_pos]
+                        req.replay_pos += 1
+                    else:
+                        tok = row_token(req, slot, i)
+                        req.output.append(tok)
+                        req.replay_pos = len(req.output)
+                        self.policy.on_tokens(req, 1)
+                    self._last_tok[slot] = tok
+                    if hid is not None:
+                        self._h_last[slot] = hid[slot, i]
+                    accepted = i < k and tok == fed[i]
+                    if not replaying and i < k:
+                        self.stats["spec_drafted"] += 1
+                        self.stats["spec_accepted"] += int(accepted)
+                    if req.replay_pos >= len(req.output) and req.should_stop():
+                        committed += int(accepted)
+                        self._retire(req, now)
+                        break
+                    if not accepted:
+                        break
+                    committed += 1
+                self.stats["spec_accept_hist"][committed] += 1
+                self._slot_pos[slot] += 1 + committed
         # rollback: rewind every cursor to its committed prefix; rejected
         # suffix rows stay as dead in-place entries until overwritten
         self.state = T.rewind_pos(self.state, self._pos_device())
@@ -1635,60 +1714,65 @@ class ContinuousBatchingEngine:
         row_token = self._row_token_fn(logits, dec)
         hid = (self._fetch(hidden, decode=True).astype(np.float32)
                if self._drafter.kind == "model" else None)
-        # the commit's rollback base: each slot's cursor BEFORE this window
-        # (window node w's K/V row sits at base + w)
-        base = np.asarray(self._slot_pos, np.int32)
-        sel = np.zeros((self.n_slots, n), np.int32)
-        keep = np.zeros((self.n_slots,), np.int32)
-        now = self._now()
-        for slot, req in dec:
-            # children of each window node in draft order; the walk is
-            # unambiguous because siblings carry distinct tokens
-            kids: dict[int, list[int]] = {}
-            for i, p in enumerate(parents[slot]):
-                kids.setdefault(p + 1, []).append(i + 1)
-            cur = 0                        # window node whose row we sample
-            path: list[int] = []           # accepted nodes, root-path order
-            while True:
-                # row `cur` is the model's next-token distribution after
-                # consuming the root plus cur's ancestor chain — valid
-                # because reaching cur means that whole chain was accepted
-                replaying = req.replay_pos < len(req.output)
-                if replaying:
-                    # the draw still runs (discarded) so a resumed sampled
-                    # request re-consumes one draw per recorded token and
-                    # its stream stays aligned — same rule as _next_tokens
-                    if req.temperature > 0:
-                        row_token(req, slot, cur)
-                    tok = req.output[req.replay_pos]
-                    req.replay_pos += 1
-                else:
-                    tok = row_token(req, slot, cur)
-                    req.output.append(tok)
-                    req.replay_pos = len(req.output)
-                    self.policy.on_tokens(req, 1)
-                self._last_tok[slot] = tok
-                if hid is not None:
-                    self._h_last[slot] = hid[slot, cur]
-                nxt = next((c for c in kids.get(cur, ())
-                            if int(toks[slot, c]) == tok), None)
-                if not replaying and kids.get(cur):
-                    self.stats["spec_drafted"] += 1
-                    self.stats["spec_accepted"] += int(nxt is not None)
-                if req.replay_pos >= len(req.output) and req.should_stop():
-                    if nxt is not None:    # the stopping token was drafted:
-                        path.append(nxt)   # commit its row like the linear
-                    self._retire(req, now)         # lane's bonus accept
-                    break
-                if nxt is None:
-                    break
-                path.append(nxt)
-                cur = nxt
-            committed = len(path)
-            sel[slot, :committed] = path
-            keep[slot] = committed
-            self.stats["spec_accept_hist"][committed] += 1
-            self._slot_pos[slot] += 1 + committed
+        with TraceAnnotation("engine.emit", slots=len(dec)):
+            # the commit's rollback base: each slot's cursor BEFORE this
+            # window (window node w's K/V row sits at base + w)
+            base = np.asarray(self._slot_pos, np.int32)
+            sel = np.zeros((self.n_slots, n), np.int32)
+            keep = np.zeros((self.n_slots,), np.int32)
+            now = self._now()
+            for slot, req in dec:
+                # children of each window node in draft order; the walk is
+                # unambiguous because siblings carry distinct tokens
+                kids: dict[int, list[int]] = {}
+                for i, p in enumerate(parents[slot]):
+                    kids.setdefault(p + 1, []).append(i + 1)
+                cur = 0                # window node whose row we sample
+                path: list[int] = []   # accepted nodes, root-path order
+                while True:
+                    # row `cur` is the model's next-token distribution
+                    # after consuming the root plus cur's ancestor chain —
+                    # valid because reaching cur means that whole chain was
+                    # accepted
+                    replaying = req.replay_pos < len(req.output)
+                    if replaying:
+                        # the draw still runs (discarded) so a resumed
+                        # sampled request re-consumes one draw per recorded
+                        # token and its stream stays aligned — same rule as
+                        # _next_tokens
+                        if req.temperature > 0:
+                            row_token(req, slot, cur)
+                        tok = req.output[req.replay_pos]
+                        req.replay_pos += 1
+                    else:
+                        tok = row_token(req, slot, cur)
+                        req.output.append(tok)
+                        req.replay_pos = len(req.output)
+                        self.policy.on_tokens(req, 1)
+                    self._last_tok[slot] = tok
+                    if hid is not None:
+                        self._h_last[slot] = hid[slot, cur]
+                    nxt = next((c for c in kids.get(cur, ())
+                                if int(toks[slot, c]) == tok), None)
+                    if not replaying and kids.get(cur):
+                        self.stats["spec_drafted"] += 1
+                        self.stats["spec_accepted"] += int(nxt is not None)
+                    if req.replay_pos >= len(req.output) and req.should_stop():
+                        # the stopping token was drafted: commit its row
+                        # like the linear lane's bonus accept
+                        if nxt is not None:
+                            path.append(nxt)
+                        self._retire(req, now)
+                        break
+                    if nxt is None:
+                        break
+                    path.append(nxt)
+                    cur = nxt
+                committed = len(path)
+                sel[slot, :committed] = path
+                keep[slot] = committed
+                self.stats["spec_accept_hist"][committed] += 1
+                self._slot_pos[slot] += 1 + committed
         # compact: gather each slot's accepted rows (base + sel) into
         # contiguous committed rows at base + 1 and land the new cursors;
         # inactive slots pass keep=0 and their unchanged cursor (no-op)

@@ -48,6 +48,8 @@ import asyncio
 import concurrent.futures
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 from repro.serve.engine import ContinuousBatchingEngine, RequestFailedError
 from repro.serve.scheduler import Request
 
@@ -277,22 +279,27 @@ class AsyncServer:
             self._wake.set()
 
     # -- serve loop --------------------------------------------------------
+    # The two spans below (server.admit, server.publish) wrap synchronous
+    # work on the event loop; no span crosses an await, so the loop's own
+    # waits read as time under no span.
     def _admit_pending(self) -> None:
         """Hand buffered submissions to the engine scheduler.  Runs on the
         event loop strictly between engine steps."""
-        pending, self._pending = self._pending, []
-        for kwargs, fut in pending:
-            if fut.done():            # cancelled while waiting
-                continue
-            try:
-                fut.set_result(self.engine.submit(**kwargs))
-            except Exception as e:                    # noqa: BLE001
-                fut.set_exception(e)
+        with TraceAnnotation("server.admit"):
+            pending, self._pending = self._pending, []
+            for kwargs, fut in pending:
+                if fut.done():            # cancelled while waiting
+                    continue
+                try:
+                    fut.set_result(self.engine.submit(**kwargs))
+                except Exception as e:                # noqa: BLE001
+                    fut.set_exception(e)
 
     def _publish(self) -> None:
         """Wake every pump waiting for this iteration's tokens."""
-        tick, self._tick = self._tick, asyncio.Event()
-        tick.set()
+        with TraceAnnotation("server.publish"):
+            tick, self._tick = self._tick, asyncio.Event()
+            tick.set()
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
