@@ -56,6 +56,38 @@ def batched_update(buf: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
     return jax.vmap(one)(buf, new, pos)
 
 
+def append_rows(pool: jax.Array, layer: jax.Array | int, new: jax.Array,
+                pos: jax.Array) -> jax.Array:
+    """Write slot ``b``'s one new row ``new[b, 0]`` into layer ``layer`` of
+    the stacked pool at offset ``pos[b]``: :func:`batched_update` one level
+    up, for a pool carried through the decode layer scan.
+
+    pool: [L, B, S, ...]; new: [B, 1, ...]; pos: [B] int32 (clamped to
+    ``S - 1`` as :func:`batched_update` clamps).  Each slot's write is a
+    ``dynamic_update_slice`` two rows deep, ``pos[b]`` and a neighbour that
+    keeps its own bytes, so the append takes place in the carried pool and
+    nothing is sliced out and written back.  Two rows, not one: the TPU
+    keeps an int8 pool whose head_dim is not a multiple of 128 with S as
+    the minor axis (no lane padding), and an update one row deep in S makes
+    XLA carry the whole pool in row-major order instead, at the cost of two
+    pool-sized relayout copies per call.
+    """
+    B, S = pool.shape[1:3]
+    pos = slot_positions(pos, B)
+    layer = jnp.asarray(layer, jnp.int32)
+    w = min(2, S)
+    rest = pool.shape[3:]
+    for b in range(B):
+        p = jnp.clip(pos[b], 0, S - 1)
+        r = jnp.minimum(p, S - w)
+        at = (layer, jnp.int32(b), r) + (jnp.int32(0),) * len(rest)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, w) + rest)
+        hit = (jnp.arange(w) == p - r).reshape((1, 1, w) + (1,) * len(rest))
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(hit, new[b].astype(pool.dtype), old), at)
+    return pool
+
+
 def chunk_update(buf: jax.Array, new: jax.Array, start: jax.Array | int,
                  ) -> jax.Array:
     """Append a ``[B, C, ...]`` chunk into ``buf`` (``[B, S, ...]``) at the

@@ -408,6 +408,15 @@ def swap_row_shardings(mesh) -> dict:
     return {"slot": r, "row": r}
 
 
+def kv_pool_spec(n_slots: int, n_kv_heads: int, mesh) -> P:
+    """PartitionSpec of a [n_p, B, S, H_kv, D] int8 K/V pool leaf (and of
+    its [n_p, B, S, H_kv, 1] scales), as :func:`decode_state_shardings`
+    lays it: slots over the data axes, KV heads over `model` when each
+    tiles its dim."""
+    return P(None, batch_entry(n_slots, mesh), None,
+             _fit(mesh, n_kv_heads, MODEL_AXIS), None)
+
+
 def decode_state_shardings(cfg: ModelConfig, shape: ShapeConfig,
                            state_abs: Any, mesh):
     """Slot-pool decode state: the batch/slot axis (dim 1 of every cache

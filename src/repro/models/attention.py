@@ -398,15 +398,14 @@ def gqa_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     return out, (k_q, k_s, v_q, v_s)
 
 
-def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
-               k_q, k_s, v_q, v_s, backend: str = "dense",
-               inter_dtype=jnp.float32):
-    """One-token decode.  Returns (out, (k_new, v_new)) to append to cache.
-    ``pos`` is a scalar (aligned batch) or [B] vector of per-slot positions —
-    each slot's k/v appends at its own SLC offset (vmapped update)."""
+def gqa_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
+                    pos_b: jax.Array, backend: str = "dense"):
+    """The projections of one decode token: ``x`` [B, 1, d] at per-slot
+    positions ``pos_b`` ([B]) -> (q [B, 1, H, D], (k_q, k_s, v_q, v_s)),
+    the token's int8 K/V rows ([B, 1, Hkv, D]) and their f32 scales
+    ([B, 1, Hkv, 1]) for the caller to append at ``pos_b``."""
     B = x.shape[0]
     hd = cfg.head_dim
-    pos_b = KV.slot_positions(pos, B)
     q = L.apply_linear(L._lin(p, "wq"), x, backend).reshape(B, 1, cfg.n_heads, hd)
     k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, 1, cfg.n_kv_heads, hd)
     v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, 1, cfg.n_kv_heads, hd)
@@ -417,17 +416,37 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
         pp = pos_b[:, None]
         q = L.apply_rope(q, pp, cfg.rope_theta)
         k = L.apply_rope(k, pp, cfg.rope_theta)
-    # current token's k/v take part via cache append done by the caller;
-    # we attend over cache *including* this position, so fold it in here.
-    kq_new, ks_new = quant.quantize_kv(k)
-    vq_new, vs_new = quant.quantize_kv(v)
-    k_q = KV.batched_update(k_q, kq_new, pos_b)
-    k_s = KV.batched_update(k_s, ks_new, pos_b)
-    v_q = KV.batched_update(v_q, vq_new, pos_b)
-    v_s = KV.batched_update(v_s, vs_new, pos_b)
-    o = decode_attention_int8(q, k_q, k_s, v_q, v_s, pos_b + 1, backend,
+    return q, (*quant.quantize_kv(k), *quant.quantize_kv(v))
+
+
+def gqa_decode_attend(p: Params, q: jax.Array, k_q, k_s, v_q, v_s,
+                      length: jax.Array, backend: str = "dense",
+                      inter_dtype=jnp.float32) -> jax.Array:
+    """Decode attention of ``q`` over the int8 rows ``[0, length)`` of
+    ``k_q``/``v_q`` ([B, S, Hkv, D], the token's own row already appended),
+    then the output projection -> [B, 1, d]."""
+    o = decode_attention_int8(q, k_q, k_s, v_q, v_s, length, backend,
                               inter_dtype)
-    out = L.apply_linear(L._lin(p, "wo"), o.reshape(B, 1, -1), backend)
+    return L.apply_linear(L._lin(p, "wo"), o.reshape(q.shape[0], 1, -1),
+                          backend)
+
+
+def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
+               k_q, k_s, v_q, v_s, backend: str = "dense",
+               inter_dtype=jnp.float32):
+    """One-token decode against one layer's cache ([B, S, Hkv, D]).
+    Returns (out, updated (k_q, k_s, v_q, v_s)).  ``pos`` is a scalar
+    (aligned batch) or [B] vector of per-slot positions — each slot's k/v
+    appends at its own SLC offset (vmapped update), and the token attends
+    over the cache including its own row.  The decoder-only layer scan
+    appends into the stacked pool instead
+    (:func:`repro.models.transformer.apply_layer_decode`)."""
+    pos_b = KV.slot_positions(pos, x.shape[0])
+    q, rows = gqa_decode_rows(p, cfg, x, pos_b, backend)
+    k_q, k_s, v_q, v_s = (KV.batched_update(c, r, pos_b)
+                          for c, r in zip((k_q, k_s, v_q, v_s), rows))
+    out = gqa_decode_attend(p, q, k_q, k_s, v_q, v_s, pos_b + 1, backend,
+                            inter_dtype)
     return out, (k_q, k_s, v_q, v_s)
 
 

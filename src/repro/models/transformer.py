@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.core import kvcache as KV
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
@@ -424,22 +425,65 @@ def warm_prefill_carry(cfg: ModelConfig, state: dict, slot: jax.Array,
             "pos": jnp.broadcast_to(n, (1,)).astype(jnp.int32)}
 
 
-def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
-                       rt: Runtime):
+_KV_LEAVES = ("k_q", "k_s", "v_q", "v_s")
+
+
+def _append_kv(caches: dict, idx, rows, pos_b, rt: Runtime) -> dict:
+    """Append one decode token's K/V rows and scales into the carried pool
+    at layer ``idx`` (:func:`KV.append_rows`).  On a mesh each device
+    appends its own slots and KV heads inside ``shard_map``: a per-slot
+    update at a constant slot index on the data-sharded slot axis would
+    otherwise make the partitioner all-gather the whole pool per slot."""
+    def append(c, i, r, p):
+        return {n: KV.append_rows(c[n], i, rr, p)
+                for n, rr in zip(_KV_LEAVES, r)}
+    if rt.mesh is None:
+        return append(caches, idx, rows, pos_b)
+    from repro.dist import sharding as SH
+    B, G = caches["k_q"].shape[1], caches["k_q"].shape[3]
+    pool = SH.kv_pool_spec(B, G, rt.mesh)
+    row = P(*pool[1:])
+    return jax.shard_map(
+        append, mesh=rt.mesh,
+        in_specs=({n: pool for n in _KV_LEAVES}, P(), (row,) * 4, P(pool[1])),
+        out_specs={n: pool for n in _KV_LEAVES})(
+            {n: caches[n] for n in _KV_LEAVES}, idx, tuple(rows), pos_b)
+
+
+def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos,
+                       caches, idx, rt: Runtime):
+    """One decode layer against ``caches``, the layer stack's carried cache
+    (leaves [n_p, B, S, ...]), at layer ``idx``.  Returns (x, caches).
+
+    A GQA layer appends its int8 K/V rows and scales straight into the
+    carried pool (:func:`_append_kv`) and attends over layer ``idx``
+    read from it: the pool is never sliced out and written back whole.
+    SSM and MLA layers update their layer's slice and write it back."""
     kind = cfg.layer_kind(slot)
     dmvm_dt = rt.dmvm_dtype or jnp.float32
     h = L.apply_norm(p["ln1"], x)
-    if kind == "ssm":
-        mix, new_cache = S.ssm_decode(p["ssm"], cfg, h, cache, rt.backend)
-    elif cfg.attn_type == "mla":
-        mix, (c_q, c_s) = A.mla_decode(p["attn"], cfg, h, pos, cache["c_q"],
-                                       cache["c_s"], rt.backend, dmvm_dt)
-        new_cache = {"c_q": c_q, "c_s": c_s}
+    if kind != "ssm" and cfg.attn_type != "mla":
+        pos_b = KV.slot_positions(pos, x.shape[0])
+        q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b, rt.backend)
+        caches = _append_kv(caches, idx, rows, pos_b, rt)
+        mix = A.gqa_decode_attend(
+            p["attn"], q, *(jax.lax.dynamic_index_in_dim(
+                caches[n], idx, 0, keepdims=False) for n in _KV_LEAVES),
+            pos_b + 1, rt.backend, dmvm_dt)
     else:
-        mix, (k_q, k_s, v_q, v_s) = A.gqa_decode(
-            p["attn"], cfg, h, pos, cache["k_q"], cache["k_s"], cache["v_q"],
-            cache["v_s"], rt.backend, dmvm_dt)
-        new_cache = {"k_q": k_q, "k_s": k_s, "v_q": v_q, "v_s": v_s}
+        cache = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0, keepdims=False),
+            caches)
+        if kind == "ssm":
+            mix, new = S.ssm_decode(p["ssm"], cfg, h, cache, rt.backend)
+        else:
+            mix, (c_q, c_s) = A.mla_decode(p["attn"], cfg, h, pos,
+                                           cache["c_q"], cache["c_s"],
+                                           rt.backend, dmvm_dt)
+            new = {"c_q": c_q, "c_s": c_s}
+        caches = jax.tree.map(
+            lambda full, n: jax.lax.dynamic_update_slice_in_dim(
+                full, n[None].astype(full.dtype), idx, 0), caches, new)
     x = x + mix
     if "moe" in p:
         mo, _ = _moe_block(p["moe"], L.apply_norm(p["ln2"], x), cfg, rt)
@@ -447,7 +491,7 @@ def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
     elif "mlp" in p:
         x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x), cfg.mlp_type,
                             rt.backend)
-    return x, new_cache
+    return x, caches
 
 
 def decode_step(p: Params, cfg: ModelConfig, state: dict, token: jax.Array,
@@ -472,19 +516,9 @@ def decode_step(p: Params, cfg: ModelConfig, state: dict, token: jax.Array,
             slot_trees, idx = xs
             new_full = []
             for s in range(period):
-                # slice this period's cache from the carried buffer and
-                # write the update back in place (dynamic_update_slice on
-                # the loop carry -> no full-cache copy per layer)
-                cache_s = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0,
-                                                           keepdims=False),
-                    full_caches[s])
-                xx, nc = apply_layer_decode(slot_trees[s], cfg, start + s, xx,
-                                            pos, cache_s, rt)
-                new_full.append(jax.tree.map(
-                    lambda full, new: jax.lax.dynamic_update_slice_in_dim(
-                        full, new[None].astype(full.dtype), idx, 0),
-                    full_caches[s], nc))
+                xx, full = apply_layer_decode(slot_trees[s], cfg, start + s,
+                                              xx, pos, full_caches[s], idx, rt)
+                new_full.append(full)
             return (xx, tuple(new_full)), None
 
         (x, new_caches), _ = jax.lax.scan(
@@ -505,7 +539,7 @@ def multi_decode_step(p: Params, cfg: ModelConfig, state: dict,
 
     Returns ``(tokens [B, m] int32, state advanced by m)``.  Each scan
     iteration is exactly one :func:`decode_step` (same K/V append at the
-    per-slot cursor via ``batched_update``, same int8 dMVM attention), and
+    per-slot cursor, same int8 dMVM attention), and
     ``jnp.argmax`` breaks ties by lowest token id like the host sampler, so
     the emitted block is token-identical to ``m`` host-driven single steps
     — only the per-token host round-trip disappears.  A caller that stops a
@@ -663,7 +697,6 @@ def tree_commit(state: dict, base, sel, keep, pos) -> dict:
     [B] post-commit cursor (= base + 1 + keep for slots that ran a window,
     unchanged elsewhere); rejected branches die in place per the SLC
     write-in-place discipline."""
-    from repro.core import kvcache as KV
     groups = jax.tree.map(lambda leaf: KV.path_gather(leaf, base, sel, keep),
                           state["groups"])
     return {"groups": groups, "pos": jnp.asarray(pos, jnp.int32)}

@@ -60,6 +60,20 @@ class TestSlottedAppend:
             np.asarray(cache.k_q[0, 1, 0]),
             np.asarray(quantize_kv(k2)[0][1, 0]))
 
+    @pytest.mark.parametrize("s", [1, 2, S])
+    def test_append_rows_matches_batched_update(self, s):
+        """The append into the stacked pool at a traced layer lands exactly
+        what ``batched_update`` lands in that layer, clamped rows included,
+        and leaves every other byte as it was."""
+        from repro.core.quant import quantize_kv
+        k_q, _ = quantize_kv(_kv(5)[0])
+        pool = jax.random.randint(jax.random.key(6), (L, B, s, H, D),
+                                  -127, 128, jnp.int32).astype(jnp.int8)
+        pos = jnp.array([0, s - 1, s + 3], jnp.int32)
+        got = jax.jit(KV.append_rows)(pool, jnp.int32(1), k_q, pos)
+        want = pool.at[1].set(KV.batched_update(pool[1], k_q, pos))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     def test_multi_token_append(self):
         """Prefill-style appends (T>1) land contiguously from each slot pos."""
         cache = KV.init_cache(L, B, S, H, D)

@@ -23,6 +23,8 @@ Covers:
 * the top-k pre-select is bit-identical to full-vocab host sampling
   (``lax.top_k``'s tie order matches the host's stable sort).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,6 +57,44 @@ def _eos_at(full, start=2):
 
 
 
+def _check_block_matches_steps(cfg, params, plens, max_len, m):
+    """Prefill one slot per prompt length, then check that the fused
+    ``m``-step block equals ``m`` argmax-fed ``decode_step`` calls, that the
+    cursor advances by ``m``, and that the block state rewound to the
+    sequential cursor decodes on bit for bit."""
+    from repro.models import model as M
+    from repro.models import transformer as T
+    from repro.models.transformer import Runtime
+    rt = Runtime()
+    B = len(plens)
+    state = M.init_decode_state(cfg, B, max_len + m - 1)
+    for b, plen in enumerate(plens):
+        toks = jnp.asarray(np.arange(1, plen + 1)[None], jnp.int32)
+        _, one = M.prefill(params, cfg, {
+            "inputs": toks, "lengths": jnp.array([plen], jnp.int32)},
+            max_len, rt)
+        state = T.write_slot(state, jnp.int32(b), one)
+    tok0 = jnp.arange(3, 3 + 2 * B, 2, dtype=jnp.int32)
+    st, tok, seq = state, tok0, []
+    for _ in range(m):
+        lg, st = M.decode_step(params, cfg, st, tok, rt)
+        tok = jnp.argmax(lg, -1).astype(jnp.int32)
+        seq.append(np.asarray(tok))
+    blk, mstate = M.multi_decode_step(params, cfg, state, tok0, m, rt)
+    np.testing.assert_array_equal(np.asarray(blk), np.stack(seq, axis=1))
+    np.testing.assert_array_equal(np.asarray(mstate["pos"]),
+                                  np.asarray(state["pos"]) + m)
+    for a, b in zip(jax.tree.leaves(mstate["groups"]),
+                    jax.tree.leaves(st["groups"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # overshoot rollback: rewind the fused state to the sequential
+    # cursor and the next decode step must match bit-for-bit
+    rewound = T.rewind_pos(mstate, np.asarray(st["pos"]))
+    lg_a, _ = M.decode_step(params, cfg, rewound, tok, rt)
+    lg_b, _ = M.decode_step(params, cfg, st, tok, rt)
+    np.testing.assert_array_equal(np.asarray(lg_a), np.asarray(lg_b))
+
+
 # ---------------------------------------------------------------------------
 # model level
 # ---------------------------------------------------------------------------
@@ -64,35 +104,20 @@ class TestMultiDecodeStep:
         argmax-fed decode steps, the cursor advances by m, and rewinding
         the block state to the sequential cursor decodes on identically
         (overshoot rollback is exact)."""
-        from repro.models import model as M
-        from repro.models import transformer as T
-        from repro.models.transformer import Runtime
         cfg, params = gqa_setup
-        rt = Runtime()
-        B, max_len, m = 3, 32, 4
-        state = M.init_decode_state(cfg, B, max_len + m - 1)
-        for b, plen in enumerate((4, 6, 5)):
-            toks = jnp.asarray(np.arange(1, plen + 1)[None], jnp.int32)
-            _, one = M.prefill(params, cfg, {
-                "inputs": toks, "lengths": jnp.array([plen], jnp.int32)},
-                max_len, rt)
-            state = T.write_slot(state, jnp.int32(b), one)
-        tok0 = jnp.array([3, 5, 7], jnp.int32)
-        st, tok, seq = state, tok0, []
-        for _ in range(m):
-            lg, st = M.decode_step(params, cfg, st, tok, rt)
-            tok = jnp.argmax(lg, -1).astype(jnp.int32)
-            seq.append(np.asarray(tok))
-        blk, mstate = M.multi_decode_step(params, cfg, state, tok0, m, rt)
-        np.testing.assert_array_equal(np.asarray(blk), np.stack(seq, axis=1))
-        np.testing.assert_array_equal(np.asarray(mstate["pos"]),
-                                      np.asarray(state["pos"]) + m)
-        # overshoot rollback: rewind the fused state to the sequential
-        # cursor and the next decode step must match bit-for-bit
-        rewound = T.rewind_pos(mstate, np.asarray(st["pos"]))
-        lg_a, _ = M.decode_step(params, cfg, rewound, tok, rt)
-        lg_b, _ = M.decode_step(params, cfg, st, tok, rt)
-        np.testing.assert_array_equal(np.asarray(lg_a), np.asarray(lg_b))
+        _check_block_matches_steps(cfg, params, (4, 6, 5), max_len=32, m=4)
+
+    def test_matches_sequential_greedy_decode_mha_to_pool_end(self):
+        """The same on an MHA stack whose head_dim (24) is not a multiple
+        of 128, at four slots, one of which appends into the pool's last
+        row during the block."""
+        from repro.models import model as M
+        cfg = dataclasses.replace(ARCHS["phi3-mini-3.8b"].reduced(),
+                                  head_dim=24)
+        assert cfg.n_kv_heads == cfg.n_heads
+        params = M.init_params(jax.random.key(0), cfg)
+        _check_block_matches_steps(cfg, params, (4, 31, 9, 1), max_len=32,
+                                   m=4)
 
     def test_encdec_rejected(self):
         from repro.models import model as M

@@ -145,3 +145,70 @@ def test_phi3_decode_step_fits_hbm(one_chip, compiled_kernels, backend):
     assert total < V5E_HBM_BYTES, mem
     if backend == "fused_int8":
         _assert_mosaic(compiled)
+
+
+@pytest.mark.parametrize("slots", [1, SLOTS])
+def test_phi3_decode_step_keeps_pool_layout(one_chip, slots):
+    """The decode step appends into the int8 pool in the layout the chip
+    gives it (S minor, so that head_dim 96 is not padded to 128): the
+    compiled step holds no copy of the pool or of a layer's slice of it."""
+    import re
+    cfg = registry.get("phi3-mini-3.8b")
+    params = jax.eval_shape(
+        lambda: quantize_tree(M.init_params(jax.random.key(0), cfg,
+                                            jnp.bfloat16)))
+    state = jax.eval_shape(lambda: M.init_decode_state(cfg, slots, MAX_LEN))
+    place = lambda t: jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), t)
+    rt = Runtime()
+    hlo = jax.jit(
+        lambda p, s, t: M.decode_step(p, cfg, s, t, rt), donate_argnums=(1,)
+    ).lower(place(params), place(state),
+            _sds(one_chip, (slots,), jnp.int32)).compile().as_text()
+    pool = re.compile(r"= s8\[[\d,]*%d,%d,%d\]\S* copy\(" % (
+        MAX_LEN, cfg.n_kv_heads, cfg.head_dim))
+    assert not [l for l in hlo.splitlines() if pool.search(l)]
+
+
+def test_phi3_mesh_decode_step_appends_per_shard(topo):
+    """On the 2x2 serve mesh, with the engine's shardings (slots over
+    `data`, KV heads over `model`), each device appends its own slots' rows:
+    the compiled step moves no pool-sized array between chips and copies
+    none, and the pool spec the append assumes is the one the engine pins."""
+    import re
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.configs.shapes import ShapeConfig
+    from repro.dist import sharding as SH
+    cfg = registry.get("phi3-mini-3.8b")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rt = Runtime(mesh=mesh, data_axes=("data",), serve_resident_moe=True)
+    params = jax.eval_shape(
+        lambda: quantize_tree(M.init_params(jax.random.key(0), cfg,
+                                            jnp.bfloat16)))
+    state = jax.eval_shape(lambda: M.init_decode_state(cfg, SLOTS, MAX_LEN))
+    psh = SH.param_shardings(cfg, params, mesh, serve=True)
+    ssh = SH.decode_state_shardings(
+        cfg, ShapeConfig("serve", MAX_LEN, SLOTS, "decode"), state, mesh)
+    io = SH.serve_step_shardings(SLOTS, mesh)
+    pool_spec = SH.kv_pool_spec(SLOTS, cfg.n_kv_heads, mesh)
+    pools = [s for s in jax.tree.leaves(ssh) if len(s.spec) == 5]
+    assert pools and all(s.spec == pool_spec for s in pools)
+    place = lambda t, sh: jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        t, sh)
+    hlo = jax.jit(
+        lambda p, s, t: M.decode_step(p, cfg, s, t, rt),
+        in_shardings=(psh, ssh, io["tokens"]),
+        out_shardings=(io["logits"], ssh), donate_argnums=(1,),
+    ).lower(place(params, psh), place(state, ssh),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.int32,
+                                 sharding=io["tokens"])).compile().as_text()
+    moved = re.compile(r"= (s8|f32)\[\d+,\d+,%d,\d+,\d+\]\S* (all-gather|"
+                       r"all-reduce|all-to-all|collective-permute|"
+                       r"reduce-scatter)" % MAX_LEN)
+    copied = re.compile(r"= s8\[\d+,\d+,%d,\d+,\d+\]\S* copy\(" % MAX_LEN)
+    lines = hlo.splitlines()
+    assert not [l for l in lines if moved.search(l)]
+    assert not [l for l in lines if copied.search(l)]
