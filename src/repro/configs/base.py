@@ -30,6 +30,18 @@ class ModelConfig:
     attn_type: str = "gqa"             # gqa | mla | none
     rope_theta: float = 10_000.0
     use_qk_norm: bool = False
+    norm_eps: float = 1e-5             # every RMSNorm / LayerNorm
+    # YaRN rotary scaling (MLA's rope dims; 0 = plain RoPE): frequencies
+    # mix theta^(-2i/d) and the same / factor over the ramp that beta_fast
+    # and beta_slow set against the original context; the softmax scale
+    # gains mscale(mscale_all_dim)^2.  YaRN's cos/sin attenuation,
+    # mscale(mscale) / mscale(mscale_all_dim), is 1 for every published
+    # DeepSeek config (the two are equal) and is left out.
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # MLA (DeepSeek-V3)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -48,6 +60,22 @@ class ModelConfig:
     moe_every: int = 1                 # layer i is MoE iff i % moe_every == moe_offset
     moe_offset: int = 0
     first_dense_layers: int = 0
+    # router: softmax top-k, or sigmoid scores (DeepSeek-V3 noaux_tc) whose
+    # selection adds a per-expert correction bias and keeps the experts of
+    # the moe_topk_group best of moe_n_group groups (a group scores the sum
+    # of its two best); weights are the unbiased scores of the chosen,
+    # normalised over them (moe_norm_topk) and times moe_routed_scaling
+    moe_scoring: str = "softmax"       # softmax | sigmoid
+    moe_router_bias: bool = False
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_norm_topk: bool = True
+    moe_routed_scaling: float = 1.0
+    # expert share: this chip holds experts [expert_shard * held,
+    # (expert_shard + 1) * held) of the n_experts it routes over, and adds
+    # only their part (0 = all held; one chip of an expert-parallel group)
+    n_experts_held: int = 0
+    expert_shard: int = 0
 
     # SSM / hybrid
     ssm_state: int = 0
@@ -74,6 +102,11 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.n_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights live here."""
+        return self.n_experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:
@@ -150,7 +183,8 @@ class ModelConfig:
         p += self._ssm_params() if kind == "ssm" else self._attn_params()
         if self.is_moe_layer(i):
             p += self.d_model * self.n_experts          # router
-            p += self.n_experts * self._mlp_params(self.moe_d_ff)
+            p += self.n_experts if self.moe_router_bias else 0
+            p += self.experts_held * self._mlp_params(self.moe_d_ff)
             p += self.n_shared_experts * self._mlp_params(self.moe_d_ff)
         elif kind == "attn" or self.family == "hybrid":
             ff = self.d_ff if self.d_ff else 0
@@ -165,7 +199,7 @@ class ModelConfig:
         total = self.param_count()
         for i in range(self.n_layers):
             if self.is_moe_layer(i):
-                inactive = self.n_experts - self.n_experts_active
+                inactive = max(0, self.experts_held - self.n_experts_active)
                 total -= inactive * self._mlp_params(self.moe_d_ff)
         return total
 
@@ -186,6 +220,11 @@ class ModelConfig:
             n_experts_active=min(self.n_experts_active, 2) if self.n_experts else 0,
             moe_d_ff=128 if self.n_experts else 0,
             first_dense_layers=min(self.first_dense_layers, 1),
+            # a group structure that divides the reduced expert count
+            moe_n_group=min(self.moe_n_group, 2),
+            moe_topk_group=min(self.moe_topk_group, 1),
+            n_experts_held=0,
+            expert_shard=0,
             q_lora_rank=64 if self.q_lora_rank else 0,
             kv_lora_rank=32 if self.kv_lora_rank else 0,
             qk_nope_head_dim=32 if self.qk_nope_head_dim else 0,

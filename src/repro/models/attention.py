@@ -10,6 +10,7 @@ partial-softmax statistics combine across shards via LSE (psum under GSPMD).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -23,6 +24,18 @@ from repro.models import layers as L
 
 Params = dict[str, Any]
 NEG_INF = -1e30
+
+
+def _scoped(name: str):
+    """Run the wrapped layer under ``jax.named_scope(name)``, so that a
+    profile attributes its device time to ``name``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -64,7 +77,8 @@ def _causal_chunk_mask(q_pos, k_pos):
 
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
-                    kv_block: int = 1024, kv_lengths=None) -> jax.Array:
+                    kv_block: int = 1024, kv_lengths=None,
+                    scale: float | None = None) -> jax.Array:
     """Memory-bounded attention: lax.scan over KV blocks with running
     (max, denom) statistics.  q: [B, Tq, H, Dk]; k: [B, Tk, G, Dk];
     v: [B, Tk, G, Dv] with G = kv heads (GQA groups computed natively —
@@ -72,7 +86,8 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
     ``kv_lengths`` ([B] int32, optional) masks keys at and beyond each
     request's true prompt length — ragged right-padded batches attend only
-    to their own valid prefix.
+    to their own valid prefix.  ``scale`` replaces the softmax scale
+    ``1/sqrt(Dk)`` (MLA's YaRN temperature).
     """
     B, Tq, H, Dk = q.shape
     G = k.shape[2]
@@ -87,7 +102,8 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(B, n_blocks, blk, G, Dk).transpose(1, 0, 2, 3, 4)
     vb = v.reshape(B, n_blocks, blk, G, Dv).transpose(1, 0, 2, 3, 4)
-    q5 = (q.astype(jnp.float32) / math.sqrt(Dk)).reshape(B, Tq, G, rep, Dk)
+    q5 = (q.astype(jnp.float32) / math.sqrt(Dk) if scale is None
+          else q.astype(jnp.float32) * scale).reshape(B, Tq, G, rep, Dk)
     q_pos = jnp.arange(Tq) + q_offset
 
     def step(carry, xs):
@@ -135,8 +151,8 @@ def gqa_forward(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, T, cfg.n_kv_heads, hd)
     v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, T, cfg.n_kv_heads, hd)
     if cfg.use_qk_norm:
-        q = L.apply_norm(p["q_norm"], q)
-        k = L.apply_norm(p["k_norm"], k)
+        q = L.apply_norm(p["q_norm"], q, cfg.norm_eps)
+        k = L.apply_norm(p["k_norm"], k, cfg.norm_eps)
     if cfg.rope_theta:
         q = _rope(q, positions, cfg.rope_theta, rt)
         k = _rope(k, positions, cfg.rope_theta, rt)
@@ -148,15 +164,16 @@ def gqa_forward(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
 # ---------------------------------------------------------------------------
 # chunked prefill: consume [B, C] tokens at an arbitrary cursor
 # ---------------------------------------------------------------------------
-def _rope(t: jax.Array, positions: jax.Array, theta: float, rt) -> jax.Array:
+def _rope(t: jax.Array, positions: jax.Array, theta: float, rt,
+          freqs=None) -> jax.Array:
     """RoPE for the prefill paths (atomic and chunked): the partition-safe
     contraction form under a mesh (rotate-half's split+concat
     mis-partitions deferred partial sums, triggering SPMD full-
     rematerialization copies — see :func:`layers.apply_rope_spmd`), the
     bit-exact elementwise form on a single device."""
     if rt is not None and rt.mesh is not None:
-        return L.apply_rope_spmd(t, positions, theta)
-    return L.apply_rope(t, positions, theta)
+        return L.apply_rope_spmd(t, positions, theta, freqs)
+    return L.apply_rope(t, positions, theta, freqs)
 
 
 def gqa_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
@@ -178,8 +195,8 @@ def gqa_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, C, cfg.n_kv_heads, hd)
     v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, C, cfg.n_kv_heads, hd)
     if cfg.use_qk_norm:
-        q = L.apply_norm(p["q_norm"], q)
-        k = L.apply_norm(p["k_norm"], k)
+        q = L.apply_norm(p["q_norm"], q, cfg.norm_eps)
+        k = L.apply_norm(p["k_norm"], k, cfg.norm_eps)
     if cfg.rope_theta:
         q = _rope(q, positions, cfg.rope_theta, rt)
         k = _rope(k, positions, cfg.rope_theta, rt)
@@ -191,6 +208,7 @@ def gqa_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     return out, {"k": k_buf, "v": v_buf}
 
 
+@_scoped("mla")
 def mla_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
               buf: dict, start: jax.Array, kv_lengths: jax.Array,
               rt=None) -> tuple[jax.Array, dict]:
@@ -201,15 +219,18 @@ def mla_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     B, C, _ = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend))
+    freqs = mla_rope_freqs(cfg)
+    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend),
+                         cfg.norm_eps)
     q = L.apply_linear(L._lin(p, "wq_b"), q_lat, backend).reshape(B, C, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = _rope(q_rope, positions, cfg.rope_theta, rt)
+    q_rope = _rope(q_rope, positions, cfg.rope_theta, rt, freqs)
 
     kv_a = L.apply_linear(L._lin(p, "wkv_a"), x, backend)
     c_kv, k_rope = kv_a[..., :cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
-    c_kv = L.apply_norm(p["kv_norm"], c_kv)
-    k_rope = _rope(k_rope[:, :, None, :], positions, cfg.rope_theta, rt)
+    c_kv = L.apply_norm(p["kv_norm"], c_kv, cfg.norm_eps)
+    k_rope = _rope(k_rope[:, :, None, :], positions, cfg.rope_theta, rt,
+                   freqs)
     kv = L.apply_linear(L._lin(p, "wkv_b"), c_kv, backend).reshape(B, C, H, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, C, H, dr))], axis=-1)
@@ -223,7 +244,8 @@ def mla_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     lat_c = KV.chunk_update(buf["lat_c"], c_kv, start)
     lat_r = KV.chunk_update(buf["lat_r"], k_rope[:, :, 0, :], start)
     o = flash_attention(qf, k_buf.astype(qf.dtype), v_buf.astype(qf.dtype),
-                        q_offset=start, kv_lengths=kv_lengths)
+                        q_offset=start, kv_lengths=kv_lengths,
+                        scale=mla_softmax_scale(cfg))
     out = L.apply_linear(L._lin(p, "wo"), o.reshape(B, C, -1), backend)
     return out, {"k": k_buf, "v": v_buf, "lat_c": lat_c, "lat_r": lat_r}
 
@@ -379,8 +401,8 @@ def gqa_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, T, cfg.n_kv_heads, hd)
     v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, T, cfg.n_kv_heads, hd)
     if cfg.use_qk_norm:
-        q = L.apply_norm(p["q_norm"], q)
-        k = L.apply_norm(p["k_norm"], k)
+        q = L.apply_norm(p["q_norm"], q, cfg.norm_eps)
+        k = L.apply_norm(p["k_norm"], k, cfg.norm_eps)
     if cfg.rope_theta:
         off = jnp.arange(T)[None, :] if depth is None else depth
         pp = pos_b[:, None] + off
@@ -410,8 +432,8 @@ def gqa_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
     k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, 1, cfg.n_kv_heads, hd)
     v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, 1, cfg.n_kv_heads, hd)
     if cfg.use_qk_norm:
-        q = L.apply_norm(p["q_norm"], q)
-        k = L.apply_norm(p["k_norm"], k)
+        q = L.apply_norm(p["q_norm"], q, cfg.norm_eps)
+        k = L.apply_norm(p["k_norm"], k, cfg.norm_eps)
     if cfg.rope_theta:
         pp = pos_b[:, None]
         q = L.apply_rope(q, pp, cfg.rope_theta)
@@ -453,6 +475,25 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V3): compressed-latent cache; absorbed decode
 # ---------------------------------------------------------------------------
+def mla_rope_freqs(cfg: ModelConfig):
+    """Inverse frequencies of MLA's rope dims: YaRN's where the config
+    stretches the context, else None (plain ``rope_theta``)."""
+    if not cfg.yarn_factor:
+        return None
+    return L.yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn_factor,
+                        cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                        cfg.yarn_beta_slow)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``1/sqrt(qk_nope + qk_rope)``, times YaRN's ``mscale^2`` where the
+    config stretches the context and sets ``mscale_all_dim``."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _quantize_latent(latent: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-token symmetric int8 for the MLA latent rows ([..., r+dr]).
     Shared by decode and verify so their SLC rows stay bit-identical —
@@ -462,6 +503,9 @@ def _quantize_latent(latent: jax.Array) -> tuple[jax.Array, jax.Array]:
     lq = jnp.clip(jnp.round(latent / sc.astype(latent.dtype)),
                   -127, 127).astype(jnp.int8)
     return lq, sc
+
+
+@_scoped("mla")
 def mla_forward(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
                 backend: str = "dense", lengths: jax.Array | None = None,
                 rt=None):
@@ -471,25 +515,30 @@ def mla_forward(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     B, T, _ = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend))
+    freqs = mla_rope_freqs(cfg)
+    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend),
+                         cfg.norm_eps)
     q = L.apply_linear(L._lin(p, "wq_b"), q_lat, backend).reshape(B, T, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = _rope(q_rope, positions, cfg.rope_theta, rt)
+    q_rope = _rope(q_rope, positions, cfg.rope_theta, rt, freqs)
 
     kv_a = L.apply_linear(L._lin(p, "wkv_a"), x, backend)
     c_kv, k_rope = kv_a[..., :cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
-    c_kv = L.apply_norm(p["kv_norm"], c_kv)
-    k_rope = _rope(k_rope[:, :, None, :], positions, cfg.rope_theta, rt)  # [B,T,1,dr]
+    c_kv = L.apply_norm(p["kv_norm"], c_kv, cfg.norm_eps)
+    k_rope = _rope(k_rope[:, :, None, :], positions, cfg.rope_theta, rt,
+                   freqs)  # [B,T,1,dr]
     kv = L.apply_linear(L._lin(p, "wkv_b"), c_kv, backend).reshape(B, T, H, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, T, H, dr))], axis=-1)
     qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-    o = flash_attention(qf, k, v, kv_lengths=lengths)
+    o = flash_attention(qf, k, v, kv_lengths=lengths,
+                        scale=mla_softmax_scale(cfg))
     out = L.apply_linear(L._lin(p, "wo"), o.reshape(B, T, -1), backend)
     latent = jnp.concatenate([c_kv, k_rope[:, :, 0, :]], axis=-1)
     return out, latent
 
 
+@_scoped("mla")
 def mla_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                c_q: jax.Array, c_s: jax.Array, backend: str = "dense",
                inter_dtype=jnp.float32):
@@ -499,17 +548,20 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     B = x.shape[0]
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    freqs = mla_rope_freqs(cfg)
     r = cfg.kv_lora_rank
     pos_b = KV.slot_positions(pos, B)
-    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend))
+    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend),
+                         cfg.norm_eps)
     q = L.apply_linear(L._lin(p, "wq_b"), q_lat, backend).reshape(B, 1, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     pp = pos_b[:, None]
-    q_rope = L.apply_rope(q_rope, pp, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, pp, cfg.rope_theta, freqs)
 
     kv_a = L.apply_linear(L._lin(p, "wkv_a"), x, backend)
-    c_new = L.apply_norm(p["kv_norm"], kv_a[..., :r])
-    k_rope_new = L.apply_rope(kv_a[:, :, None, r:], pp, cfg.rope_theta)[:, :, 0, :]
+    c_new = L.apply_norm(p["kv_norm"], kv_a[..., :r], cfg.norm_eps)
+    k_rope_new = L.apply_rope(kv_a[:, :, None, r:], pp, cfg.rope_theta,
+                              freqs)[:, :, 0, :]
     latent_new = jnp.concatenate([c_new, k_rope_new], axis=-1)      # [B,1,r+dr]
     lq, sc = _quantize_latent(latent_new)
     c_q = KV.batched_update(c_q, lq, pos_b)
@@ -525,7 +577,7 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                          preferred_element_type=jnp.float32) +
               jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(inter_dtype),
                          cache[..., r:], preferred_element_type=jnp.float32))
-    scores = scores / math.sqrt(dn + dr)
+    scores = scores * mla_softmax_scale(cfg)
     S = c_q.shape[1]
     mask = jnp.arange(S)[None, None, :] < (pos_b + 1)[:, None, None]
     scores = jnp.where(mask, scores, NEG_INF)
@@ -538,6 +590,7 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     return out, (c_q, c_s)
 
 
+@_scoped("mla")
 def mla_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                c_q: jax.Array, c_s: jax.Array, backend: str = "dense",
                inter_dtype=jnp.float32, depth=None, anc=None):
@@ -551,18 +604,21 @@ def mla_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     B, T, _ = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    freqs = mla_rope_freqs(cfg)
     r = cfg.kv_lora_rank
     pos_b = KV.slot_positions(pos, B)
     off = jnp.arange(T)[None, :] if depth is None else depth
     pp = pos_b[:, None] + off
-    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend))
+    q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend),
+                         cfg.norm_eps)
     q = L.apply_linear(L._lin(p, "wq_b"), q_lat, backend).reshape(B, T, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, pp, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, pp, cfg.rope_theta, freqs)
 
     kv_a = L.apply_linear(L._lin(p, "wkv_a"), x, backend)
-    c_new = L.apply_norm(p["kv_norm"], kv_a[..., :r])
-    k_rope_new = L.apply_rope(kv_a[:, :, None, r:], pp, cfg.rope_theta)[:, :, 0, :]
+    c_new = L.apply_norm(p["kv_norm"], kv_a[..., :r], cfg.norm_eps)
+    k_rope_new = L.apply_rope(kv_a[:, :, None, r:], pp, cfg.rope_theta,
+                              freqs)[:, :, 0, :]
     latent_new = jnp.concatenate([c_new, k_rope_new], axis=-1)      # [B,T,r+dr]
     lq, sc = _quantize_latent(latent_new)
     c_q = KV.batched_update(c_q, lq, pos_b)
@@ -578,7 +634,7 @@ def mla_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                          preferred_element_type=jnp.float32) +
               jnp.einsum("bthd,bsd->bths", q_rope.astype(inter_dtype),
                          cache[..., r:], preferred_element_type=jnp.float32))
-    scores = scores / math.sqrt(dn + dr)
+    scores = scores * mla_softmax_scale(cfg)
     S = c_q.shape[1]
     if anc is not None:
         mask = tree_visibility_mask(pos_b, anc, S, T)[:, :, None, :]

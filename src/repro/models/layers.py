@@ -8,10 +8,12 @@ bf16 training path, the W8A8 reference path, or the Pallas PIM kernels.
 """
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import quant
 
@@ -90,10 +92,43 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, T, H, D]; positions: [B, T] (or [T])."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 * mscale * ln(factor) + 1``
+    (1 where the context is not stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float, original: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN inverse frequencies (DeepSeek-V3's rotary embedding): the
+    plain ``theta^(-2i/d)`` below the ramp, the same divided by ``factor``
+    above it, mixed linearly between the pair indices at which a frequency
+    turns ``beta_fast`` and ``beta_slow`` times over ``original``
+    positions."""
+    i = np.arange(0, head_dim, 2, dtype=np.float32) / head_dim
+    extra = (1.0 / theta ** i).astype(np.float32)
+    inter = (extra / factor).astype(np.float32)
+
+    def dim_at(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_at(beta_fast)), 0)
+    high = min(math.ceil(dim_at(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs=None) -> jax.Array:
+    """x: [B, T, H, D]; positions: [B, T] (or [T]).  ``freqs`` ([D/2],
+    optional) replaces the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # [D/2]
+    freqs = rope_freqs(d, theta) if freqs is None else freqs   # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, T, D/2]
     cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -101,7 +136,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def apply_rope_spmd(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def apply_rope_spmd(x: jax.Array, positions: jax.Array, theta: float,
+                    freqs=None) -> jax.Array:
     """Partition-safe RoPE: same rotation as :func:`apply_rope` but written
     as a per-position [D, D] rotation *contraction* instead of rotate-half's
     split+concat.  XLA's SPMD partitioner mis-partitions the concat when
@@ -112,7 +148,7 @@ def apply_rope_spmd(x: jax.Array, positions: jax.Array, theta: float) -> jax.Arr
     O(D) — negligible beside attention, and only paid under a mesh."""
     d = x.shape[-1]
     half = d // 2
-    freqs = rope_freqs(d, theta)                       # [D/2]
+    freqs = rope_freqs(d, theta) if freqs is None else freqs   # [D/2]
     ang = positions[..., None].astype(jnp.float32) * freqs     # [B, T, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     i = jnp.arange(half)

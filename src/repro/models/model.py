@@ -87,14 +87,17 @@ def train_loss(params, cfg: ModelConfig, batch: dict, rt: Runtime):
     return T.lm_loss(params, cfg, batch["inputs"], batch["labels"], rt)
 
 
-def prefill(params, cfg: ModelConfig, batch: dict, max_len: int, rt: Runtime):
+def prefill(params, cfg: ModelConfig, batch: dict, max_len: int, rt: Runtime,
+            counts: bool = False):
     """``batch`` may carry ``lengths`` ([B] int32) for ragged right-padded
-    prompts — threaded through attention masking and last-logit gathering."""
+    prompts — threaded through attention masking and last-logit gathering.
+    ``counts`` adds the MoE routing counters as a third output (see
+    :func:`repro.models.transformer.prefill`)."""
     if cfg.family == "encdec":
         return encdec.prefill(params, cfg, batch["frames"], batch["tokens"],
                               max_len, rt)
     return T.prefill(params, cfg, batch["inputs"], max_len, rt,
-                     lengths=batch.get("lengths"))
+                     lengths=batch.get("lengths"), counts=counts)
 
 
 def init_prefill_carry(cfg: ModelConfig, buf_len: int):
@@ -131,21 +134,23 @@ def finalize_prefill_carry(cfg: ModelConfig, carry: dict, max_len: int):
     return T.finalize_prefill_carry(cfg, carry, max_len)
 
 
-def decode_step(params, cfg: ModelConfig, state: dict, token, rt: Runtime):
+def decode_step(params, cfg: ModelConfig, state: dict, token, rt: Runtime,
+                counts: bool = False):
     if cfg.family == "encdec":
         return encdec.decode_step(params, cfg, state, token, rt)
-    return T.decode_step(params, cfg, state, token, rt)
+    return T.decode_step(params, cfg, state, token, rt, counts=counts)
 
 
 def multi_decode_step(params, cfg: ModelConfig, state: dict, token, m: int,
-                      rt: Runtime):
+                      rt: Runtime, counts: bool = False):
     """Fused multi-step greedy decode: ``m`` decode iterations in one jitted
     scan with the argmax fed back on device -> (tokens [B, m], state).  See
     :func:`repro.models.transformer.multi_decode_step`."""
     if cfg.family == "encdec":
         raise NotImplementedError(
             "fused multi-step decode targets decoder-only LMs")
-    return T.multi_decode_step(params, cfg, state, token, m, rt)
+    return T.multi_decode_step(params, cfg, state, token, m, rt,
+                               counts=counts)
 
 
 def verify_step(params, cfg: ModelConfig, state: dict, tokens, rt: Runtime,

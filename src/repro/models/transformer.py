@@ -177,10 +177,34 @@ def _moe_block(p: Params, x: jax.Array, cfg: ModelConfig, rt: Runtime):
     return out, aux
 
 
+def zero_counts() -> dict:
+    """MoE routing counters before any layer (see
+    :func:`repro.models.moe.moe_local_counted`)."""
+    return {"local_pairs": jnp.zeros((), jnp.int32),
+            "experts_touched": jnp.zeros((), jnp.int32)}
+
+
+def _ffn(p: Params, x: jax.Array, cfg: ModelConfig, rt: Runtime,
+         counts: dict | None, valid=None) -> tuple[jax.Array, dict | None]:
+    """The residual's second half of a decode or prefill layer: the MoE
+    block or the MLP.  Unless ``counts`` is None, one chip's MoE share
+    (no mesh) adds its routing counters of the ``valid`` rows to it."""
+    if "moe" in p:
+        h = L.apply_norm(p["ln2"], x, cfg.norm_eps)
+        if counts is None or rt.mesh is not None:
+            return x + _moe_block(p["moe"], h, cfg, rt)[0], counts
+        mo, _, c = M.moe_share(p["moe"], h, cfg, valid)
+        return x + mo, jax.tree.map(jnp.add, counts, c)
+    if "mlp" in p:
+        x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x, cfg.norm_eps),
+                            cfg.mlp_type, rt.backend)
+    return x, counts
+
+
 def apply_layer_train(p: Params, cfg: ModelConfig, slot: int, x, positions,
                       rt: Runtime):
     kind = cfg.layer_kind(slot)
-    h = L.apply_norm(p["ln1"], x)
+    h = L.apply_norm(p["ln1"], x, cfg.norm_eps)
     if kind == "ssm":
         mix = S.ssm_forward(p["ssm"], cfg, h, backend=rt.backend)
     elif cfg.attn_type == "mla":
@@ -190,11 +214,11 @@ def apply_layer_train(p: Params, cfg: ModelConfig, slot: int, x, positions,
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
-        h2 = L.apply_norm(p["ln2"], x)
+        h2 = L.apply_norm(p["ln2"], x, cfg.norm_eps)
         mo, aux = _moe_block(p["moe"], h2, cfg, rt)
         x = x + mo
     elif "mlp" in p:
-        h2 = L.apply_norm(p["ln2"], x)
+        h2 = L.apply_norm(p["ln2"], x, cfg.norm_eps)
         x = x + L.apply_mlp(p["mlp"], h2, cfg.mlp_type, rt.backend)
     return x, aux
 
@@ -268,7 +292,7 @@ def forward_train(p: Params, cfg: ModelConfig, inputs: jax.Array,
             return (xx, aux), None
         body_fn = jax.checkpoint(body) if rt.remat else body
         (x, aux_total), _ = jax.lax.scan(body_fn, (x, aux_total), slots)
-    x = L.apply_norm(p["ln_f"], x)
+    x = L.apply_norm(p["ln_f"], x, cfg.norm_eps)
     return x, aux_total
 
 
@@ -451,9 +475,10 @@ def _append_kv(caches: dict, idx, rows, pos_b, rt: Runtime) -> dict:
 
 
 def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos,
-                       caches, idx, rt: Runtime):
+                       caches, idx, rt: Runtime, counts: dict | None = None):
     """One decode layer against ``caches``, the layer stack's carried cache
-    (leaves [n_p, B, S, ...]), at layer ``idx``.  Returns (x, caches).
+    (leaves [n_p, B, S, ...]), at layer ``idx``.  Returns (x, caches,
+    counts), the MoE counters added to ``counts`` unless it is None.
 
     A GQA layer appends its int8 K/V rows and scales straight into the
     carried pool (:func:`_append_kv`) and attends over layer ``idx``
@@ -461,7 +486,7 @@ def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos,
     SSM and MLA layers update their layer's slice and write it back."""
     kind = cfg.layer_kind(slot)
     dmvm_dt = rt.dmvm_dtype or jnp.float32
-    h = L.apply_norm(p["ln1"], x)
+    h = L.apply_norm(p["ln1"], x, cfg.norm_eps)
     if kind != "ssm" and cfg.attn_type != "mla":
         pos_b = KV.slot_positions(pos, x.shape[0])
         q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b, rt.backend)
@@ -484,20 +509,16 @@ def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos,
         caches = jax.tree.map(
             lambda full, n: jax.lax.dynamic_update_slice_in_dim(
                 full, n[None].astype(full.dtype), idx, 0), caches, new)
-    x = x + mix
-    if "moe" in p:
-        mo, _ = _moe_block(p["moe"], L.apply_norm(p["ln2"], x), cfg, rt)
-        x = x + mo
-    elif "mlp" in p:
-        x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x), cfg.mlp_type,
-                            rt.backend)
-    return x, caches
+    x, counts = _ffn(p, x + mix, cfg, rt, counts)
+    return x, caches, counts
 
 
 def decode_step(p: Params, cfg: ModelConfig, state: dict, token: jax.Array,
-                rt: Runtime) -> tuple[jax.Array, dict]:
+                rt: Runtime, counts: bool = False):
     """token: [B] (or [B, d] embedding) -> (logits [B, V], new state).
-    ``state["pos"]`` is [B]: slots decode at heterogeneous positions."""
+    ``state["pos"]`` is [B]: slots decode at heterogeneous positions.
+    With ``counts`` a third output holds the step's MoE routing counters
+    summed over its layers (:func:`zero_counts`)."""
     pos = jnp.broadcast_to(jnp.asarray(state["pos"], jnp.int32),
                            (token.shape[0],))
     if cfg.input_mode == "embeddings" and token.ndim == 2:
@@ -507,31 +528,34 @@ def decode_step(p: Params, cfg: ModelConfig, state: dict, token: jax.Array,
     if not cfg.rope_theta:
         x = x + _sinusoid_at(pos, cfg.d_model).astype(x.dtype)[:, None]
     new_groups = []
+    cnt = zero_counts() if counts else None
     for (start, count, period), slots, caches in zip(
             layer_groups(cfg), p["groups"], state["groups"]):
         n_p = jax.tree.leaves(slots[0])[0].shape[0]
 
         def body(carry, xs):
-            xx, full_caches = carry
+            xx, full_caches, c = carry
             slot_trees, idx = xs
             new_full = []
             for s in range(period):
-                xx, full = apply_layer_decode(slot_trees[s], cfg, start + s,
-                                              xx, pos, full_caches[s], idx, rt)
+                xx, full, c = apply_layer_decode(slot_trees[s], cfg, start + s,
+                                                 xx, pos, full_caches[s], idx,
+                                                 rt, c)
                 new_full.append(full)
-            return (xx, tuple(new_full)), None
+            return (xx, tuple(new_full), c), None
 
-        (x, new_caches), _ = jax.lax.scan(
-            body, (x, caches), (slots, jnp.arange(n_p)))
+        (x, new_caches, cnt), _ = jax.lax.scan(
+            body, (x, caches, cnt), (slots, jnp.arange(n_p)))
         new_groups.append(new_caches)
-    x = L.apply_norm(p["ln_f"], x)
+    x = L.apply_norm(p["ln_f"], x, cfg.norm_eps)
     logits = _lm_head(p, cfg, x[:, 0], rt)
-    return logits, {"groups": tuple(new_groups), "pos": pos + 1}
+    state = {"groups": tuple(new_groups), "pos": pos + 1}
+    return (logits, state, cnt) if counts else (logits, state)
 
 
 def multi_decode_step(p: Params, cfg: ModelConfig, state: dict,
                       token: jax.Array, m: int, rt: Runtime,
-                      ) -> tuple[jax.Array, dict]:
+                      counts: bool = False):
     """Fused multi-step greedy decode: run ``m`` :func:`decode_step`
     iterations in one jitted ``lax.scan``, feeding each step's argmax back
     on device — the device-resident decode loop.  ``token`` is the [B]
@@ -553,15 +577,23 @@ def multi_decode_step(p: Params, cfg: ModelConfig, state: dict,
     Works for any stack :func:`decode_step` accepts (the scan body is the
     single step), but engines must not fuse SSM/hybrid stacks: their
     recurrent state cannot rewind, so mid-block stops could not roll back.
+    With ``counts`` a third output sums the ``m`` steps' MoE counters.
     """
     def body(carry, _):
-        tok, st = carry
-        logits, st = decode_step(p, cfg, st, tok, rt)
+        tok, st, c = carry
+        if counts:
+            logits, st, c1 = decode_step(p, cfg, st, tok, rt, counts=True)
+            c = jax.tree.map(jnp.add, c, c1)
+        else:
+            logits, st = decode_step(p, cfg, st, tok, rt)
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        return (nxt, st), nxt
+        return (nxt, st, c), nxt
 
-    (_, new_state), toks = jax.lax.scan(
-        body, (jnp.asarray(token, jnp.int32), state), None, length=m)
+    (_, new_state, cnt), toks = jax.lax.scan(
+        body, (jnp.asarray(token, jnp.int32), state,
+               zero_counts() if counts else None), None, length=m)
+    if counts:
+        return toks.T, new_state, cnt
     return toks.T, new_state                              # [B, m]
 
 
@@ -576,7 +608,7 @@ def apply_layer_verify(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
     ``depth``/``anc`` ([B, T] int32) switch the window to tree mode (see
     :func:`attention.gqa_verify`)."""
     dmvm_dt = rt.dmvm_dtype or jnp.float32
-    h = L.apply_norm(p["ln1"], x)
+    h = L.apply_norm(p["ln1"], x, cfg.norm_eps)
     if cfg.attn_type == "mla":
         mix, (c_q, c_s) = A.mla_verify(p["attn"], cfg, h, pos, cache["c_q"],
                                        cache["c_s"], rt.backend, dmvm_dt,
@@ -587,13 +619,7 @@ def apply_layer_verify(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
             p["attn"], cfg, h, pos, cache["k_q"], cache["k_s"], cache["v_q"],
             cache["v_s"], rt.backend, dmvm_dt, depth=depth, anc=anc)
         new_cache = {"k_q": k_q, "k_s": k_s, "v_q": v_q, "v_s": v_s}
-    x = x + mix
-    if "moe" in p:
-        mo, _ = _moe_block(p["moe"], L.apply_norm(p["ln2"], x), cfg, rt)
-        x = x + mo
-    elif "mlp" in p:
-        x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x), cfg.mlp_type,
-                            rt.backend)
+    x, _ = _ffn(p, x + mix, cfg, rt, None)
     return x, new_cache
 
 
@@ -667,7 +693,7 @@ def verify_step(p: Params, cfg: ModelConfig, state: dict, tokens: jax.Array,
         (x, new_caches), _ = jax.lax.scan(
             body, (x, caches), (slots, jnp.arange(n_p)))
         new_groups.append(new_caches)
-    x = L.apply_norm(p["ln_f"], x)
+    x = L.apply_norm(p["ln_f"], x, cfg.norm_eps)
     logits = _lm_head(p, cfg, x, rt)
     return logits, x, {"groups": tuple(new_groups), "pos": pos + T}
 
@@ -795,7 +821,7 @@ def _sinusoid_at(pos: jax.Array, d: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
             rt: Runtime, lengths: jax.Array | None = None,
-            ) -> tuple[jax.Array, dict]:
+            counts: bool = False):
     """Process a prompt of length T; return (last-token logits, decode state).
 
     The prefill pass is the "GPU stage" of the paper's pipeline: full-width
@@ -808,6 +834,9 @@ def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
     padded tail); SSM/hybrid stacks scan the padding through their recurrent
     state, so ragged prefill for those families should go through per-request
     prefill instead (the serve engine does).
+
+    With ``counts`` a third output holds the MoE routing counters summed
+    over the layers (:func:`zero_counts`), of the rows within ``lengths``.
     """
     x = _embed(p, cfg, inputs)
     B, T = x.shape[:2]
@@ -816,16 +845,19 @@ def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
         lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     state = init_decode_state(cfg, B, max_len)
     new_groups = []
+    cnt = zero_counts() if counts else None
+    real = None if lengths is None else positions < lengths[:, None]
     for (start, count, period), slots, caches in zip(
             layer_groups(cfg), p["groups"], state["groups"]):
-        def body(xx, xs):
+        def body(carry, xs):
+            xx, cn = carry
             slot_trees, slot_caches = xs
             new_c = []
             for s in range(period):
                 slot = start + s
                 kind = cfg.layer_kind(slot)
                 pp = slot_trees[s]
-                h = L.apply_norm(pp["ln1"], xx)
+                h = L.apply_norm(pp["ln1"], xx, cfg.norm_eps)
                 if kind == "ssm":
                     mix, nc = S.ssm_forward(pp["ssm"], cfg, h,
                                             backend=rt.backend,
@@ -868,18 +900,12 @@ def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
                           "k_s": jax.lax.dynamic_update_slice(c["k_s"], k_s, (0, 0, 0, 0)),
                           "v_q": jax.lax.dynamic_update_slice(c["v_q"], v_q, (0, 0, 0, 0)),
                           "v_s": jax.lax.dynamic_update_slice(c["v_s"], v_s, (0, 0, 0, 0))}
-                xx = xx + mix
-                if "moe" in pp:
-                    mo, _ = _moe_block(pp["moe"], L.apply_norm(pp["ln2"], xx), cfg, rt)
-                    xx = xx + mo
-                elif "mlp" in pp:
-                    xx = xx + L.apply_mlp(pp["mlp"], L.apply_norm(pp["ln2"], xx),
-                                          cfg.mlp_type, rt.backend)
+                xx, cn = _ffn(pp, xx + mix, cfg, rt, cn, real)
                 new_c.append(nc)
-            return xx, tuple(new_c)
-        x, new_caches = jax.lax.scan(body, x, (slots, caches))
+            return (xx, cn), tuple(new_c)
+        (x, cnt), new_caches = jax.lax.scan(body, (x, cnt), (slots, caches))
         new_groups.append(new_caches)
-    x = L.apply_norm(p["ln_f"], x)
+    x = L.apply_norm(p["ln_f"], x, cfg.norm_eps)
     if lengths is None:
         last = x[:, -1]
         pos = jnp.full((B,), T, jnp.int32)
@@ -888,7 +914,8 @@ def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
             x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         pos = lengths
     logits = _lm_head(p, cfg, last, rt)
-    return logits, {"groups": tuple(new_groups), "pos": pos}
+    state = {"groups": tuple(new_groups), "pos": pos}
+    return (logits, state, cnt) if counts else (logits, state)
 
 
 # ---------------------------------------------------------------------------
@@ -964,26 +991,19 @@ def prefill_chunk(p: Params, cfg: ModelConfig, carry: dict, tokens: jax.Array,
             new_b = []
             for s in range(period):
                 pp = slot_trees[s]
-                h = L.apply_norm(pp["ln1"], xx)
+                h = L.apply_norm(pp["ln1"], xx, cfg.norm_eps)
                 if cfg.attn_type == "mla":
                     mix, nb = A.mla_chunk(pp["attn"], cfg, h, positions,
                                           slot_bufs[s], pos0, kv_lengths, rt)
                 else:
                     mix, nb = A.gqa_chunk(pp["attn"], cfg, h, positions,
                                           slot_bufs[s], pos0, kv_lengths, rt)
-                xx = xx + mix
-                if "moe" in pp:
-                    mo, _ = _moe_block(pp["moe"], L.apply_norm(pp["ln2"], xx),
-                                       cfg, rt)
-                    xx = xx + mo
-                elif "mlp" in pp:
-                    xx = xx + L.apply_mlp(pp["mlp"], L.apply_norm(pp["ln2"], xx),
-                                          cfg.mlp_type, rt.backend)
+                xx, _ = _ffn(pp, xx + mix, cfg, rt, None)
                 new_b.append(nb)
             return xx, tuple(new_b)
         x, nb = jax.lax.scan(body, x, (slots, bufs))
         new_groups.append(nb)
-    x = L.apply_norm(p["ln_f"], x)
+    x = L.apply_norm(p["ln_f"], x, cfg.norm_eps)
     last = jnp.take_along_axis(
         x, jnp.reshape(n_real - 1, (1, 1, 1)).astype(jnp.int32), axis=1)[:, 0]
     logits = _lm_head(p, cfg, last, rt)

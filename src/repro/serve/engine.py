@@ -98,6 +98,13 @@ class RequestFailedError(RuntimeError):
             f"request {r.rid}: {r.error}" for r in failures))
 
 
+# MoE counters a program returns (models.transformer.zero_counts) -> the
+# engine stats they add to
+_PREFILL_COUNTS = {"local_pairs": "moe_prefill_local_pairs"}
+_DECODE_COUNTS = {"local_pairs": "moe_local_pairs",
+                  "experts_touched": "moe_experts_touched"}
+
+
 def _place_on_mesh(cfg: ModelConfig, params: Any, qparams: Any, rt: Runtime):
     """Land the float (prefill) and QLC (decode) param trees on ``rt.mesh``
     per ``dist.sharding``; returns (params, qparams, qparam_shardings)."""
@@ -258,6 +265,12 @@ class ContinuousBatchingEngine:
         self.qparams = quantize_tree(params) if quantize else params
         self._has_ssm = any(cfg.layer_kind(i) == "ssm"
                             for i in range(cfg.n_layers))
+        # one chip's MoE share counts its routing: the atomic prefill, the
+        # decode step and the fused decode block return the counters, and
+        # they ride the next fetch (engine._fetch)
+        self._moe_counts = self.rt.mesh is None and any(
+            cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+        self._pending_counts: list = []
         # SSM/hybrid stacks keep the exact-length prefill (recurrent-state
         # boundary); attention stacks chunk
         self.chunk = None if (chunk is None or self._has_ssm) else int(chunk)
@@ -391,6 +404,13 @@ class ContinuousBatchingEngine:
                       # counters always exist
                       "timeouts": 0, "slow_steps": 0, "step_failures": 0,
                       "step_retries": 0, "pool_rebuilds": 0}
+        if self._moe_counts:
+            # absent-when-off, like the prefix/swap keys: routed pairs that
+            # landed on the experts held here (prefill; decode) and, per
+            # decode step and MoE layer, the held experts that got a token
+            self.stats.update({"moe_prefill_local_pairs": 0,
+                               "moe_local_pairs": 0,
+                               "moe_experts_touched": 0})
         if self._pcache is not None:
             # keys exist only when the cache is on so downstream record
             # schemas stay backward-compatible (absent, not null, when off)
@@ -479,9 +499,10 @@ class ContinuousBatchingEngine:
         ``T.read_slot``, ``T.copy_slot_prefix``) and ``M.tree_commit`` are
         jitted under their own names."""
         cfg, rt, max_len = self.cfg, self.rt, self.max_len
+        counts = self._moe_counts
 
         def prefill(p, b):
-            return M.prefill(p, cfg, b, max_len, rt)
+            return M.prefill(p, cfg, b, max_len, rt, counts=counts)
 
         def init_prefill_carry():
             return M.init_prefill_carry(cfg, max_len + self.chunk)
@@ -498,10 +519,11 @@ class ContinuousBatchingEngine:
                                         max_len + self.chunk)
 
         def decode_step(p, s, t):
-            return M.decode_step(p, cfg, s, t, rt)
+            return M.decode_step(p, cfg, s, t, rt, counts=counts)
 
         def multi_decode_step(p, s, t):
-            return M.multi_decode_step(p, cfg, s, t, self.multi_step, rt)
+            return M.multi_decode_step(p, cfg, s, t, self.multi_step, rt,
+                                       counts=counts)
 
         def verify_step(p, s, t):
             return M.verify_step(p, cfg, s, t, rt)
@@ -675,11 +697,19 @@ class ContinuousBatchingEngine:
     # reduction can group by name.
     def _fetch(self, x, decode: bool = False):
         """Explicit device->host fetch (counted): the host blocks until the
-        device has produced ``x`` and copied it back (``wait_s``)."""
+        device has produced ``x`` and copied it back (``wait_s``).  MoE
+        counters of programs dispatched since the last fetch come back in
+        the same transfer and add to ``stats``."""
+        pending, self._pending_counts = self._pending_counts, []
         t0 = time.perf_counter()
         with TraceAnnotation("engine.fetch"):
-            out = jax.device_get(x)
+            out = jax.device_get((x, pending) if pending else x)
         dt = time.perf_counter() - t0
+        if pending:
+            out, got = out
+            for counts in got:
+                for name, v in counts.items():
+                    self.stats[name] += int(v)
         self.stats["wait_s"] += dt
         n = sum(a.nbytes for a in jax.tree.leaves(out))
         self.stats["xfer_bytes"] += n
@@ -697,6 +727,17 @@ class ContinuousBatchingEngine:
             if sharding is not None:
                 return jax.device_put(arr, sharding)
             return jax.device_put(arr)
+
+    def _counted(self, out, stats: dict[str, str]):
+        """A MoE program's outputs less its counters, which wait for the
+        next fetch and then add to ``self.stats``, each counter under the
+        name ``stats`` gives it."""
+        if not self._moe_counts:
+            return out
+        *out, counts = out
+        self._pending_counts.append(
+            {name: counts[key] for key, name in stats.items()})
+        return tuple(out)
 
     def _dev(self, fn, *args):
         """Enqueue a jitted program (``dispatch_s``); returns before the
@@ -871,7 +912,9 @@ class ContinuousBatchingEngine:
         if padded != plen or not self._has_ssm:
             batch["lengths"] = jnp.array([plen], jnp.int32)
         try:
-            logits, one = self._dev(self._prefill, self.params, batch)
+            logits, one = self._counted(
+                self._dev(self._prefill, self.params, batch),
+                _PREFILL_COUNTS)
             self.state = self._dev(self._write, self.state,
                                    jnp.int32(req.slot), one)
         except Exception as e:                        # noqa: BLE001
@@ -1427,10 +1470,11 @@ class ContinuousBatchingEngine:
         if self._can_fuse(dec):
             self._multi_decode(dec)
             return True
-        logits, self.state = self._dev(
+        logits, self.state = self._counted(self._dev(
             self._decode, self.qparams, self.state,
             self._push(self._last_tok,
-                       self._io and self._io["tokens"], decode=True))
+                       self._io and self._io["tokens"], decode=True)),
+            _DECODE_COUNTS)
         nxt = self._next_tokens(logits, dec)
         with TraceAnnotation("engine.emit", slots=len(dec)):
             now = self._now()
@@ -1479,10 +1523,11 @@ class ContinuousBatchingEngine:
         m = self.multi_step
         self.stats["decode_steps"] += m - 1       # step() counted one
         self.stats["multi_blocks"] += 1
-        blk_dev, self.state = self._dev(
+        blk_dev, self.state = self._counted(self._dev(
             self._multi, self.qparams, self.state,
             self._push(self._last_tok,
-                       self._io and self._io["tokens"], decode=True))
+                       self._io and self._io["tokens"], decode=True)),
+            _DECODE_COUNTS)
         blk = self._fetch(blk_dev, decode=True)   # [n_slots, m] int32
         with TraceAnnotation("engine.emit", slots=len(dec)):
             now = self._now()

@@ -56,7 +56,7 @@ def _reference_decode_step(p, cfg, state, token, rt):
                 cache = jax.tree.map(
                     lambda a: jax.lax.dynamic_index_in_dim(
                         a, idx, 0, keepdims=False), full_caches[s])
-                h = L.apply_norm(lp["ln1"], xx)
+                h = L.apply_norm(lp["ln1"], xx, cfg.norm_eps)
                 if cfg.layer_kind(start + s) == "ssm":
                     mix, new = SSM.ssm_decode(lp["ssm"], cfg, h, cache,
                                               rt.backend)
@@ -73,11 +73,11 @@ def _reference_decode_step(p, cfg, state, token, rt):
                 xx = xx + mix
                 if "moe" in lp:
                     mo, _ = T._moe_block(lp["moe"],
-                                         L.apply_norm(lp["ln2"], xx), cfg, rt)
+                                         L.apply_norm(lp["ln2"], xx, cfg.norm_eps), cfg, rt)
                     xx = xx + mo
                 elif "mlp" in lp:
                     xx = xx + L.apply_mlp(lp["mlp"],
-                                          L.apply_norm(lp["ln2"], xx),
+                                          L.apply_norm(lp["ln2"], xx, cfg.norm_eps),
                                           cfg.mlp_type, rt.backend)
                 new_full.append(jax.tree.map(
                     lambda full, n: jax.lax.dynamic_update_slice_in_dim(
@@ -88,7 +88,7 @@ def _reference_decode_step(p, cfg, state, token, rt):
         (x, new_caches), _ = jax.lax.scan(
             body, (x, caches), (slots, jnp.arange(n_p)))
         new_groups.append(new_caches)
-    x = L.apply_norm(p["ln_f"], x)
+    x = L.apply_norm(p["ln_f"], x, cfg.norm_eps)
     logits = T._lm_head(p, cfg, x[:, 0], rt)
     return logits, {"groups": tuple(new_groups), "pos": pos + 1}
 

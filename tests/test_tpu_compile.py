@@ -212,3 +212,44 @@ def test_phi3_mesh_decode_step_appends_per_shard(topo):
     lines = hlo.splitlines()
     assert not [l for l in lines if moved.search(l)]
     assert not [l for l in lines if copied.search(l)]
+
+
+def test_deepseek_share_serves_in_hbm(one_chip):
+    """One EP32 chip's share of DeepSeek-V3 (3 dense and 4 MoE layers of 8
+    held experts, an eighth of the vocabulary) at published widths: the
+    bf16 prefill weights, the W8A8 decode weights and the 32-slot latent
+    pool sit in one v5e beside the larger of the counted decode step and
+    the longest prefill's temporaries."""
+    import dataclasses
+    cfg = dataclasses.replace(registry.get("deepseek-v3-671b"), n_layers=7,
+                              n_experts_held=8, expert_shard=5,
+                              vocab_size=16160, mtp=False)
+    params = jax.eval_shape(
+        lambda: M.init_params(jax.random.key(0), cfg, jnp.bfloat16))
+    qparams = jax.eval_shape(quantize_tree, params)
+    state = jax.eval_shape(lambda: M.init_decode_state(
+        cfg, 32, 2048 + KV.pool_headroom()))
+    place = lambda t: jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), t)
+    rt = Runtime()
+    decode = jax.jit(
+        lambda p, s, t: M.decode_step(p, cfg, s, t, rt, counts=True),
+        donate_argnums=(1,)).lower(
+            place(qparams), place(state),
+            _sds(one_chip, (32,), jnp.int32)).compile().memory_analysis()
+    batch = {"inputs": _sds(one_chip, (1, 1536), jnp.int32),
+             "lengths": _sds(one_chip, (1,), jnp.int32)}
+    prefill = jax.jit(
+        lambda p, b: M.prefill(p, cfg, b, 2048, rt, counts=True)).lower(
+            place(params), batch).compile().memory_analysis()
+    nbytes = lambda leaves: sum(a.size * a.dtype.itemsize for a in leaves)
+    # qparams keep the float leaves they do not quantize (embedding, head,
+    # norms, router) as the same arrays as params: only the int8 weights
+    # and their scales are new
+    new = [a for path, a in jax.tree_util.tree_flatten_with_path(qparams)[0]
+           if str(path[-1].key).endswith(("_q", "_s"))]
+    resident = (nbytes(jax.tree.leaves(params)) + nbytes(new)
+                + nbytes(jax.tree.leaves(state)))
+    assert decode.alias_size_in_bytes > 0        # the pool updates in place
+    peak = resident + max(decode.temp_size_in_bytes, prefill.temp_size_in_bytes)
+    assert peak < V5E_HBM_BYTES, (resident, decode, prefill)
