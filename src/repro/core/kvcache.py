@@ -15,9 +15,17 @@ retires; the backing buffers never reallocate, so ``cache_bytes`` is
 invariant under slot churn.
 
 Layouts (per layer, stacked over layers as the leading axis):
-  k_q, v_q     : [L, B, S_max, H_kv, D_h]  int8
-  k_s, v_s     : [L, B, S_max, H_kv, 1]    f32
-  (MLA latent) : [L, B, S_max, C_latent]   int8 (+ scale)
+  k_q, v_q     : [L, B, S_max, H_kv·D_h]  int8, lane-dense — heads and
+                 head_dim merged in the minor axis, S on the sublane axis,
+                 so a token's append is a one-row write and no head_dim
+                 pads to 128
+  k_s, v_s     : [L, B, S_max, H_kv, 1]   f32
+  (MLA latent) : [L, B, S_max, C_latent]  int8 (+ [.., 1] f32 scale)
+Every GQA cache takes that layout: the decode pool
+(``transformer.init_decode_state``), the encoder-decoder's self- and
+cross-attention caches and the :class:`KVCache` reference model.  K/V are
+computed per head ([.., H_kv, D_h]); :func:`merge_heads` and
+:func:`split_heads` are the one conversion each way.
 SSM layers instead carry a fixed-size recurrent state — the most
 flash-write-friendly cache of all (constant footprint; see DESIGN.md).
 """
@@ -38,6 +46,25 @@ def slot_positions(pos: jax.Array | int, batch: int) -> jax.Array:
     if pos.ndim == 0:
         pos = pos[None]
     return jnp.broadcast_to(pos, (batch,))
+
+
+def merge_heads(x: jax.Array) -> jax.Array:
+    """Per-head K/V rows ``[..., H_kv, D_h]`` -> the cache's lane-dense
+    ``[..., H_kv·D_h]`` (a reshape: the bytes are the same)."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def split_heads(x: jax.Array, n_kv_heads: int) -> jax.Array:
+    """Lane-dense cache rows ``[..., H_kv·D_h]`` -> per head
+    ``[..., H_kv, D_h]``: the inverse of :func:`merge_heads`."""
+    return x.reshape(x.shape[:-1] + (n_kv_heads, -1))
+
+
+def quantize_rows(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Per-(token, head) int8 quantization of ``[..., H_kv, D_h]`` float
+    K/V -> (lane-dense int8 rows, ``[..., H_kv, 1]`` f32 scales)."""
+    x_q, x_s = quantize_kv(x)
+    return merge_heads(x_q), x_s
 
 
 def batched_update(buf: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
@@ -64,27 +91,18 @@ def append_rows(pool: jax.Array, layer: jax.Array | int, new: jax.Array,
 
     pool: [L, B, S, ...]; new: [B, 1, ...]; pos: [B] int32 (clamped to
     ``S - 1`` as :func:`batched_update` clamps).  Each slot's write is a
-    ``dynamic_update_slice`` two rows deep, ``pos[b]`` and a neighbour that
-    keeps its own bytes, so the append takes place in the carried pool and
-    nothing is sliced out and written back.  Two rows, not one: the TPU
-    keeps an int8 pool whose head_dim is not a multiple of 128 with S as
-    the minor axis (no lane padding), and an update one row deep in S makes
-    XLA carry the whole pool in row-major order instead, at the cost of two
-    pool-sized relayout copies per call.
+    one-row ``dynamic_update_slice`` into the carried pool, so nothing is
+    sliced out and written back.  In the lane-dense int8 leaves S is the
+    sublane axis and the row is a sublane write of ``H_kv·D_h`` bytes.
     """
     B, S = pool.shape[1:3]
-    pos = slot_positions(pos, B)
+    pos = jnp.clip(slot_positions(pos, B), 0, S - 1)
     layer = jnp.asarray(layer, jnp.int32)
-    w = min(2, S)
-    rest = pool.shape[3:]
+    rest = (jnp.int32(0),) * (pool.ndim - 3)
     for b in range(B):
-        p = jnp.clip(pos[b], 0, S - 1)
-        r = jnp.minimum(p, S - w)
-        at = (layer, jnp.int32(b), r) + (jnp.int32(0),) * len(rest)
-        old = jax.lax.dynamic_slice(pool, at, (1, 1, w) + rest)
-        hit = (jnp.arange(w) == p - r).reshape((1, 1, w) + (1,) * len(rest))
         pool = jax.lax.dynamic_update_slice(
-            pool, jnp.where(hit, new[b].astype(pool.dtype), old), at)
+            pool, new[None, b:b + 1].astype(pool.dtype),
+            (layer, jnp.int32(b), pos[b]) + rest)
     return pool
 
 
@@ -187,8 +205,7 @@ def append_layer_chunk(cache: "KVCache", layer: int, k: jax.Array,
     """Chunked-prefill append of ``[B, C, H_kv, D_h]`` float k/v into one
     layer of the slotted cache at sequence offset ``start`` (quantized on
     the way in, like :func:`append_layer`)."""
-    k_q, k_s = quantize_kv(k)
-    v_q, v_s = quantize_kv(v)
+    (k_q, k_s), (v_q, v_s) = quantize_rows(k), quantize_rows(v)
     return dataclasses.replace(
         cache,
         k_q=cache.k_q.at[layer].set(chunk_update(cache.k_q[layer], k_q, start)),
@@ -218,7 +235,7 @@ class KVCache:
 
 def init_cache(n_layers: int, n_slots: int, max_len: int, n_kv_heads: int,
                head_dim: int) -> KVCache:
-    shape = (n_layers, n_slots, max_len, n_kv_heads, head_dim)
+    shape = (n_layers, n_slots, max_len, n_kv_heads * head_dim)
     sshape = (n_layers, n_slots, max_len, n_kv_heads, 1)
     return KVCache(
         k_q=jnp.zeros(shape, jnp.int8),
@@ -236,8 +253,7 @@ def append_layer(cache: KVCache, layer: int, k: jax.Array, v: jax.Array,
     ``pos`` may be a scalar (all slots aligned — the single-batch paper
     setting) or a [B] vector of heterogeneous per-slot positions.
     """
-    k_q, k_s = quantize_kv(k)
-    v_q, v_s = quantize_kv(v)
+    (k_q, k_s), (v_q, v_s) = quantize_rows(k), quantize_rows(v)
     return dataclasses.replace(
         cache,
         k_q=cache.k_q.at[layer].set(batched_update(cache.k_q[layer], k_q, pos)),

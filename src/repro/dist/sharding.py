@@ -408,21 +408,29 @@ def swap_row_shardings(mesh) -> dict:
     return {"slot": r, "row": r}
 
 
-def kv_pool_spec(n_slots: int, n_kv_heads: int, mesh) -> P:
-    """PartitionSpec of a [n_p, B, S, H_kv, D] int8 K/V pool leaf (and of
-    its [n_p, B, S, H_kv, 1] scales), as :func:`decode_state_shardings`
-    lays it: slots over the data axes, KV heads over `model` when each
-    tiles its dim."""
-    return P(None, batch_entry(n_slots, mesh), None,
-             _fit(mesh, n_kv_heads, MODEL_AXIS), None)
+def kv_pool_spec(n_slots: int, n_kv_heads: int, mesh) -> dict:
+    """PartitionSpecs of the GQA pool leaves, as
+    :func:`decode_state_shardings` lays them: slots over the data axes;
+    the lane-dense ``[n_p, B, S, H_kv·D]`` int8 rows split their merged
+    axis, and the ``[n_p, B, S, H_kv, 1]`` scales their KV-head axis, over
+    `model` when the KV heads tile it, so each device holds whole KV-head
+    groups."""
+    b = batch_entry(n_slots, mesh)
+    m = _fit(mesh, n_kv_heads, MODEL_AXIS)
+    rows, scales = P(None, b, None, m), P(None, b, None, m, None)
+    return {"k_q": rows, "k_s": scales, "v_q": rows, "v_s": scales}
 
 
 def decode_state_shardings(cfg: ModelConfig, shape: ShapeConfig,
                            state_abs: Any, mesh):
     """Slot-pool decode state: the batch/slot axis (dim 1 of every cache
-    leaf, under the layer-stack dim) shards over the data axes; GQA KV
-    heads additionally shard over `model` when they tile it.  ``pos`` and
-    other per-slot scalars replicate (they feed control flow)."""
+    leaf, under the layer-stack dim) shards over the data axes.  The
+    lane-dense int8 K/V rows (told by name: ``k_q``/``v_q``, and the
+    encoder-decoder's cross-attention ``xk_q``/``xv_q``) split their merged
+    ``H_kv·D`` axis over `model` when the KV heads tile it, as
+    :func:`kv_pool_spec` lays them; 5-dim leaves (the rows' per-head
+    scales, SSM state) shard dim 3 likewise.  ``pos`` and other per-slot
+    scalars replicate (they feed control flow)."""
     b = batch_entry(shape.global_batch, mesh)
 
     def leaf_sharding(path_keys, x):
@@ -431,7 +439,9 @@ def decode_state_shardings(cfg: ModelConfig, shape: ShapeConfig,
         entries = [None] * x.ndim
         if x.shape[1] == shape.global_batch:
             entries[1] = b
-        if x.ndim == 5:                  # [n_p, B, S, H_kv, D] int8 KV rows
+        if path_keys[-1] in ("k_q", "v_q", "xk_q", "xv_q"):
+            entries[3] = _fit(mesh, cfg.n_kv_heads, MODEL_AXIS)
+        elif x.ndim == 5:
             entries[3] = _fit(mesh, x.shape[3], MODEL_AXIS)
         return NamedSharding(mesh, P(*entries))
 

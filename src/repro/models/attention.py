@@ -253,42 +253,90 @@ def mla_chunk(p: Params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
 # ---------------------------------------------------------------------------
 # decode attention against the int8 SLC cache (dMVM)
 # ---------------------------------------------------------------------------
+def _pv_rows(w: jax.Array, v_q: jax.Array, inter_dtype) -> jax.Array:
+    """``w`` [B, T, H, S] f32 weights against ``v_q`` [B, S, C] int8 rows
+    -> [B, T, H, C] f32, every head against every lane.
+
+    With an f32 ``inter_dtype`` the weights split into three bf16 parts
+    whose sum is ``w`` exactly (8 + 8 + 8 significand bits), and an int8
+    value is exact in bf16, so every product is exact and accumulates in
+    f32: the arithmetic of an f32 dot at HIGHEST precision, as one bf16
+    MXU dot whose row axis carries the three parts (the rows of V are
+    read once) and whose sum over them fuses into its output.  A lower
+    ``inter_dtype`` (bf16 dMVM) is one dot in that type."""
+    if jnp.dtype(inter_dtype) != jnp.float32:
+        return jnp.einsum("bths,bsc->bthc", w.astype(inter_dtype),
+                          v_q.astype(inter_dtype),
+                          preferred_element_type=jnp.float32)
+    hi = w.astype(jnp.bfloat16)
+    rest = w - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    parts = jnp.stack([hi, mid, lo], axis=2)               # [B,T,3,H,S]
+    return jnp.einsum("btkhs,bsc->btkhc", parts, v_q.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32).sum(axis=2)
+
+
+def _lane_dense_attend(q: jax.Array, k_q, k_s, v_q, v_s, mask: jax.Array,
+                       inter_dtype) -> jax.Array:
+    """int8 dMVM of ``q`` ([B, T, H, D] float) over a lane-dense cache:
+    ``k_q``/``v_q`` [B, S, Hkv·D] int8 (heads and head_dim merged in the
+    minor axis), ``k_s``/``v_s`` [B, S, Hkv, 1] f32; ``mask`` broadcasts
+    to [B, T, H, S].
+
+    Each head's int8 q sits in its KV group's D lanes of an otherwise zero
+    [Hkv·D] row, so QK^T is one int8 dot of those rows against the cache
+    rows as they lie (int32 accumulate, exact).  PV dots the softmax
+    weights, V scales folded in per head, against the cache rows
+    (:func:`_pv_rows`), then keeps each head's own D lanes; the barrier
+    stops XLA from fusing that pick back into the dot as a grouped
+    convolution, which would copy the V slab.  Nothing reads the cache in
+    any other layout.  Both dots do Hkv times the multiply-adds a
+    per-head layout needs, over the same bytes of K/V."""
+    B, T, H, D = q.shape
+    G = k_s.shape[2]
+    own = (jnp.arange(H)[:, None] // (H // G)
+           == jnp.arange(G)[None, :])[..., None]            # [H, G, 1]
+    q_q, q_scale = quant.quantize_kv(q)                    # per-(B,T,H)
+    q_lanes = jnp.where(own, q_q[:, :, :, None, :],
+                        jnp.int8(0)).reshape(B, T, H, G * D)
+    s_int = jnp.einsum("bthc,bsc->bths", q_lanes, k_q,
+                       preferred_element_type=jnp.int32)
+    per_head = lambda s: jnp.repeat(s[..., 0].transpose(0, 2, 1), H // G,
+                                    axis=1)[:, None]       # [B,1,H,S]
+    scores = (s_int.astype(jnp.float32) * q_scale * per_head(k_s)
+              / math.sqrt(D))
+    scores = jnp.where(mask, scores, NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)                    # controller op, fp32
+    o_all = jax.lax.optimization_barrier(
+        _pv_rows(w * per_head(v_s), v_q, inter_dtype))     # [B,T,H,Hkv·D]
+    return jnp.where(own, o_all.reshape(B, T, H, G, D), 0.0).sum(axis=3)
+
+
 def decode_attention_int8(q: jax.Array, k_q, k_s, v_q, v_s, length: jax.Array,
                           backend: str = "dense",
                           inter_dtype=jnp.float32) -> jax.Array:
-    """q: [B, 1, H, D] float; cache: [B, S, Hkv, D] int8 (+[B, S, Hkv, 1] f32).
+    """q: [B, 1, H, D] float; cache: [B, S, Hkv·D] int8 lane-dense rows
+    (:mod:`repro.core.kvcache`) with [B, S, Hkv, 1] f32 scales.
 
     QK^T as integer VVMs (q quantized per-head), SV as the row-wise product:
-    softmax weights scatter over V rows, never transposing the S axis.
-    GQA groups are computed natively (no cache replication).  ``length`` is a
-    scalar (aligned batch) or a [B] vector of per-slot cache lengths
-    (continuous batching: every slot masks to its own resident prefix).
+    softmax weights scatter over V rows, never transposing the S axis
+    (:func:`_lane_dense_attend`).  GQA groups are computed natively (no
+    cache replication).  ``length`` is a scalar (aligned batch) or a [B]
+    vector of per-slot cache lengths (continuous batching: every slot masks
+    to its own resident prefix).
     """
+    B = q.shape[0]
     if backend in ("fused_int8", "pallas"):
         from repro.kernels.decode_attn import ops as da_ops
-        return da_ops.decode_attention(q, k_q, k_s, v_q, v_s, length)
-    B, _, H, D = q.shape
+        G = k_s.shape[2]
+        return da_ops.decode_attention(q, KV.split_heads(k_q, G), k_s,
+                                       KV.split_heads(v_q, G), v_s, length)
     lengths = KV.slot_positions(length, B)
-    G = k_q.shape[2]
-    rep = H // G
-    qh = q.reshape(B, H, D)
-    q_q, q_scale = quant.quantize_kv(qh)                 # per-(B,H) int8
-    q_q = q_q.reshape(B, G, rep, D)
-    q_scale = q_scale.reshape(B, G, rep, 1)
-    # int8 operands straight into the dot (MXU s8xs8->s32); casting first
-    # would materialise a 4x copy of the K cache
-    s_int = jnp.einsum("bgrd,bsgd->bgrs", q_q, k_q,
-                       preferred_element_type=jnp.int32)
-    k_sc = k_s[..., 0].transpose(0, 2, 1)[:, :, None, :]   # [B,G,1,S]
-    scores = s_int.astype(jnp.float32) * q_scale * k_sc / math.sqrt(D)
     S = k_q.shape[1]
     mask = jnp.arange(S)[None, None, None, :] < lengths[:, None, None, None]
-    scores = jnp.where(mask, scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)                  # controller op, fp32
-    vf = (v_q.astype(inter_dtype) * v_s.astype(inter_dtype))   # [B,S,G,D]
-    o = jnp.einsum("bgrs,bsgd->bgrd", w.astype(inter_dtype), vf,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, 1, H, D).astype(q.dtype)
+    o = _lane_dense_attend(q, k_q, k_s, v_q, v_s, mask, inter_dtype)
+    return o.astype(q.dtype)
 
 
 def tree_visibility_mask(pos_b: jax.Array, anc: jax.Array, S: int,
@@ -318,9 +366,9 @@ def verify_attention_int8(q: jax.Array, k_q, k_s, v_q, v_s, pos: jax.Array,
 
     Query ``t`` of slot ``b`` attends keys ``[0, pos[b]+t]`` — the per-row
     causal mask that makes one batched pass score every draft position
-    exactly as T sequential decode steps would.  The T axis folds into the
-    GQA ``rep`` axis so the integer dMVM einsums are *structurally
-    identical* to the T=1 decode: int8xint8 scores are exact integer
+    exactly as T sequential decode steps would.  The T query rows run
+    through the same dMVM einsums as the T=1 decode
+    (:func:`_lane_dense_attend`): int8xint8 scores are exact integer
     arithmetic, so acceptance decisions match step-by-step decode
     bit-for-bit.
 
@@ -340,63 +388,33 @@ def verify_attention_int8(q: jax.Array, k_q, k_s, v_q, v_s, pos: jax.Array,
     pos_b = KV.slot_positions(pos, B)
     if backend in ("fused_int8", "pallas"):
         from repro.kernels.decode_attn import ops as da_ops
+        G = k_s.shape[2]
+        k_q, v_q = KV.split_heads(k_q, G), KV.split_heads(v_q, G)
         if anc is not None:
             return da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s,
                                                 pos_b, anc)
         return da_ops.verify_attention(q, k_q, k_s, v_q, v_s, pos_b)
-    G = k_q.shape[2]
-    rep = H // G
-    q_q, q_scale = quant.quantize_kv(q.reshape(B, T * H, D))  # per-(B,T,H)
-    q_q = (q_q.reshape(B, T, G, rep, D).transpose(0, 2, 1, 3, 4)
-           .reshape(B, G, T * rep, D))
-    q_scale = (q_scale.reshape(B, T, G, rep, 1).transpose(0, 2, 1, 3, 4)
-               .reshape(B, G, T * rep, 1))
-    s_int = jnp.einsum("bgrd,bsgd->bgrs", q_q, k_q,
-                       preferred_element_type=jnp.int32)
-    k_sc = k_s[..., 0].transpose(0, 2, 1)[:, :, None, :]   # [B,G,1,S]
-    scores = s_int.astype(jnp.float32) * q_scale * k_sc / math.sqrt(D)
     S = k_q.shape[1]
     if anc is not None:
-        m3 = tree_visibility_mask(pos_b, anc, S, T)        # [B,T,S]
-        mask = (jnp.broadcast_to(m3[:, None, :, None, :], (B, G, T, rep, S))
-                .reshape(B, G, T * rep, S))
+        mask = tree_visibility_mask(pos_b, anc, S, T)[:, :, None, :]
     else:
-        # row r = (t, rep) attends keys [0, pos + t]
-        t_of_row = jnp.arange(T * rep) // rep
-        limit = pos_b[:, None, None, None] + t_of_row[None, None, :, None] + 1
-        mask = jnp.arange(S)[None, None, None, :] < limit
-    scores = jnp.where(mask, scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)                    # controller op
-    vf = (v_q.astype(inter_dtype) * v_s.astype(inter_dtype))
-    o = jnp.einsum("bgrs,bsgd->bgrd", w.astype(inter_dtype), vf,
-                   preferred_element_type=jnp.float32)
-    o = o.reshape(B, G, T, rep, D).transpose(0, 2, 1, 3, 4)
-    return o.reshape(B, T, H, D).astype(q.dtype)
+        # query t attends keys [0, pos + t]
+        limit = pos_b[:, None] + jnp.arange(T)[None, :] + 1      # [B,T]
+        mask = jnp.arange(S)[None, None, None, :] < limit[:, :, None, None]
+    o = _lane_dense_attend(q, k_q, k_s, v_q, v_s, mask, inter_dtype)
+    return o.astype(q.dtype)
 
 
-def gqa_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
-               k_q, k_s, v_q, v_s, backend: str = "dense",
-               inter_dtype=jnp.float32, depth=None, anc=None):
-    """Multi-token decode for the speculative verify step: consume ``x``
-    ([B, T, d], the last committed token plus T-1 drafts per slot) at each
-    slot's cursor.  The T tokens' int8 K/V land at the per-slot offset in
-    one multi-token :func:`KV.batched_update` — the same SLC append
-    discipline chunked prefill uses (:func:`KV.chunk_update`), vectorised
-    over slots — and all T positions are scored in one pass.  K/V rows and
-    integer scores are bit-identical to T sequential :func:`gqa_decode`
-    calls, which is what makes greedy speculative decode token-identical
-    to the plain engine.
-
-    Tree mode (``depth``/``anc`` both [B, T] int32): the T tokens are draft
-    *tree* nodes — node i's row still lands at cache offset ``pos + i``,
-    but RoPE rotates it at its tree depth (``pos + depth[b, i]``) and the
-    stepped mask becomes the ancestor mask, so each node's K row and
-    scores match what sequential decode of its root-path would produce
-    (chain-prefix nodes bit-exactly; past a skipped sibling, up to float
-    reduction order — see :func:`verify_attention_int8`)."""
+def gqa_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
+                    positions: jax.Array, backend: str = "dense"):
+    """The projections of decode tokens: ``x`` [B, T, d] at ``positions``
+    ([B, T]; one token at ``pos_b[:, None]``, a verify window at the
+    cursor plus its offsets or tree depths) -> (q [B, T, H, D],
+    (k_q, k_s, v_q, v_s)), the tokens' int8 K/V rows in the pool's
+    lane-dense layout ([B, T, Hkv·D]) and their f32 scales
+    ([B, T, Hkv, 1]) for the caller to append."""
     B, T, _ = x.shape
     hd = cfg.head_dim
-    pos_b = KV.slot_positions(pos, B)
     q = L.apply_linear(L._lin(p, "wq"), x, backend).reshape(B, T, cfg.n_heads, hd)
     k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, T, cfg.n_kv_heads, hd)
     v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, T, cfg.n_kv_heads, hd)
@@ -404,59 +422,24 @@ def gqa_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
         q = L.apply_norm(p["q_norm"], q, cfg.norm_eps)
         k = L.apply_norm(p["k_norm"], k, cfg.norm_eps)
     if cfg.rope_theta:
-        off = jnp.arange(T)[None, :] if depth is None else depth
-        pp = pos_b[:, None] + off
-        q = L.apply_rope(q, pp, cfg.rope_theta)
-        k = L.apply_rope(k, pp, cfg.rope_theta)
-    kq_new, ks_new = quant.quantize_kv(k)
-    vq_new, vs_new = quant.quantize_kv(v)
-    k_q = KV.batched_update(k_q, kq_new, pos_b)
-    k_s = KV.batched_update(k_s, ks_new, pos_b)
-    v_q = KV.batched_update(v_q, vq_new, pos_b)
-    v_s = KV.batched_update(v_s, vs_new, pos_b)
-    o = verify_attention_int8(q, k_q, k_s, v_q, v_s, pos_b, backend,
-                              inter_dtype, anc=anc)
-    out = L.apply_linear(L._lin(p, "wo"), o.reshape(B, T, -1), backend)
-    return out, (k_q, k_s, v_q, v_s)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+    (k_q, k_s), (v_q, v_s) = KV.quantize_rows(k), KV.quantize_rows(v)
+    return q, (k_q, k_s, v_q, v_s)
 
 
-def gqa_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
-                    pos_b: jax.Array, backend: str = "dense"):
-    """The projections of one decode token: ``x`` [B, 1, d] at per-slot
-    positions ``pos_b`` ([B]) -> (q [B, 1, H, D], (k_q, k_s, v_q, v_s)),
-    the token's int8 K/V rows ([B, 1, Hkv, D]) and their f32 scales
-    ([B, 1, Hkv, 1]) for the caller to append at ``pos_b``."""
-    B = x.shape[0]
-    hd = cfg.head_dim
-    q = L.apply_linear(L._lin(p, "wq"), x, backend).reshape(B, 1, cfg.n_heads, hd)
-    k = L.apply_linear(L._lin(p, "wk"), x, backend).reshape(B, 1, cfg.n_kv_heads, hd)
-    v = L.apply_linear(L._lin(p, "wv"), x, backend).reshape(B, 1, cfg.n_kv_heads, hd)
-    if cfg.use_qk_norm:
-        q = L.apply_norm(p["q_norm"], q, cfg.norm_eps)
-        k = L.apply_norm(p["k_norm"], k, cfg.norm_eps)
-    if cfg.rope_theta:
-        pp = pos_b[:, None]
-        q = L.apply_rope(q, pp, cfg.rope_theta)
-        k = L.apply_rope(k, pp, cfg.rope_theta)
-    return q, (*quant.quantize_kv(k), *quant.quantize_kv(v))
-
-
-def gqa_decode_attend(p: Params, q: jax.Array, k_q, k_s, v_q, v_s,
-                      length: jax.Array, backend: str = "dense",
-                      inter_dtype=jnp.float32) -> jax.Array:
-    """Decode attention of ``q`` over the int8 rows ``[0, length)`` of
-    ``k_q``/``v_q`` ([B, S, Hkv, D], the token's own row already appended),
-    then the output projection -> [B, 1, d]."""
-    o = decode_attention_int8(q, k_q, k_s, v_q, v_s, length, backend,
-                              inter_dtype)
-    return L.apply_linear(L._lin(p, "wo"), o.reshape(q.shape[0], 1, -1),
+def gqa_decode_out(p: Params, o: jax.Array, backend: str = "dense"):
+    """The output projection of decode tokens' attention ``o``
+    ([B, T, H, D]) -> [B, T, d]."""
+    return L.apply_linear(L._lin(p, "wo"), o.reshape(o.shape[:2] + (-1,)),
                           backend)
 
 
 def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                k_q, k_s, v_q, v_s, backend: str = "dense",
                inter_dtype=jnp.float32):
-    """One-token decode against one layer's cache ([B, S, Hkv, D]).
+    """One-token decode against one layer's cache ([B, S, Hkv·D] rows,
+    [B, S, Hkv, 1] scales).
     Returns (out, updated (k_q, k_s, v_q, v_s)).  ``pos`` is a scalar
     (aligned batch) or [B] vector of per-slot positions — each slot's k/v
     appends at its own SLC offset (vmapped update), and the token attends
@@ -464,12 +447,12 @@ def gqa_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     appends into the stacked pool instead
     (:func:`repro.models.transformer.apply_layer_decode`)."""
     pos_b = KV.slot_positions(pos, x.shape[0])
-    q, rows = gqa_decode_rows(p, cfg, x, pos_b, backend)
+    q, rows = gqa_decode_rows(p, cfg, x, pos_b[:, None], backend)
     k_q, k_s, v_q, v_s = (KV.batched_update(c, r, pos_b)
                           for c, r in zip((k_q, k_s, v_q, v_s), rows))
-    out = gqa_decode_attend(p, q, k_q, k_s, v_q, v_s, pos_b + 1, backend,
-                            inter_dtype)
-    return out, (k_q, k_s, v_q, v_s)
+    o = decode_attention_int8(q, k_q, k_s, v_q, v_s, pos_b + 1, backend,
+                              inter_dtype)
+    return gqa_decode_out(p, o, backend), (k_q, k_s, v_q, v_s)
 
 
 # ---------------------------------------------------------------------------
@@ -600,7 +583,7 @@ def mla_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
     masks the latent cache to ``[0, pos[b]+t]``, so all T positions score
     against exactly the prefix T sequential decode steps would see.
     Tree mode (``depth``/``anc``): RoPE at tree depth, ancestor mask — see
-    :func:`gqa_verify`."""
+    :func:`repro.models.transformer.apply_layer_verify`."""
     B, T, _ = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim

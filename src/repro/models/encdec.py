@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.quant import quantize_kv
+from repro.core import kvcache as KV
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.transformer import Runtime, tree_stack, _sinusoid_at
@@ -141,9 +141,10 @@ def lm_loss(p: Params, cfg: ModelConfig, frames, tokens, labels, rt: Runtime):
 # ---------------------------------------------------------------------------
 def init_decode_state(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     Ld = cfg.n_layers
-    kv = (Ld, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    # lane-dense int8 rows, per-head scales (see repro.core.kvcache)
+    kv = (Ld, batch, max_len, cfg.n_kv_heads * cfg.head_dim)
     sc = (Ld, batch, max_len, cfg.n_kv_heads, 1)
-    xe = (Ld, batch, cfg.encoder_seq, cfg.n_kv_heads, cfg.head_dim)
+    xe = (Ld, batch, cfg.encoder_seq, cfg.n_kv_heads * cfg.head_dim)
     xs = (Ld, batch, cfg.encoder_seq, cfg.n_kv_heads, 1)
     return {
         "k_q": jnp.zeros(kv, jnp.int8), "k_s": jnp.zeros(sc, jnp.float32),
@@ -168,16 +169,14 @@ def prefill(p: Params, cfg: ModelConfig, frames: jax.Array, tokens: jax.Array,
     def body(xx, pl):
         h = L.apply_norm(pl["ln1"], xx)
         mix, (k, v) = A.gqa_forward(pl["attn"], cfg, h, positions, rt.backend)
-        k_q, k_s = quantize_kv(k)
-        v_q, v_s = quantize_kv(v)
+        (k_q, k_s), (v_q, v_s) = KV.quantize_rows(k), KV.quantize_rows(v)
         xx = xx + mix
         hx = L.apply_norm(pl["ln_x"], xx)
         xk = L.apply_linear(L._lin(pl["xattn"], "wk"), enc, rt.backend).reshape(
             B, -1, cfg.n_kv_heads, hd)
         xv = L.apply_linear(L._lin(pl["xattn"], "wv"), enc, rt.backend).reshape(
             B, -1, cfg.n_kv_heads, hd)
-        xk_q, xk_s = quantize_kv(xk)
-        xv_q, xv_s = quantize_kv(xv)
+        (xk_q, xk_s), (xv_q, xv_s) = KV.quantize_rows(xk), KV.quantize_rows(xv)
         xx = xx + _cross_attn(pl["xattn"], cfg, hx, (xk, xv), rt)
         xx = xx + L.apply_mlp(pl["mlp"], L.apply_norm(pl["ln2"], xx),
                               cfg.mlp_type, rt.backend)
@@ -185,10 +184,8 @@ def prefill(p: Params, cfg: ModelConfig, frames: jax.Array, tokens: jax.Array,
 
     x, caches = jax.lax.scan(body, x, p["dec"])
     k_q, k_s, v_q, v_s, xk_q, xk_s, xv_q, xv_s = caches
-    state["k_q"] = jax.lax.dynamic_update_slice(state["k_q"], k_q, (0,) * 5)
-    state["k_s"] = jax.lax.dynamic_update_slice(state["k_s"], k_s, (0,) * 5)
-    state["v_q"] = jax.lax.dynamic_update_slice(state["v_q"], v_q, (0,) * 5)
-    state["v_s"] = jax.lax.dynamic_update_slice(state["v_s"], v_s, (0,) * 5)
+    for n, c in zip(("k_q", "k_s", "v_q", "v_s"), (k_q, k_s, v_q, v_s)):
+        state[n] = jax.lax.dynamic_update_slice(state[n], c, (0,) * c.ndim)
     state.update(xk_q=xk_q, xk_s=xk_s, xv_q=xv_q, xv_s=xv_s)
     state["pos"] = jnp.array(T, jnp.int32)
     x = L.apply_norm(p["ln_f"], x)

@@ -322,7 +322,8 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int) -> dict:
                     "c_q": jnp.zeros((n_p, batch, max_len, dim), jnp.int8),
                     "c_s": jnp.zeros((n_p, batch, max_len, 1), jnp.float32)})
             else:
-                kv = (n_p, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+                # lane-dense K/V rows (see repro.core.kvcache)
+                kv = (n_p, batch, max_len, cfg.n_kv_heads * cfg.head_dim)
                 sc = (n_p, batch, max_len, cfg.n_kv_heads, 1)
                 slots.append({
                     "k_q": jnp.zeros(kv, jnp.int8), "k_s": jnp.zeros(sc, jnp.float32),
@@ -375,9 +376,9 @@ def copy_slot_prefix(state: dict, src: jax.Array, dst: jax.Array,
     or past ``n`` keep ``dst``'s dead in-place entries (masked, then
     overwritten by the resumed chunked prefill's finalize).
 
-    GQA pools only: every leaf is ``[n_p, B, S, H, D]``-shaped with the
-    slot axis at 1 and the sequence axis at 2 (the layout
-    :func:`init_decode_state` builds for attention stacks).  ``src``,
+    GQA pools only: every leaf has the slot axis at 1 and the sequence
+    axis at 2 (the layout :func:`init_decode_state` builds for attention
+    stacks).  ``src``,
     ``dst`` and ``n`` are traced, so one compile serves every admission.
     """
     src = jnp.asarray(src, jnp.int32)
@@ -430,15 +431,16 @@ def warm_prefill_carry(cfg: ModelConfig, state: dict, slot: jax.Array,
             if "k_q" not in b:
                 raise NotImplementedError(
                     "prefix-cache warm start targets GQA attention pools")
-            n_p, _, S, H, D = b["k_q"].shape
+            n_p, _, S, H, _ = b["k_s"].shape
             w = min(S, buf_len)
             keep = (jnp.arange(w) < n).reshape(1, 1, w, 1, 1)
 
             def dequant(q, s):
                 row_q = jax.lax.dynamic_index_in_dim(q, slot, 1, keepdims=True)
                 row_s = jax.lax.dynamic_index_in_dim(s, slot, 1, keepdims=True)
-                row = row_q.astype(jnp.float32) * row_s
-                buf = jnp.zeros((n_p, 1, buf_len, H, D), jnp.float32)
+                row = KV.split_heads(row_q, H).astype(jnp.float32) * row_s
+                buf = jnp.zeros((n_p, 1, buf_len) + row.shape[3:],
+                                jnp.float32)
                 return buf.at[:, :, :w].set(
                     jnp.where(keep, row[:, :, :w], 0.0))
 
@@ -452,26 +454,34 @@ def warm_prefill_carry(cfg: ModelConfig, state: dict, slot: jax.Array,
 _KV_LEAVES = ("k_q", "k_s", "v_q", "v_s")
 
 
-def _append_kv(caches: dict, idx, rows, pos_b, rt: Runtime) -> dict:
-    """Append one decode token's K/V rows and scales into the carried pool
-    at layer ``idx`` (:func:`KV.append_rows`).  On a mesh each device
-    appends its own slots and KV heads inside ``shard_map``: a per-slot
-    update at a constant slot index on the data-sharded slot axis would
-    otherwise make the partitioner all-gather the whole pool per slot."""
-    def append(c, i, r, p):
-        return {n: KV.append_rows(c[n], i, rr, p)
-                for n, rr in zip(_KV_LEAVES, r)}
+def _kv_attend(body, cache: dict, rows, q, pos_b, rt: Runtime, *extra):
+    """Run ``body(cache, rows, q, pos_b, *extra) -> (cache, o)`` — write
+    the tokens' int8 K/V rows and scales into ``cache`` and attend ``q``
+    ([B, T, H, D]) over them — on the GQA leaves of ``cache``: the carried
+    pool ([n_p, B, S, ...]) or one layer of it ([B, S, ...]).
+
+    On a mesh each device writes and attends its own slots and KV-head
+    groups inside ``shard_map``: a per-slot update at a constant slot index
+    on the data-sharded slot axis would otherwise make the partitioner
+    all-gather the whole pool per slot, and a contraction over the merged
+    lane axis would make it all-gather the layer's K/V.  Each ``extra``
+    operand is a replicated scalar or a per-slot [B, ...] array."""
+    cache = {n: cache[n] for n in _KV_LEAVES}
     if rt.mesh is None:
-        return append(caches, idx, rows, pos_b)
+        return body(cache, rows, q, pos_b, *extra)
     from repro.dist import sharding as SH
-    B, G = caches["k_q"].shape[1], caches["k_q"].shape[3]
+    lead = cache["k_s"].ndim - 4                  # 1 on the stacked pool
+    B, G = cache["k_s"].shape[lead], cache["k_s"].shape[lead + 2]
     pool = SH.kv_pool_spec(B, G, rt.mesh)
-    row = P(*pool[1:])
+    b, m = pool["k_s"][1], pool["k_s"][3]
+    at = {n: P(*s[1 - lead:]) for n, s in pool.items()}
+    per_slot = lambda a: P(b, *(None,) * (jnp.ndim(a) - 1)) if jnp.ndim(a) else P()
     return jax.shard_map(
-        append, mesh=rt.mesh,
-        in_specs=({n: pool for n in _KV_LEAVES}, P(), (row,) * 4, P(pool[1])),
-        out_specs={n: pool for n in _KV_LEAVES})(
-            {n: caches[n] for n in _KV_LEAVES}, idx, tuple(rows), pos_b)
+        body, mesh=rt.mesh,
+        in_specs=(at, tuple(P(*pool[n][1:]) for n in _KV_LEAVES),
+                  P(b, None, m, None), P(b)) + tuple(map(per_slot, extra)),
+        out_specs=(at, P(b, None, m, None)))(
+            cache, tuple(rows), q, pos_b, *extra)
 
 
 def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos,
@@ -481,20 +491,27 @@ def apply_layer_decode(p: Params, cfg: ModelConfig, slot: int, x, pos,
     counts), the MoE counters added to ``counts`` unless it is None.
 
     A GQA layer appends its int8 K/V rows and scales straight into the
-    carried pool (:func:`_append_kv`) and attends over layer ``idx``
-    read from it: the pool is never sliced out and written back whole.
+    carried pool (:func:`KV.append_rows`) and attends over layer ``idx``
+    as it lies there (:func:`A.decode_attention_int8`): the pool is never
+    sliced out and written back whole.
     SSM and MLA layers update their layer's slice and write it back."""
     kind = cfg.layer_kind(slot)
     dmvm_dt = rt.dmvm_dtype or jnp.float32
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps)
     if kind != "ssm" and cfg.attn_type != "mla":
         pos_b = KV.slot_positions(pos, x.shape[0])
-        q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b, rt.backend)
-        caches = _append_kv(caches, idx, rows, pos_b, rt)
-        mix = A.gqa_decode_attend(
-            p["attn"], q, *(jax.lax.dynamic_index_in_dim(
-                caches[n], idx, 0, keepdims=False) for n in _KV_LEAVES),
-            pos_b + 1, rt.backend, dmvm_dt)
+        q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b[:, None],
+                                    rt.backend)
+
+        def append_attend(c, r, qq, p, i):
+            c = {n: KV.append_rows(c[n], i, rr, p)
+                 for n, rr in zip(_KV_LEAVES, r)}
+            layer = (jax.lax.dynamic_index_in_dim(c[n], i, 0, keepdims=False)
+                     for n in _KV_LEAVES)
+            return c, A.decode_attention_int8(qq, *layer, p + 1, rt.backend,
+                                              dmvm_dt)
+        caches, o = _kv_attend(append_attend, caches, rows, q, pos_b, rt, idx)
+        mix = A.gqa_decode_out(p["attn"], o, rt.backend)
     else:
         cache = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0, keepdims=False),
@@ -604,9 +621,20 @@ def apply_layer_verify(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
                        rt: Runtime, depth=None, anc=None):
     """One layer of the speculative verify pass: like
     :func:`apply_layer_decode` but over ``x`` [B, T, d] (T = 1 + drafted
-    tokens per slot), appending T K/V rows at the per-slot cursor.
-    ``depth``/``anc`` ([B, T] int32) switch the window to tree mode (see
-    :func:`attention.gqa_verify`)."""
+    tokens per slot): the window's T K/V rows land at each slot's cursor
+    in one multi-token :func:`KV.batched_update` (the SLC append chunked
+    prefill makes too) and all T queries are scored over the layer in one
+    pass (:func:`A.verify_attention_int8`).  K/V rows and integer scores
+    are bit-identical to T sequential decode steps, which is what makes
+    greedy speculative decode token-identical to the plain engine.
+
+    Tree mode (``depth``/``anc`` both [B, T] int32): the T tokens are draft
+    *tree* nodes — node i's row still lands at cache offset ``pos + i``,
+    but RoPE rotates it at its tree depth (``pos + depth[b, i]``) and the
+    stepped mask becomes the ancestor mask, so each node's K row and
+    scores match what sequential decode of its root-path would produce
+    (chain-prefix nodes bit-exactly; past a skipped sibling, up to float
+    reduction order — see :func:`attention.verify_attention_int8`)."""
     dmvm_dt = rt.dmvm_dtype or jnp.float32
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps)
     if cfg.attn_type == "mla":
@@ -615,10 +643,21 @@ def apply_layer_verify(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
                                        depth=depth, anc=anc)
         new_cache = {"c_q": c_q, "c_s": c_s}
     else:
-        mix, (k_q, k_s, v_q, v_s) = A.gqa_verify(
-            p["attn"], cfg, h, pos, cache["k_q"], cache["k_s"], cache["v_q"],
-            cache["v_s"], rt.backend, dmvm_dt, depth=depth, anc=anc)
-        new_cache = {"k_q": k_q, "k_s": k_s, "v_q": v_q, "v_s": v_s}
+        B, T = x.shape[:2]
+        pos_b = KV.slot_positions(pos, B)
+        off = jnp.arange(T)[None, :] if depth is None else depth
+        q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b[:, None] + off,
+                                    rt.backend)
+
+        def update_attend(c, r, qq, p, *a):
+            c = {n: KV.batched_update(c[n], rr, p)
+                 for n, rr in zip(_KV_LEAVES, r)}
+            return c, A.verify_attention_int8(
+                qq, *(c[n] for n in _KV_LEAVES), p, rt.backend, dmvm_dt,
+                anc=a[0] if a else None)
+        new_cache, o = _kv_attend(update_attend, cache, rows, q, pos_b, rt,
+                                  *(() if anc is None else (anc,)))
+        mix = A.gqa_decode_out(p["attn"], o, rt.backend)
     x, _ = _ffn(p, x + mix, cfg, rt, None)
     return x, new_cache
 
@@ -882,7 +921,6 @@ def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
                     mix, (k, v) = A.gqa_forward(pp["attn"], cfg, h, positions,
                                                 rt.backend, lengths=lengths,
                                                 rt=rt)
-                    from repro.core.quant import quantize_kv
                     # land k/v on the cache's sharding *before* quantizing so
                     # the quantize+update pipeline doesn't bounce layouts
                     # (SPMD otherwise falls back to full rematerialisation)
@@ -893,13 +931,11 @@ def prefill(p: Params, cfg: ModelConfig, inputs: jax.Array, max_len: int,
                             k, NamedSharding(rt.mesh, kv_spec))
                         v = jax.lax.with_sharding_constraint(
                             v, NamedSharding(rt.mesh, kv_spec))
-                    k_q, k_s = quantize_kv(k)
-                    v_q, v_s = quantize_kv(v)
                     c = slot_caches[s]
-                    nc = {"k_q": jax.lax.dynamic_update_slice(c["k_q"], k_q, (0, 0, 0, 0)),
-                          "k_s": jax.lax.dynamic_update_slice(c["k_s"], k_s, (0, 0, 0, 0)),
-                          "v_q": jax.lax.dynamic_update_slice(c["v_q"], v_q, (0, 0, 0, 0)),
-                          "v_s": jax.lax.dynamic_update_slice(c["v_s"], v_s, (0, 0, 0, 0))}
+                    nc = {n: jax.lax.dynamic_update_slice(c[n], r,
+                                                          (0,) * c[n].ndim)
+                          for n, r in zip(_KV_LEAVES, (*KV.quantize_rows(k),
+                                                       *KV.quantize_rows(v)))}
                 xx, cn = _ffn(pp, xx + mix, cfg, rt, cn, real)
                 new_c.append(nc)
             return (xx, cn), tuple(new_c)
@@ -1029,11 +1065,9 @@ def finalize_prefill_carry(cfg: ModelConfig, carry: dict, max_len: int) -> dict:
                 lq = jnp.clip(jnp.round(lat / sc), -127, 127).astype(jnp.int8)
                 slots.append({"c_q": lq, "c_s": sc.astype(jnp.float32)})
             else:
-                from repro.core.quant import quantize_kv
-                k_q, k_s = quantize_kv(b["k"][:, :, :max_len])
-                v_q, v_s = quantize_kv(b["v"][:, :, :max_len])
-                slots.append({"k_q": k_q, "k_s": k_s,
-                              "v_q": v_q, "v_s": v_s})
+                slots.append(dict(zip(_KV_LEAVES, (
+                    *KV.quantize_rows(b["k"][:, :, :max_len]),
+                    *KV.quantize_rows(b["v"][:, :, :max_len])))))
         groups.append(tuple(slots))
     return {"groups": tuple(groups),
             "pos": jnp.asarray(carry["pos"], jnp.int32)}

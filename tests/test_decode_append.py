@@ -1,14 +1,17 @@
 """The decode layer scan appends each layer's int8 K/V rows in place into the
-carried pool and attends over the layer read back from it.
+lane-dense carried pool ([n_p, B, S, H_kv·D]) and attends over the layer
+read back from it.
 
 Covers:
 
-* equivalence with the slice formulation (slice a layer's cache out of the
-  carried pool, append with ``KV.batched_update``, attend over the copy,
-  write the slice back), written here as the reference: the pool's int8
-  bytes and f32 scales are equal exactly, logits to float tolerance, for
-  an MHA stack whose head_dim is not a multiple of 128 and for a GQA
-  stack, at one and four slots, with a slot at ``max_len - 1``;
+* equivalence with the slice formulation (slice a layer's [B, S, H_kv·D]
+  cache out of the carried pool, append with ``KV.batched_update``,
+  attend over the copy, write the slice back), written here as the
+  reference: the pool's int8 bytes and f32 scales are equal exactly,
+  logits to 1e-5, for an MHA
+  stack whose head_dim is not a multiple of 128 and for a GQA stack with
+  four queries per KV head, at one and four slots, with a slot at
+  ``max_len - 1``;
 * structure: no ``dynamic_update_slice`` or ``scatter`` in the layer scan
   writes an update as long as the pool (the whole-slice write-back);
 * MLA and SSM stacks keep the slice path and still match the reference.
@@ -37,8 +40,8 @@ MAX_LEN = 40          # no other axis of the tiny stacks is 40 long
 def _reference_decode_step(p, cfg, state, token, rt):
     """``decode_step`` in the slice formulation: each layer's cache is
     sliced out of the carried pool, updated (GQA through
-    :func:`attention.gqa_decode`, i.e. ``KV.batched_update``), attended
-    over, and written back whole."""
+    :func:`attention.gqa_decode`, i.e. ``KV.batched_update`` on the
+    [B, S, H_kv·D] layer), attended over, and written back whole."""
     pos = jnp.broadcast_to(jnp.asarray(state["pos"], jnp.int32),
                            (token.shape[0],))
     x = p["embed"]["w"][token][:, None]
@@ -99,6 +102,10 @@ def _cfg(name):
         # MHA (32 KV heads at full width) whose head_dim, like the full
         # model's 96, is not a multiple of 128
         cfg = dataclasses.replace(cfg, head_dim=24)
+    elif name == "llama3-8b":
+        # four queries per KV head, as in the full model
+        cfg = dataclasses.replace(cfg, n_heads=4 * cfg.n_kv_heads,
+                                  head_dim=cfg.d_model // (4 * cfg.n_kv_heads))
     return cfg
 
 
@@ -135,7 +142,7 @@ def test_decode_step_matches_slice_reference(name, B):
     if name == "phi3-mini-3.8b":
         assert cfg.n_kv_heads == cfg.n_heads and cfg.head_dim % 128
     else:
-        assert cfg.n_kv_heads < cfg.n_heads
+        assert cfg.n_heads == 4 * cfg.n_kv_heads
     params = M.init_params(jax.random.key(1), cfg)
     rt = Runtime()
     state = _filled_state(cfg, B, POSITIONS[B])
@@ -144,13 +151,15 @@ def test_decode_step_matches_slice_reference(name, B):
     ref = jax.jit(lambda s, t: _reference_decode_step(params, cfg, s, t, rt))
     lg, st = step(state, tok)
     lg_ref, st_ref = ref(state, tok)
+    assert st["groups"][0][0]["k_q"].shape == (
+        cfg.n_layers, B, MAX_LEN, cfg.n_kv_heads * cfg.head_dim)
     _assert_states_equal(st, st_ref)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(lg_ref),
                                rtol=1e-5, atol=1e-5)
     # the step wrote each slot's row (the pool changed only there)
     k_old = np.asarray(state["groups"][0][0]["k_q"])
     k_new = np.asarray(st["groups"][0][0]["k_q"])
-    changed = np.argwhere((k_old != k_new).any(axis=(0, 3, 4)))
+    changed = np.argwhere((k_old != k_new).any(axis=(0, 3)))
     assert {tuple(c) for c in changed} == {
         (b, p) for b, p in enumerate(POSITIONS[B])}
 
@@ -206,7 +215,8 @@ def test_mla_and_ssm_keep_slice_path(name):
     lg, st = jax.jit(lambda s, t: M.decode_step(params, cfg, s, t, rt))(
         state, tok)
     lg_ref, st_ref = jax.jit(
-        lambda s, t: _reference_decode_step(params, cfg, s, t, rt))(state, tok)
+        lambda s, t: _reference_decode_step(params, cfg, s, t, rt))(
+            state, tok)
     _assert_states_equal(st, st_ref)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(lg_ref),
                                rtol=1e-5, atol=1e-5)

@@ -24,8 +24,7 @@ class TestSlottedAppend:
         k, v = _kv(0)
         pos = jnp.array([0, 5, 11], jnp.int32)
         cache = KV.append_layer(cache, 0, k, v, pos)
-        from repro.core.quant import quantize_kv
-        k_q, _ = quantize_kv(k)
+        k_q, _ = KV.quantize_rows(k)
         for b, p in enumerate([0, 5, 11]):
             np.testing.assert_array_equal(
                 np.asarray(cache.k_q[0, b, p]), np.asarray(k_q[b, 0]))
@@ -55,24 +54,44 @@ class TestSlottedAppend:
         assert int(jnp.abs(cache.k_q[0, 1, 4]).max()) > 0
         k2, v2 = _kv(3)
         cache = KV.append_layer(cache, 0, k2, v2, cache.lengths)
-        from repro.core.quant import quantize_kv
         np.testing.assert_array_equal(
             np.asarray(cache.k_q[0, 1, 0]),
-            np.asarray(quantize_kv(k2)[0][1, 0]))
+            np.asarray(KV.quantize_rows(k2)[0][1, 0]))
+
+    def test_split_heads_inverts_merge_heads(self):
+        """The one conversion between per-head K/V and the cache's
+        lane-dense rows: ``merge_heads`` is a reshape of the same bytes,
+        and ``split_heads`` gives them back per head."""
+        k, _ = _kv(8, t=3)
+        rows = KV.merge_heads(k)
+        assert rows.shape == (B, 3, H * D)
+        np.testing.assert_array_equal(np.asarray(rows[:, :, D:2 * D]),
+                                      np.asarray(k[:, :, 1]))
+        np.testing.assert_array_equal(np.asarray(KV.split_heads(rows, H)),
+                                      np.asarray(k))
 
     @pytest.mark.parametrize("s", [1, 2, S])
     def test_append_rows_matches_batched_update(self, s):
-        """The append into the stacked pool at a traced layer lands exactly
-        what ``batched_update`` lands in that layer, clamped rows included,
-        and leaves every other byte as it was."""
+        """The one-row append into the lane-dense pool ([L, B, S, H·D]) at a
+        traced layer lands exactly the bytes ``batched_update`` lands in a
+        per-head [B, S, H, D] layer, clamped rows included, and leaves every
+        other byte as it was; the [L, B, S, H, 1] scales likewise."""
         from repro.core.quant import quantize_kv
-        k_q, _ = quantize_kv(_kv(5)[0])
+        k_q, k_s = quantize_kv(_kv(5)[0])
         pool = jax.random.randint(jax.random.key(6), (L, B, s, H, D),
                                   -127, 128, jnp.int32).astype(jnp.int8)
+        scales = jax.random.uniform(jax.random.key(7), (L, B, s, H, 1))
         pos = jnp.array([0, s - 1, s + 3], jnp.int32)
-        got = jax.jit(KV.append_rows)(pool, jnp.int32(1), k_q, pos)
+        append = jax.jit(KV.append_rows)
+        got = append(KV.merge_heads(pool), jnp.int32(1), KV.merge_heads(k_q),
+                     pos)
         want = pool.at[1].set(KV.batched_update(pool[1], k_q, pos))
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(KV.merge_heads(want)))
+        np.testing.assert_array_equal(
+            np.asarray(append(scales, jnp.int32(1), k_s, pos)),
+            np.asarray(scales.at[1].set(
+                KV.batched_update(scales[1], k_s, pos))))
 
     def test_multi_token_append(self):
         """Prefill-style appends (T>1) land contiguously from each slot pos."""
@@ -80,8 +99,7 @@ class TestSlottedAppend:
         k, v = _kv(4, t=3)
         pos = jnp.array([2, 0, 7], jnp.int32)
         cache = KV.append_layer(cache, 0, k, v, pos)
-        from repro.core.quant import quantize_kv
-        v_q, _ = quantize_kv(v)
+        v_q, _ = KV.quantize_rows(v)
         for b, p in enumerate([2, 0, 7]):
             np.testing.assert_array_equal(
                 np.asarray(cache.v_q[0, b, p:p + 3]), np.asarray(v_q[b]))
@@ -141,7 +159,7 @@ class TestCacheBytesInvariance:
                     k, v = _kv(n)
                     cache = KV.append_layer(cache, 0, k, v, cache.lengths)
                 assert KV.cache_bytes(cache) == base
-                assert cache.k_q.shape == (1, B, S, H, D)
+                assert cache.k_q.shape == (1, B, S, H * D)
 
         prop()
 
@@ -248,6 +266,60 @@ class TestZeroRowEdges:
                              jax.tree.leaves(state["groups"])):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         assert out["pos"].tolist() == [4, 6, 0]
+
+
+def _lane_dense_case(b, rep, d, seed, t=1):
+    """q [b, t, G·rep, d] and a random int8 cache of S rows, per head
+    ([b, S, G, d]) and lane-dense ([b, S, G·d]), with its scales."""
+    from repro.core.quant import quantize_kv
+    g = 2 if rep > 1 else 4
+    k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
+    q = jax.random.normal(k1, (b, t, g * rep, d))
+    k_q, k_s = quantize_kv(jax.random.normal(k2, (b, S, g, d)))
+    v_q, v_s = quantize_kv(jax.random.normal(k3, (b, S, g, d)))
+    flat = KV.merge_heads
+    return q, (k_q, k_s, v_q, v_s), (flat(k_q), k_s, flat(v_q), v_s)
+
+
+LANE_DENSE = pytest.mark.parametrize("rep,d", [(1, 24), (4, 16)],
+                                     ids=["mha-hd24", "gqa-rep4"])
+
+
+class TestLaneDenseAttention:
+    """Decode and verify attention read the lane-dense pool as it lies and
+    match the per-head dMVM oracle (``kernels/decode_attn/ref.py``)."""
+
+    @pytest.mark.parametrize("backend", ["dense", "pallas"])
+    @pytest.mark.parametrize("b", [1, 4])
+    @LANE_DENSE
+    def test_decode_matches_per_head_oracle(self, rep, d, b, backend):
+        from repro.kernels.decode_attn import ref as da_ref
+        from repro.models import attention as A
+        q, per_head, flat = _lane_dense_case(b, rep, d, seed=b + rep)
+        # one slot's cursor at max_len - 1: it attends every row
+        length = jnp.array([S, 5, 1, 11][:b], jnp.int32)
+        want = da_ref.ref(q, *per_head, length[:, None, None, None])
+        got = A.decode_attention_int8(q, *flat, length, backend)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("tree", [False, True], ids=["linear", "tree"])
+    @LANE_DENSE
+    def test_verify_matches_per_head_oracle(self, rep, d, tree):
+        from repro.kernels.decode_attn import ref as da_ref
+        from repro.models import attention as A
+        t = 3
+        q, per_head, flat = _lane_dense_case(4, rep, d, seed=rep, t=t)
+        pos = jnp.array([S - t, 0, 6, 9], jnp.int32)
+        if tree:
+            anc = jnp.broadcast_to(jnp.array([1, 3, 5], jnp.int32), (4, t))
+            want = da_ref.verify_tree_ref(q, *per_head, pos, anc)
+        else:
+            anc = None
+            want = da_ref.verify_ref(q, *per_head, pos)
+        got = A.verify_attention_int8(q, *flat, pos, anc=anc)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestSlotLedger:

@@ -121,6 +121,37 @@ def test_verify_attention_compiles(one_chip, tree):
     _assert_mosaic(jax.jit(fn).lower(*args).compile())
 
 
+@pytest.mark.parametrize("name", ["phi3-mini-3.8b", "granite-3-8b"])
+@pytest.mark.parametrize("T", [1, 5], ids=["decode", "verify"])
+def test_lane_dense_attention_dots_are_exact(one_chip, name, T):
+    """The dense backend's QK and PV over the lane-dense pool, compiled for
+    the chip: no MXU dot takes an f32 operand at default precision (the
+    MXU would round it to one bf16 pass).  QK is int8 x int8; PV feeds the
+    softmax weights as three bf16 parts that sum to them exactly."""
+    import re
+    from repro.models import attention as A
+    cfg = registry.get(name)
+    G, D = cfg.n_kv_heads, cfg.head_dim
+    rows = _sds(one_chip, (SLOTS, MAX_LEN, G * D), jnp.int8)
+    scales = _sds(one_chip, (SLOTS, MAX_LEN, G, 1), jnp.float32)
+    q = _sds(one_chip, (SLOTS, T, cfg.n_heads, D), jnp.bfloat16)
+    pos = _sds(one_chip, (SLOTS,), jnp.int32)
+    fn = A.decode_attention_int8 if T == 1 else A.verify_attention_int8
+    hlo = jax.jit(fn).lower(q, rows, scales, rows, scales,
+                            pos).compile().as_text()
+    types = dict(re.findall(r"%([\w.\-]+) = (\w+)\[", hlo))
+    dots = [(l, [types[n] for n in re.search(
+                r" (?:convolution|dot)\(%([\w.\-]+), %([\w.\-]+)\)",
+                l).groups()])
+            for l in hlo.splitlines()
+            if re.search(r" (?:convolution|dot)\(", l)]
+    assert len(dots) == 2, dots                    # QK and PV, on the MXU
+    assert sorted(map(sorted, (t for _, t in dots))) == [
+        ["bf16", "s8"], ["s8", "s8"]], dots
+    assert not [l for l, t in dots if "f32" in t
+                and "operand_precision={highest,highest}" not in l]
+
+
 @pytest.mark.parametrize("backend", ["dense", "fused_int8"])
 def test_phi3_decode_step_fits_hbm(one_chip, compiled_kernels, backend):
     """The serve decode step at full width: bf16 W8A8 weights from
@@ -147,13 +178,19 @@ def test_phi3_decode_step_fits_hbm(one_chip, compiled_kernels, backend):
         _assert_mosaic(compiled)
 
 
-@pytest.mark.parametrize("slots", [1, SLOTS])
-def test_phi3_decode_step_keeps_pool_layout(one_chip, slots):
-    """The decode step appends into the int8 pool in the layout the chip
-    gives it (S minor, so that head_dim 96 is not padded to 128): the
-    compiled step holds no copy of the pool or of a layer's slice of it."""
+@pytest.mark.parametrize("name,slots", [
+    pytest.param("phi3-mini-3.8b", 1, id="1"),
+    pytest.param("phi3-mini-3.8b", SLOTS, id=str(SLOTS)),
+    pytest.param("granite-3-8b", 1, id="granite-1"),
+    pytest.param("granite-3-8b", SLOTS, id=f"granite-{SLOTS}")])
+def test_phi3_decode_step_keeps_pool_layout(one_chip, name, slots):
+    """The decode step appends into the lane-dense int8 pool
+    ([L, B, S, H_kv·D]) with S off the lane axis — each append is a
+    one-row sublane write, not a rewrite of the slot's layer slab — and
+    holds no int8 copy the size of the pool or of a layer's slab: for
+    phi3 (MHA, head_dim 96) and granite (GQA, head_dim 128)."""
     import re
-    cfg = registry.get("phi3-mini-3.8b")
+    cfg = registry.get(name)
     params = jax.eval_shape(
         lambda: quantize_tree(M.init_params(jax.random.key(0), cfg,
                                             jnp.bfloat16)))
@@ -165,9 +202,14 @@ def test_phi3_decode_step_keeps_pool_layout(one_chip, slots):
         lambda p, s, t: M.decode_step(p, cfg, s, t, rt), donate_argnums=(1,)
     ).lower(place(params), place(state),
             _sds(one_chip, (slots,), jnp.int32)).compile().as_text()
-    pool = re.compile(r"= s8\[[\d,]*%d,%d,%d\]\S* copy\(" % (
-        MAX_LEN, cfg.n_kv_heads, cfg.head_dim))
-    assert not [l for l in hlo.splitlines() if pool.search(l)]
+    seq = r"s8\[(?:\d+,)*%d(?:,\d+)*\]" % MAX_LEN     # pool or slab
+    appends = re.findall(r"= s8\[%d,%d,%d,[\d,]+\]\{([\d,]+)[:}]"
+                         r"\S* dynamic-update-slice\(" % (
+                             cfg.n_layers, slots, MAX_LEN), hlo)
+    assert appends                      # the K and V appends into the pool
+    assert all(m2m.split(",")[0] != "2" for m2m in appends), appends
+    assert not [l for l in hlo.splitlines()
+                if re.search(r"= %s\S* copy\(" % seq, l)]
 
 
 def test_phi3_mesh_decode_step_appends_per_shard(topo):
@@ -193,8 +235,8 @@ def test_phi3_mesh_decode_step_appends_per_shard(topo):
         cfg, ShapeConfig("serve", MAX_LEN, SLOTS, "decode"), state, mesh)
     io = SH.serve_step_shardings(SLOTS, mesh)
     pool_spec = SH.kv_pool_spec(SLOTS, cfg.n_kv_heads, mesh)
-    pools = [s for s in jax.tree.leaves(ssh) if len(s.spec) == 5]
-    assert pools and all(s.spec == pool_spec for s in pools)
+    pools = ssh["groups"][0][0]
+    assert {n: s.spec for n, s in pools.items()} == pool_spec
     place = lambda t, sh: jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         t, sh)
@@ -205,10 +247,10 @@ def test_phi3_mesh_decode_step_appends_per_shard(topo):
     ).lower(place(params, psh), place(state, ssh),
             jax.ShapeDtypeStruct((SLOTS,), jnp.int32,
                                  sharding=io["tokens"])).compile().as_text()
-    moved = re.compile(r"= (s8|f32)\[\d+,\d+,%d,\d+,\d+\]\S* (all-gather|"
+    moved = re.compile(r"= (s8|f32)\[\d+,\d+,%d(,\d+)+\]\S* (all-gather|"
                        r"all-reduce|all-to-all|collective-permute|"
                        r"reduce-scatter)" % MAX_LEN)
-    copied = re.compile(r"= s8\[\d+,\d+,%d,\d+,\d+\]\S* copy\(" % MAX_LEN)
+    copied = re.compile(r"= s8\[\d+,\d+,%d(,\d+)+\]\S* copy\(" % MAX_LEN)
     lines = hlo.splitlines()
     assert not [l for l in lines if moved.search(l)]
     assert not [l for l in lines if copied.search(l)]
