@@ -77,11 +77,11 @@ def run_decode_attn():
 
 
 def run_verify_attn():
-    """Speculative verify-window kernels: the linear (stepped causal
-    limit) and tree-mask (per-row ancestor bitmask) variants at a few
-    window sizes.  One pass scores all T window rows against the live
-    prefix, so the structural signal is rows-per-pass: T rows amortize
-    the same K/V sweep a single decode row pays."""
+    """Speculative verify-window kernel (per-row ancestor bitmask) at a few
+    window sizes, on a chain window of T-1 drafts.  One pass scores all T
+    window rows against the live prefix, so the structural signal is
+    rows-per-pass: T rows amortize the same K/V sweep a single decode row
+    pays."""
     import numpy as np
     from repro.kernels.decode_attn import ops as da_ops
     from repro.kernels.decode_attn.kernel import BLOCK_S
@@ -97,14 +97,7 @@ def run_verify_attn():
         k_q, k_s = quant.quantize_kv(k)
         v_q, v_s = quant.quantize_kv(v)
         pos = jnp.full((B,), length, jnp.int32)
-        t = time_fn(lambda q=q, k_q=k_q, k_s=k_s, v_q=v_q, v_s=v_s,
-                    pos=pos: da_ops.verify_attention(
-                        q, k_q, k_s, v_q, v_s, pos))
         live = -(-(length + T) // BLOCK_S)
-        emit(f"kernel/verify_attn_T{T}_len{length}", t,
-             f"rows_per_pass={T};live_blocks={live};bs={BLOCK_S}")
-        # tree mask: same window budget as a chain of T-1 drafts, two
-        # branches (the degenerate chain anc reproduces the linear mask)
         _, anc_l = tree_depths_ancestors(chain_parents(T - 1))
         anc = jnp.asarray(np.tile(np.asarray(anc_l, np.int32), (B, 1)))
         t = time_fn(lambda q=q, k_q=k_q, k_s=k_s, v_q=v_q, v_s=v_s,

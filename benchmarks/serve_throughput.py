@@ -11,23 +11,18 @@ chunked prefill, so ``max_pf/step`` is bounded by the iteration token
 budget instead of the longest prompt: no decode iteration ever stalls
 behind a full-prompt prefill.
 
-With ``--spec-k 0,2,4,8`` every policy is additionally swept through the
-speculative decode lane (draft k tokens, one batched verify step per
-iteration): each record reports the draft acceptance rate and the TPOT
-speedup relative to that policy's non-speculative (k=0) run — the paper's
-per-token weight-read amortization, measured end to end.
-
-With ``--spec-tree 0,4`` the sweep adds the tree-draft lane (a token
-*tree* of N nodes per slot, ancestor-masked verify, accepted root-path
-compacted in place): a tree record's ``speedup`` column is relative to
-the non-speculative baseline like every other record, and its ``vs-lin``
-column is the TPOT speedup over the linear ``spec_k`` record with the
-same draft budget — equal budget, tree vs chain.
+With ``--spec-tree 0,4`` every policy is additionally swept through the
+speculative decode lane (a draft token *tree* of N nodes per slot — a
+chain at ``--spec-branch 1`` — one ancestor-masked verify step per
+iteration, accepted root-path committed in place): each record reports the
+draft acceptance rate and the TPOT speedup relative to that policy's
+non-speculative run — the paper's per-token weight-read amortization,
+measured end to end.
 
 With ``--multi-step 1,2,4`` the sweep also covers the fused multi-step
 decode lane (m greedy iterations per jitted call, argmax fed back on
 device): the speedup column for an ``m>1`` record is relative to the same
-policy's (k=0, m=1) baseline.  Every record carries the per-iteration
+policy's (tree=0, m=1) baseline.  Every record carries the per-iteration
 host-clock breakdown (``host_ms``: the engine's own host work, dispatch
 included; ``dispatch_ms``: enqueueing jitted programs; ``wait_ms``: blocked
 on device results) and the per-decode-step host transfer volume
@@ -40,13 +35,13 @@ running ``AsyncServer`` while the step loop executes in its worker thread
 — the measured path includes the real admission handoff and stream pumps.
 ``--parity`` instead runs the closed-loop check: the streamed output must
 be token-identical to ``generate_all`` on an identically-configured
-engine for every policy (with ``--chunk``/``--spec-k`` honoured).  All
+engine for every policy (with ``--chunk``/``--spec-tree`` honoured).  All
 timing in every mode rides the engine's monotonic clock.
 
 Run:  PYTHONPATH=src python benchmarks/serve_throughput.py \
           [--arch llama3-8b] [--requests 24] [--rate 20] [--slots 4] \
           [--policies fifo,sjf,priority,fair] [--chunk 8] \
-          [--max-step-tokens 12] [--spec-k 0,2,4,8] [--drafter ngram] \
+          [--max-step-tokens 12] [--spec-tree 0,4] [--drafter ngram] \
           [--multi-step 1,4] [--mesh 2x4] \
           [--json BENCH_serve_throughput.json]
 
@@ -121,8 +116,8 @@ def make_chaos(args):
                          ber=ber, step_fail_every=every)
 
 
-def make_engine(cfg, params, args, rt, spec_k=0, multi_step=1,
-                prefix_cache=None, spec_tree=0):
+def make_engine(cfg, params, args, rt, multi_step=1, prefix_cache=None,
+                spec_tree=0):
     max_len = args.max_prompt + args.max_new + 1
     if prefix_cache is None:
         prefix_cache = getattr(args, "prefix_cache", False)
@@ -130,7 +125,7 @@ def make_engine(cfg, params, args, rt, spec_k=0, multi_step=1,
         cfg, params, n_slots=args.slots, max_len=max_len, rt=rt,
         policy=args.policy, chunk=args.chunk,
         max_step_tokens=args.max_step_tokens,
-        spec_k=spec_k, spec_tree=spec_tree,
+        spec_tree=spec_tree,
         spec_branch=getattr(args, "spec_branch", 2),
         drafter=args.drafter, multi_step=multi_step,
         prefix_cache=prefix_cache,
@@ -243,7 +238,6 @@ def run_parity(cfg, params, args, rt):
     budgets = [int(rng.integers(max(1, args.max_new // 2),
                                 args.max_new + 1))
                for _ in range(args.requests)]
-    spec_k = max(int(s) for s in args.spec_k.split(","))
     spec_tree = max(int(s) for s in args.spec_tree.split(","))
     policies = (["fifo", "sjf", "priority:preempt",
                  f"fair:{max(1, args.max_new // 2)}"]
@@ -258,11 +252,13 @@ def run_parity(cfg, params, args, rt):
 
     for pol in policies:
         args.policy = pol
-        # the reference is always a cache-LESS engine: with --prefix-cache
-        # the check below is literally "warm-hit streams == cold prefill"
-        ref = make_engine(cfg, params, args, rt, spec_k=spec_k,
+        # the reference is always a cache-LESS, spec-less engine: with
+        # --prefix-cache the check below is literally "warm-hit streams ==
+        # cold prefill", and with --spec-tree "verified drafts == plain
+        # decode"
+        ref = make_engine(cfg, params, args, rt,
                           prefix_cache=False).generate_all(prompts, budgets)
-        eng = make_engine(cfg, params, args, rt, spec_k=spec_k)
+        eng = make_engine(cfg, params, args, rt, spec_tree=spec_tree)
         got = asyncio.run(stream_all(eng))
         assert got == ref, (pol, got, ref)
         extra = ""
@@ -275,18 +271,9 @@ def run_parity(cfg, params, args, rt):
             assert hits > 0, (pol, "prefix cache never hit", eng._pcache.stats)
             extra = (f" prefix_hits={hits} "
                      f"saved={eng.stats['prefill_tokens_saved']}")
-        print(f"PARITY_OK {pol} chunk={args.chunk} spec_k={eng.spec_k} "
+        print(f"PARITY_OK {pol} chunk={args.chunk} "
+              f"spec_tree={eng.spec_tree} branch={eng.spec_branch} "
               f"({sum(len(o) for o in got)} tokens){extra}")
-        if spec_tree > 0:
-            # tree lane parity against the same cache-less reference: the
-            # ancestor-masked verify + path compaction must stream the
-            # exact tokens the plain (and linear-spec) engines produced
-            teng = make_engine(cfg, params, args, rt, spec_tree=spec_tree)
-            tgot = asyncio.run(stream_all(teng))
-            assert tgot == ref, (pol, "tree lane diverged", tgot, ref)
-            print(f"PARITY_OK {pol} chunk={args.chunk} "
-                  f"spec_tree={teng.spec_tree} branch={teng.spec_branch} "
-                  f"({sum(len(o) for o in tgot)} tokens)")
 
 
 def summarize(policy, eng, reqs, wall):
@@ -316,13 +303,11 @@ def summarize(policy, eng, reqs, wall):
         "preemptions": eng.stats["preemptions"],
         "steps": eng.stats["steps"],
         "max_step_prefill_tokens": eng.stats["max_step_prefill_tokens"],
-        # eng.spec_k, not the requested value: the engine zeroes it for
+        # eng.spec_tree, not the requested value: the engine zeroes it for
         # SSM stacks (no rewindable state) and never builds a drafter
-        "spec_k": eng.spec_k,
         "spec_tree": eng.spec_tree,
         "spec_branch": eng.spec_branch if eng.spec_tree else None,
-        "drafter": (eng._drafter.name
-                    if eng.spec_k or eng.spec_tree else None),
+        "drafter": eng._drafter.name if eng.spec_tree else None,
         "verify_steps": eng.stats["verify_steps"],
         # None (JSON null), never NaN, when nothing was drafted
         "acceptance_rate": (eng.acceptance_rate
@@ -330,7 +315,7 @@ def summarize(policy, eng, reqs, wall):
         # per-window accepted-length histogram (index = drafted tokens
         # committed by one verify pass); null when no spec lane ran
         "spec_accept_hist": eng.stats.get("spec_accept_hist"),
-        # eng.multi_step (like eng.spec_k): 1 for SSM stacks
+        # eng.multi_step (like eng.spec_tree): 1 for SSM stacks
         "multi_step": eng.multi_step,
         "multi_blocks": eng.stats["multi_blocks"],
         # per-iteration host-clock breakdown (engine host work, of it
@@ -373,7 +358,7 @@ def summarize(policy, eng, reqs, wall):
     return rec
 
 
-COLS = [("policy", "%-16s"), ("spec_k", "%6d"), ("spec_tree", "%5d"),
+COLS = [("policy", "%-16s"), ("spec_tree", "%5d"),
         ("multi_step", "%5d"),
         ("throughput_tok_s", "%8.1f"),
         ("ttft_p50_ms", "%9.1f"), ("ttft_p99_ms", "%9.1f"),
@@ -383,12 +368,10 @@ COLS = [("policy", "%-16s"), ("spec_k", "%6d"), ("spec_tree", "%5d"),
         ("max_step_prefill_tokens", "%11d"),
         ("host_ms", "%8.2f"), ("dispatch_ms", "%8.2f"), ("wait_ms", "%8.2f"),
         ("xfer_bytes", "%7.0f"),
-        ("acceptance_rate", "%7.2f"), ("tpot_speedup", "%8.2f"),
-        ("tpot_speedup_vs_linear", "%8.2f")]
-HEAD = ("policy            spec_k   tree  mstep     tok/s  ttft-p50  "
+        ("acceptance_rate", "%7.2f"), ("tpot_speedup", "%8.2f")]
+HEAD = ("policy             tree  mstep     tok/s  ttft-p50  "
         "ttft-p99  tpot-p50  tpot-p99   lat-p99  qdel-p50  qdel-p99  prmpt  "
-        "max_pf/step   host_ms  disp_ms  wait_ms  xfer_B   accept  speedup   "
-        "vs-lin")
+        "max_pf/step   host_ms  disp_ms  wait_ms  xfer_B   accept  speedup")
 # appended only when --prefix-cache is on (fields are absent otherwise)
 PREFIX_COLS = [("prefix_hits", "%6d"), ("prefill_tokens_saved", "%8d")]
 PREFIX_HEAD = "  pfhits   pfsaved"
@@ -411,23 +394,19 @@ def main():
                     help="chunked prefill size (None = atomic prefills)")
     ap.add_argument("--max-step-tokens", type=int, default=None,
                     help="per-iteration token budget (default slots + chunk)")
-    ap.add_argument("--spec-k", default="0", metavar="K[,K...]",
-                    help="speculative decode draft lengths to sweep, e.g. "
-                         '"0,2,4,8" (0 = the non-speculative baseline the '
-                         "TPOT speedup column is relative to)")
     ap.add_argument("--spec-tree", default="0", metavar="N[,N...]",
-                    help="tree-draft node budgets to sweep, e.g. \"0,4\" "
-                         "(0 = off).  A tree record's vs-lin column is its "
-                         "TPOT speedup over the linear spec_k record with "
-                         "the same draft budget — equal budget, tree vs "
-                         "chain")
+                    help="speculative decode draft-tree node budgets to "
+                         'sweep, e.g. "0,4" (0 = the non-speculative '
+                         "baseline the TPOT speedup column is relative to)")
     ap.add_argument("--spec-branch", type=int, default=2,
-                    help="tree-draft branching factor (with --spec-tree)")
+                    help="draft-tree branching factor (with --spec-tree; "
+                         "1 = a chain)")
     ap.add_argument("--drafter", default="ngram",
                     help="draft proposer: ngram[:N] | mtp")
     ap.add_argument("--multi-step", default="1", metavar="M[,M...]",
-                    help="fused multi-step decode block sizes to sweep at "
-                         'k=0, e.g. "1,2,4" (1 = the per-token baseline)')
+                    help="fused multi-step decode block sizes to sweep "
+                         'without speculation, e.g. "1,2,4" (1 = the '
+                         "per-token baseline)")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="radix prefix cache (needs --chunk): adds "
                          "prefix_hits / prefill_tokens_saved to the table "
@@ -446,7 +425,7 @@ def main():
     ap.add_argument("--parity", action="store_true",
                     help="closed-loop check instead of a benchmark: streamed "
                          "output must be token-identical to generate_all per "
-                         "policy (honours --chunk/--spec-k), then exit")
+                         "policy (honours --chunk/--spec-tree), then exit")
     ap.add_argument("--stream-buffer", type=int, default=16,
                     help="per-stream token queue bound in --serve/--parity")
     ap.add_argument("--kv-swap", action="store_true",
@@ -488,27 +467,23 @@ def main():
           f"rate={args.rate}/s prompts 4..{args.max_prompt} "
           f"new {max(1, args.max_new//2)}..{args.max_new} "
           f"chunk={args.chunk} budget={args.max_step_tokens}")
-    spec_ks = [int(s) for s in args.spec_k.split(",")]
     spec_trees = [int(s) for s in args.spec_tree.split(",")]
     multi_ms = [int(s) for s in args.multi_step.split(",")]
-    # the lanes don't combine (spec_tree > spec_k > multi_step precedence
-    # in the engine), so sweep each against the shared (k=0, m=1, tree=0)
-    # baseline; a requested m=1 baseline is kept even when --spec-k omits 0
-    combos = [(K, 1, 0) for K in spec_ks]
+    # the lanes don't combine (spec_tree takes the step before multi_step
+    # in the engine), so sweep each as (tree, m) against the shared
+    # (0, 1) baseline, kept even when --spec-tree omits 0
+    combos = [(n, 1) for n in spec_trees]
     for m in multi_ms:
-        if (0, m, 0) not in combos:
-            combos.append((0, m, 0))
-    for n in spec_trees:
-        if n and (0, 1, n) not in combos:
-            combos.append((0, 1, n))
+        if (0, m) not in combos:
+            combos.append((0, m))
     cols = COLS + (PREFIX_COLS if args.prefix_cache else [])
     print(HEAD + (PREFIX_HEAD if args.prefix_cache else ""))
     records = {}
     for pol in policies:
         args.policy = pol
         recs = []
-        for K, m, n in combos:
-            eng = make_engine(cfg, params, args, rt, spec_k=K, multi_step=m,
+        for n, m in combos:
+            eng = make_engine(cfg, params, args, rt, multi_step=m,
                               spec_tree=n)
             warm_engine(eng, args)
             if args.serve:
@@ -518,30 +493,21 @@ def main():
                 reqs, wall = replay_trace(eng, arrivals, prompts, budgets,
                                           priorities, users)
             recs.append(summarize(pol, eng, reqs, wall))
-        # speedup baseline: the (k=0, m=1) record wherever it sits in the
-        # sweep (None — JSON null — when there is no baseline or NaN TPOTs)
+        # speedup baseline: the (tree=0, m=1) record wherever it sits in
+        # the sweep (None — JSON null — when there is no baseline or NaN
+        # TPOTs)
         base = next((r for r in recs
-                     if r["spec_k"] == 0 and r["multi_step"] == 1
-                     and r["spec_tree"] == 0), None)
+                     if r["spec_tree"] == 0 and r["multi_step"] == 1), None)
         base_tpot = base["tpot_p50_ms"] if base else None
         if base_tpot is None or base_tpot != base_tpot:
             base_tpot = None
-        # per-budget linear-spec TPOTs: a tree record's vs-lin column is
-        # its speedup over the chain window with the same draft budget
-        lin_tpot = {r["spec_k"]: r["tpot_p50_ms"] for r in recs
-                    if r["spec_k"] and r["multi_step"] == 1
-                    and r["spec_tree"] == 0}
         for rec in recs:
             tpot = rec["tpot_p50_ms"]
             rec["tpot_speedup"] = (base_tpot / tpot
                                    if base_tpot and tpot == tpot else None)
-            lin = lin_tpot.get(rec["spec_tree"]) if rec["spec_tree"] else None
-            rec["tpot_speedup_vs_linear"] = (
-                lin / tpot if lin and lin == lin and tpot == tpot else None)
-            K, m, n = rec["spec_k"], rec["multi_step"], rec["spec_tree"]
-            key = pol if (K == 0 and m == 1 and n == 0) else (
-                f"{pol}@spec{K}" if K else
-                f"{pol}@tree{n}" if n else f"{pol}@m{m}")
+            n, m = rec["spec_tree"], rec["multi_step"]
+            key = (f"{pol}@tree{n}" if n else
+                   f"{pol}@m{m}" if m > 1 else pol)
             records[key] = rec
             print("  ".join(_cell(fmt, rec[k]) for k, fmt in cols))
 
@@ -552,7 +518,7 @@ def main():
                "rate_req_s": args.rate, "mesh": args.mesh,
                "seed": args.seed, "chunk": args.chunk,
                "max_step_tokens": args.max_step_tokens,
-               "spec_k": spec_ks, "spec_tree": spec_trees,
+               "spec_tree": spec_trees,
                "spec_branch": args.spec_branch, "drafter": args.drafter,
                "multi_step": multi_ms,
                "prefix_cache": args.prefix_cache,

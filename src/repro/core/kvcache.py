@@ -121,16 +121,13 @@ def chunk_update(buf: jax.Array, new: jax.Array, start: jax.Array | int,
     return jax.lax.dynamic_update_slice(buf, new.astype(buf.dtype), idx)
 
 
-def pool_headroom(spec_k: int = 0, spec_tree: int = 0,
-                  multi_step: int = 1) -> int:
+def pool_headroom(spec_tree: int = 0, multi_step: int = 1) -> int:
     """Scratch rows each slot needs past ``max_len`` — the one audited
     sizing rule for every lane that writes ahead of the committed cursor:
 
-    - linear spec: a verify window appends ``spec_k + 1`` rows at
-      ``pos .. pos + spec_k`` with ``pos <= max_len - 1``, so ``spec_k``
-      rows of headroom;
-    - tree spec: a window appends ``spec_tree + 1`` node rows (root +
-      drafts) at ``pos .. pos + spec_tree`` — ``spec_tree`` rows;
+    - speculative verify: a window appends ``spec_tree + 1`` rows (root +
+      drafted nodes) at ``pos .. pos + spec_tree`` with
+      ``pos <= max_len - 1``, so ``spec_tree`` rows of headroom;
     - fused multi-step: a block appends up to ``m`` rows at
       ``pos .. pos + m - 1`` before the host truncates a mid-block stop —
       ``m - 1`` rows.
@@ -141,9 +138,9 @@ def pool_headroom(spec_k: int = 0, spec_tree: int = 0,
     rows beyond ``max_len + headroom`` would clamp into live rows of the
     window itself.
     """
-    if min(spec_k, spec_tree, multi_step - 1) < 0:
-        raise ValueError("negative spec_k/spec_tree or multi_step < 1")
-    return max(spec_k, spec_tree, multi_step - 1)
+    if min(spec_tree, multi_step - 1) < 0:
+        raise ValueError("negative spec_tree or multi_step < 1")
+    return max(spec_tree, multi_step - 1)
 
 
 def path_gather(buf: jax.Array, base: jax.Array, sel: jax.Array,

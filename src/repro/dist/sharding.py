@@ -342,22 +342,12 @@ def verify_shardings(n_slots: int, mesh) -> dict:
     [B, T, d] hidden carry shards over the data axes (T — the verify
     window — and vocab/model dims replicate).  Pinning these beside the
     pool's ``decode_state_shardings`` keeps the jitted verify step from
-    migrating the SLC pool on any draft-length path."""
-    b = batch_entry(n_slots, mesh)
-    return {
-        "tokens": NamedSharding(mesh, P(b, None)),
-        "logits": NamedSharding(mesh, P(b, None, None)),
-        "hidden": NamedSharding(mesh, P(b, None, None)),
-    }
-
-
-def tree_verify_shardings(n_slots: int, mesh) -> dict:
-    """Tree-verify extras, pinned beside :func:`verify_shardings`: the
-    [B, T] per-slot node depths and ancestor bitmasks shard their slot axis
-    over the data axes like the draft tokens (the mask rides the same rows
-    of the window), while the tree-commit operands replicate — they feed
-    per-slot dynamic slicing inside the jitted path gather, exactly like
-    the prefix-cache admission scalars:
+    migrating the SLC pool on any window size.  The [B, T] per-slot node
+    depths and ancestor bitmasks shard their slot axis like the draft
+    tokens (the mask rides the same rows of the window), while the
+    tree-commit operands replicate — they feed per-slot dynamic slicing
+    inside the jitted path gather, exactly like the prefix-cache admission
+    scalars:
 
     * ``window`` — depth / anc [B, T] int32 (slot axis data-sharded);
     * ``commit`` — base [B], sel [B, W], keep [B], pos [B] (replicated,
@@ -365,6 +355,9 @@ def tree_verify_shardings(n_slots: int, mesh) -> dict:
     """
     b = batch_entry(n_slots, mesh)
     return {
+        "tokens": NamedSharding(mesh, P(b, None)),
+        "logits": NamedSharding(mesh, P(b, None, None)),
+        "hidden": NamedSharding(mesh, P(b, None, None)),
         "window": NamedSharding(mesh, P(b, None)),
         "commit": replicated(mesh),
     }

@@ -88,25 +88,12 @@ def _dmvm_block(q_ref, qs_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _linear_kernel(len_ref, *refs, t: int, rep: int, bs: int, **kw):
-    """``t`` query tokens per batch row: the plain decode step is
-    ``t == 1``; the speculative verify step folds its T draft positions
-    into the row axis ([G, t*rep, D] q block) with a *per-row* key limit —
-    row ``r`` (draft position ``r // rep``) masks keys to
-    ``len_ref[b, r // rep]``, the verify window's stepped causal mask."""
-    b_idx = pl.program_id(0)
-    lims = [len_ref[b_idx, i] for i in range(t)]      # t scalar SMEM reads
-    lim_max = lims[0]
-    for li in lims[1:]:
-        lim_max = jnp.maximum(lim_max, li)
-
-    def visible(kpos):
-        lim = jnp.stack(lims).reshape(t, 1)
-        lim = jnp.broadcast_to(lim, (t, rep)).reshape(t * rep, 1)
-        return kpos < lim
-
-    _dmvm_block(*refs, bs=bs, live=pl.program_id(1) * bs < lim_max,
-                visible=visible, **kw)
+def _decode_kernel(len_ref, *refs, t: int, rep: int, bs: int, **kw):
+    """The plain decode step (``t == 1``): batch row ``b`` masks keys to
+    its cache length ``len_ref[b, 0]``."""
+    lim = len_ref[pl.program_id(0), 0]
+    _dmvm_block(*refs, bs=bs, live=pl.program_id(1) * bs < lim,
+                visible=lambda kpos: kpos < jnp.full((rep, 1), lim), **kw)
 
 
 def _tree_kernel(pos_ref, anc_ref, *refs, t: int, rep: int, bs: int, **kw):
@@ -116,8 +103,8 @@ def _tree_kernel(pos_ref, anc_ref, *refs, t: int, rep: int, bs: int, **kw):
     ``< pos_ref[b, 0]``) plus exactly the in-window keys whose node index
     is an ancestor-or-self of its node — bit ``j`` of ``anc_ref[b, r //
     rep]`` (int32, so t <= 31 in-window bits stay in the sign-safe range).
-    The stepped causal mask of the linear verify is the special case
-    anc[i] = (1 << (i+1)) - 1 (a chain)."""
+    A chain, anc[i] = (1 << (i+1)) - 1, gives the stepped causal mask of
+    t sequential decode steps."""
     b_idx = pl.program_id(0)
     base = pos_ref[b_idx, 0]
     ancs = [anc_ref[b_idx, i] for i in range(t)]      # t scalar SMEM reads
@@ -130,7 +117,7 @@ def _tree_kernel(pos_ref, anc_ref, *refs, t: int, rep: int, bs: int, **kw):
         return (kpos < base) | ((idx >= 0) & (idx < t) & (bit == 1))
 
     # no row sees past the window's last node (base + t - 1); blocks past it
-    # are fully masked — same dead-block skip as the linear kernel
+    # are fully masked — same dead-block skip as the decode kernel
     _dmvm_block(*refs, bs=bs, live=pl.program_id(1) * bs < base + t,
                 visible=visible, **kw)
 
@@ -178,25 +165,8 @@ def decode_attn_pallas(q_q, q_s, k_q, k_s, v_q, v_s, length, *,
     B, G, rep, D = q_q.shape
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1, 1),
                                (B, 1))
-    return _attn_pallas(_linear_kernel, (lengths,), q_q, q_s, k_q, k_s, v_q,
+    return _attn_pallas(_decode_kernel, (lengths,), q_q, q_s, k_q, k_s, v_q,
                         v_s, t=1, rep=rep, bs=bs, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("bs", "interpret"))
-def verify_attn_pallas(q_q, q_s, k_q, k_s, v_q, v_s, lengths, *,
-                       bs: int = BLOCK_S, interpret: bool | None = None):
-    """Speculative-verify flash decoding: q_q: [B,G,T,rep,D] int8 (T = the
-    last committed token + drafts per slot); lengths: [B,T] int32 per-row
-    key limits (``pos + t + 1``) -> out [B,G,T,rep,D] f32.  T folds into
-    the q row axis, so the dMVM dataflow is the T=1 kernel's with a
-    stepped per-row mask."""
-    B, G, T, rep, D = q_q.shape
-    out = _attn_pallas(_linear_kernel, (jnp.asarray(lengths, jnp.int32),),
-                       q_q.reshape(B, G, T * rep, D),
-                       q_s.reshape(B, G, T * rep, 1),
-                       k_q, k_s, v_q, v_s, t=T, rep=rep, bs=bs,
-                       interpret=interpret)
-    return out.reshape(B, G, T, rep, D)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
@@ -207,8 +177,8 @@ def verify_tree_attn_pallas(q_q, q_s, k_q, k_s, v_q, v_s, pos, anc, *,
     committed token / tree root); ``pos``: [B] int32 committed-prefix
     cursors; ``anc``: [B,T] int32 per-node ancestor bitmasks (bit j set
     iff node j is an ancestor-or-self of node i) -> [B,G,T,rep,D] f32.
-    Same launch geometry as :func:`verify_attn_pallas` with the stepped
-    limit replaced by (committed prefix) | (ancestor bit)."""
+    T folds into the q row axis, so the dMVM dataflow is the T=1 kernel's
+    with a per-row (committed prefix) | (ancestor bit) mask."""
     B, G, T, rep, D = q_q.shape
     smem = (jnp.asarray(pos, jnp.int32).reshape(B, 1),
             jnp.asarray(anc, jnp.int32))

@@ -26,25 +26,6 @@ def decode_attention(q, k_q, k_s, v_q, v_s, length,
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
-def verify_attention(q, k_q, k_s, v_q, v_s, pos,
-                     interpret: bool | None = None):
-    """Speculative-verify attention: q: [B,T,H,D] float (T = last committed
-    token + drafts per slot at positions ``pos[b]..pos[b]+T-1``); cache as
-    in :func:`decode_attention`; ``pos``: [B] (or scalar) int32 per-slot
-    cursors.  Query t of slot b masks keys to [0, pos[b]+t] -> [B,T,H,D]."""
-    B, T, H, D = q.shape
-    G = k_q.shape[2]
-    rep = H // G
-    q_q, q_s = quant.quantize_kv(q.reshape(B, T * H, D))
-    q_q = q_q.reshape(B, T, G, rep, D).transpose(0, 2, 1, 3, 4)
-    q_s = q_s.reshape(B, T, G, rep, 1).transpose(0, 2, 1, 3, 4)
-    pos_b = slot_positions(pos, B)
-    lens = pos_b[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :] + 1
-    out = K.verify_attn_pallas(q_q, q_s, k_q, k_s, v_q, v_s, lens,
-                               interpret=interpret)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, T, H, D).astype(q.dtype)
-
-
 def verify_attention_tree(q, k_q, k_s, v_q, v_s, pos, anc,
                           interpret: bool | None = None):
     """Tree-verify attention: q: [B,T,H,D] float (T draft-tree nodes per

@@ -33,30 +33,6 @@ def ref(q, k_q, k_s, v_q, v_s, length, out_dtype=None):
     return o.reshape(B, 1, H, D).astype(out_dtype or q.dtype)
 
 
-def verify_ref(q, k_q, k_s, v_q, v_s, pos, out_dtype=None):
-    """Speculative-verify oracle: q: [B,T,H,D] float; query t of slot b
-    attends keys [0, pos[b]+t] (``pos``: [B] int32 per-slot cursors)."""
-    B, T, H, D = q.shape
-    G = k_q.shape[2]
-    rep = H // G
-    q_q, q_s = quant.quantize_kv(q.reshape(B, T * H, D))
-    q_q = q_q.reshape(B, T, G, rep, D)
-    q_s = q_s.reshape(B, T, G, rep, 1)
-    s_int = jnp.einsum("btgrd,bsgd->btgrs", q_q.astype(jnp.int32),
-                       k_q.astype(jnp.int32))
-    k_sc = k_s[..., 0].transpose(0, 2, 1)[:, None, :, None, :]   # [B,1,G,1,S]
-    scores = s_int.astype(jnp.float32) * q_s * k_sc / math.sqrt(D)
-    S = k_q.shape[1]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-    limit = (pos[:, None] + jnp.arange(T) + 1)[:, :, None, None, None]
-    mask = jnp.arange(S)[None, None, None, None, :] < limit
-    scores = jnp.where(mask, scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    vf = v_q.astype(jnp.float32) * v_s
-    o = jnp.einsum("btgrs,bsgd->btgrd", w, vf)
-    return o.reshape(B, T, H, D).astype(out_dtype or q.dtype)
-
-
 def verify_tree_ref(q, k_q, k_s, v_q, v_s, pos, anc, out_dtype=None):
     """Tree-verify oracle: q: [B,T,H,D] float (T tree nodes per slot at
     cache rows ``pos[b]..pos[b]+T-1``; node 0 is the root / last committed

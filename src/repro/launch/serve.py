@@ -18,14 +18,9 @@ iteration):
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
         --continuous --chunk 8 --policy sjf --requests 16 --slots 4
 
-Speculative decode (draft ``K`` tokens per slot, verify all K+1 positions
-in one batched step, roll rejected suffixes back via a cursor rewind):
-
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
-        --continuous --chunk 4 --spec-k 4 --drafter ngram
-
-Tree-draft speculative decode (a token *tree* per slot instead of a
-chain: ancestor-masked verify, accepted root-path compacted in place):
+Speculative decode (a draft token *tree* of ``N`` nodes per slot, one
+ancestor-masked verify step, the accepted root-path committed in place;
+``--spec-branch 1`` drafts a plain chain):
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
         --continuous --chunk 4 --spec-tree 4 --spec-branch 2
@@ -207,7 +202,6 @@ def _run_continuous(cfg, params, args):
                                    quantize=not args.no_quantize,
                                    policy=args.policy, chunk=args.chunk,
                                    max_step_tokens=args.max_step_tokens,
-                                   spec_k=args.spec_k,
                                    spec_tree=args.spec_tree,
                                    spec_branch=args.spec_branch,
                                    drafter=args.drafter,
@@ -241,10 +235,9 @@ def _run_continuous(cfg, params, args):
     print(f"steps={eng.stats['steps']} chunks={eng.stats['chunks']} "
           f"preemptions={eng.stats['preemptions']} "
           f"max prefill tokens/step={eng.stats['max_step_prefill_tokens']}")
-    if eng.spec_k or eng.spec_tree:
-        lane = (f"tree={eng.spec_tree} branch={eng.spec_branch}"
-                if eng.spec_tree else f"k={eng.spec_k}")
-        print(f"spec: {lane} drafter={eng._drafter.name} "
+    if eng.spec_tree:
+        print(f"spec: tree={eng.spec_tree} branch={eng.spec_branch} "
+              f"drafter={eng._drafter.name} "
               f"verify_steps={eng.stats['verify_steps']} "
               f"acceptance={eng.acceptance_rate:.2%} "
               f"accept_hist={eng.stats['spec_accept_hist']}")
@@ -283,7 +276,6 @@ def _run_serve(cfg, params, args):
                                    quantize=not args.no_quantize,
                                    policy=args.policy, chunk=args.chunk,
                                    max_step_tokens=args.max_step_tokens,
-                                   spec_k=args.spec_k,
                                    spec_tree=args.spec_tree,
                                    spec_branch=args.spec_branch,
                                    drafter=args.drafter,
@@ -372,17 +364,13 @@ def main():
     ap.add_argument("--max-step-tokens", type=int, default=None,
                     help="per-iteration token budget (decode slots + prefill "
                          "chunk tokens); default slots + chunk")
-    ap.add_argument("--spec-k", type=int, default=0, metavar="K",
-                    help="speculative decode: draft K tokens per slot and "
-                         "verify all K+1 positions in one batched step "
-                         "(0 = off)")
     ap.add_argument("--spec-tree", type=int, default=0, metavar="N",
-                    help="tree-draft speculative decode: draft a token tree "
-                         "of N nodes per slot and verify the whole tree in "
-                         "one ancestor-masked step (0 = off; takes "
-                         "precedence over --spec-k)")
+                    help="speculative decode: draft a token tree of N nodes "
+                         "per slot and verify the whole tree in one "
+                         "ancestor-masked step (0 = off)")
     ap.add_argument("--spec-branch", type=int, default=2, metavar="B",
-                    help="tree-draft branching factor (with --spec-tree)")
+                    help="draft-tree branching factor (with --spec-tree; "
+                         "1 = a chain)")
     ap.add_argument("--drafter", default="ngram",
                     help='draft proposer: ngram[:N] (prompt lookup) | mtp '
                          '(multi-token-prediction head, cfg.mtp archs)')

@@ -327,7 +327,7 @@ def decode_attention_int8(q: jax.Array, k_q, k_s, v_q, v_s, length: jax.Array,
     to its own resident prefix).
     """
     B = q.shape[0]
-    if backend in ("fused_int8", "pallas"):
+    if backend == "fused_int8":
         from repro.kernels.decode_attn import ops as da_ops
         G = k_s.shape[2]
         return da_ops.decode_attention(q, KV.split_heads(k_q, G), k_s,
@@ -344,9 +344,9 @@ def tree_visibility_mask(pos_b: jax.Array, anc: jax.Array, S: int,
     """[B, T, S] bool tree-verify visibility: node ``t`` of slot ``b`` sees
     the committed prefix (keys ``< pos_b[b]``) plus in-window key
     ``pos_b[b]+j`` iff bit j of ``anc[b, t]`` (int32 ancestor-or-self
-    bitmask; node 0 = root = last committed token) is set.  The linear
-    verify's stepped causal mask is the chain special case
-    ``anc[i] = (1 << (i+1)) - 1``."""
+    bitmask; node 0 = root = last committed token) is set.  A chain's
+    mask, ``anc[i] = (1 << (i+1)) - 1``, is the stepped causal mask of T
+    sequential decode steps."""
     idx = jnp.arange(S, dtype=jnp.int32)[None, :] - pos_b[:, None]   # [B,S]
     committed = idx < 0
     in_win = (idx >= 0) & (idx < T)
@@ -357,50 +357,39 @@ def tree_visibility_mask(pos_b: jax.Array, anc: jax.Array, S: int,
 
 
 def verify_attention_int8(q: jax.Array, k_q, k_s, v_q, v_s, pos: jax.Array,
-                          backend: str = "dense",
-                          inter_dtype=jnp.float32, anc=None) -> jax.Array:
-    """Speculative-verify attention: ``q`` is [B, T, H, D] — T query tokens
-    per slot sitting at positions ``pos[b] .. pos[b]+T-1`` (the last
-    committed token plus T-1 drafts); cache layout as in
-    :func:`decode_attention_int8`.
+                          anc: jax.Array, backend: str = "dense",
+                          inter_dtype=jnp.float32) -> jax.Array:
+    """Speculative-verify attention: ``q`` is [B, T, H, D] — the T nodes of
+    a per-slot draft *tree* whose K/V rows sit at ``pos[b] .. pos[b]+T-1``
+    (node 0 = root = the last committed token); cache layout as in
+    :func:`decode_attention_int8`.  ``anc`` ([B, T] int32) holds each
+    node's ancestor-or-self bitmask, and the mask is
+    :func:`tree_visibility_mask`: node ``t`` sees the committed prefix and
+    its own root-path.  The T query rows run through the same dMVM einsums
+    as the T=1 decode (:func:`_lane_dense_attend`): int8xint8 scores are
+    exact integer arithmetic.
 
-    Query ``t`` of slot ``b`` attends keys ``[0, pos[b]+t]`` — the per-row
-    causal mask that makes one batched pass score every draft position
-    exactly as T sequential decode steps would.  The T query rows run
-    through the same dMVM einsums as the T=1 decode
-    (:func:`_lane_dense_attend`): int8xint8 scores are exact integer
-    arithmetic, so acceptance decisions match step-by-step decode
-    bit-for-bit.
-
-    With ``anc`` ([B, T] int32 ancestor bitmasks) the T tokens are a draft
-    *tree* and the stepped mask becomes :func:`tree_visibility_mask`; a
-    node's unmasked keys hold exactly the values sequential decode of its
+    A node's unmasked keys hold exactly the values sequential decode of its
     root-path would see.  Nodes whose ancestor set is a window *prefix*
-    (chain-prefix nodes) stay bit-exact with sequential decode; a node
-    whose path skips an interleaved sibling sees the same visible values
-    at shifted lane positions — masked keys weigh exactly zero, but the
-    vectorised softmax/PV reductions associate across lanes differently,
-    so those rows match only up to float reduction order (~1 ulp; the
-    engine's greedy token parity is pinned by test seeds, like the warm
-    prefix bar in DESIGN.md Sec. 1g).
+    (chain-prefix nodes; every node of a chain, whose mask is the stepped
+    causal one) stay bit-exact with sequential decode, so acceptance
+    decisions match step-by-step decode bit-for-bit; a node whose path
+    skips an interleaved sibling sees the same visible values at shifted
+    lane positions — masked keys weigh exactly zero, but the vectorised
+    softmax/PV reductions associate across lanes differently, so those
+    rows match only up to float reduction order (~1 ulp; the engine's
+    greedy token parity is pinned by test seeds, like the warm prefix bar
+    in DESIGN.md Sec. 1g).
     """
     B, T, H, D = q.shape
     pos_b = KV.slot_positions(pos, B)
-    if backend in ("fused_int8", "pallas"):
+    if backend == "fused_int8":
         from repro.kernels.decode_attn import ops as da_ops
         G = k_s.shape[2]
-        k_q, v_q = KV.split_heads(k_q, G), KV.split_heads(v_q, G)
-        if anc is not None:
-            return da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s,
-                                                pos_b, anc)
-        return da_ops.verify_attention(q, k_q, k_s, v_q, v_s, pos_b)
-    S = k_q.shape[1]
-    if anc is not None:
-        mask = tree_visibility_mask(pos_b, anc, S, T)[:, :, None, :]
-    else:
-        # query t attends keys [0, pos + t]
-        limit = pos_b[:, None] + jnp.arange(T)[None, :] + 1      # [B,T]
-        mask = jnp.arange(S)[None, None, None, :] < limit[:, :, None, None]
+        return da_ops.verify_attention_tree(
+            q, KV.split_heads(k_q, G), k_s, KV.split_heads(v_q, G), v_s,
+            pos_b, anc)
+    mask = tree_visibility_mask(pos_b, anc, k_q.shape[1], T)[:, :, None, :]
     o = _lane_dense_attend(q, k_q, k_s, v_q, v_s, mask, inter_dtype)
     return o.astype(q.dtype)
 
@@ -575,23 +564,24 @@ def mla_decode(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
 
 @_scoped("mla")
 def mla_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
-               c_q: jax.Array, c_s: jax.Array, backend: str = "dense",
-               inter_dtype=jnp.float32, depth=None, anc=None):
-    """Absorbed MLA decode over T tokens per slot — the speculative verify
-    sibling of :func:`mla_decode`.  The T compressed latents append at the
-    per-slot cursor (multi-token :func:`KV.batched_update`); query ``t``
-    masks the latent cache to ``[0, pos[b]+t]``, so all T positions score
-    against exactly the prefix T sequential decode steps would see.
-    Tree mode (``depth``/``anc``): RoPE at tree depth, ancestor mask — see
-    :func:`repro.models.transformer.apply_layer_verify`."""
+               c_q: jax.Array, c_s: jax.Array, depth: jax.Array,
+               anc: jax.Array, backend: str = "dense",
+               inter_dtype=jnp.float32):
+    """Absorbed MLA decode over the T nodes of a per-slot draft tree — the
+    speculative verify sibling of :func:`mla_decode`.  The T compressed
+    latents append at the per-slot cursor (multi-token
+    :func:`KV.batched_update`), RoPE'd at their tree depth ``depth``;
+    node ``t`` masks the latent cache to the committed prefix plus its
+    root-path (``anc``, :func:`tree_visibility_mask`), so every node scores
+    against exactly the prefix sequential decode of its path would see —
+    see :func:`repro.models.transformer.apply_layer_verify`."""
     B, T, _ = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     freqs = mla_rope_freqs(cfg)
     r = cfg.kv_lora_rank
     pos_b = KV.slot_positions(pos, B)
-    off = jnp.arange(T)[None, :] if depth is None else depth
-    pp = pos_b[:, None] + off
+    pp = pos_b[:, None] + depth
     q_lat = L.apply_norm(p["q_norm"], L.apply_linear(L._lin(p, "wq_a"), x, backend),
                          cfg.norm_eps)
     q = L.apply_linear(L._lin(p, "wq_b"), q_lat, backend).reshape(B, T, H, dn + dr)
@@ -618,11 +608,7 @@ def mla_verify(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
               jnp.einsum("bthd,bsd->bths", q_rope.astype(inter_dtype),
                          cache[..., r:], preferred_element_type=jnp.float32))
     scores = scores * mla_softmax_scale(cfg)
-    S = c_q.shape[1]
-    if anc is not None:
-        mask = tree_visibility_mask(pos_b, anc, S, T)[:, :, None, :]
-    else:
-        mask = jnp.arange(S)[None, None, None, :] < (pp + 1)[:, :, None, None]
+    mask = tree_visibility_mask(pos_b, anc, c_q.shape[1], T)[:, :, None, :]
     scores = jnp.where(mask, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     o_lat = jnp.einsum("bths,bsr->bthr", w.astype(inter_dtype), cache[..., :r],

@@ -154,15 +154,16 @@ def multi_decode_step(params, cfg: ModelConfig, state: dict, token, m: int,
 
 
 def verify_step(params, cfg: ModelConfig, state: dict, tokens, rt: Runtime,
-                depth=None, anc=None):
-    """Speculative-decode verify: ``tokens`` [B, T] (last committed token +
-    T-1 drafts per slot) -> (logits [B, T, V], hidden [B, T, d], state with
-    ``pos + T``).  With ``depth``/``anc`` ([B, T] int32) the window is a
-    draft *tree* (ancestor masking, depth positions).  Decoder-only
-    attention stacks; see :func:`repro.models.transformer.verify_step`."""
+                depth, anc):
+    """Speculative-decode verify of a per-slot draft tree: ``tokens``
+    [B, T] (root = last committed token + T-1 drafted nodes per slot) with
+    ``depth``/``anc`` ([B, T] int32 tree depths and ancestor bitmasks) ->
+    (logits [B, T, V], hidden [B, T, d], state with ``pos + T``).
+    Decoder-only attention stacks; see
+    :func:`repro.models.transformer.verify_step`."""
     if cfg.family == "encdec":
         raise NotImplementedError("speculative decode targets decoder-only LMs")
-    return T.verify_step(params, cfg, state, tokens, rt, depth=depth, anc=anc)
+    return T.verify_step(params, cfg, state, tokens, rt, depth, anc)
 
 
 def tree_commit(state: dict, base, sel, keep, pos):
@@ -170,14 +171,6 @@ def tree_commit(state: dict, base, sel, keep, pos):
     contiguous committed rows and rewind the cursor — see
     :func:`repro.models.transformer.tree_commit`."""
     return T.tree_commit(state, base, sel, keep, pos)
-
-
-def mtp_draft(params, cfg: ModelConfig, hidden, token, pos, k: int,
-              rt: Runtime):
-    """Draft ``k`` tokens per slot from the MTP head (requires ``cfg.mtp``)."""
-    if cfg.family == "encdec":
-        raise NotImplementedError("speculative decode targets decoder-only LMs")
-    return T.mtp_draft(params, cfg, hidden, token, pos, k, rt)
 
 
 def mtp_draft_tree(params, cfg: ModelConfig, hidden, token, pos, n: int,

@@ -416,7 +416,7 @@ def warm_prefill_carry(cfg: ModelConfig, state: dict, slot: jax.Array,
     GQA attention stacks only: the MLA pool caches the compressed latent
     (reconstructing the carry's per-head K/V needs per-layer weights) and
     SSM state cannot restart mid-prompt — the serve engine silently
-    disables the prefix cache for both, mirroring ``chunk``/``spec_k``.
+    disables the prefix cache for both, mirroring ``chunk``/``spec_tree``.
     """
     if cfg.attn_type == "mla":
         raise NotImplementedError(
@@ -618,78 +618,69 @@ def multi_decode_step(p: Params, cfg: ModelConfig, state: dict,
 # speculative decode: batched multi-token verify + cursor rollback + MTP draft
 # ---------------------------------------------------------------------------
 def apply_layer_verify(p: Params, cfg: ModelConfig, slot: int, x, pos, cache,
-                       rt: Runtime, depth=None, anc=None):
+                       rt: Runtime, depth, anc):
     """One layer of the speculative verify pass: like
-    :func:`apply_layer_decode` but over ``x`` [B, T, d] (T = 1 + drafted
-    tokens per slot): the window's T K/V rows land at each slot's cursor
-    in one multi-token :func:`KV.batched_update` (the SLC append chunked
-    prefill makes too) and all T queries are scored over the layer in one
-    pass (:func:`A.verify_attention_int8`).  K/V rows and integer scores
-    are bit-identical to T sequential decode steps, which is what makes
-    greedy speculative decode token-identical to the plain engine.
-
-    Tree mode (``depth``/``anc`` both [B, T] int32): the T tokens are draft
-    *tree* nodes — node i's row still lands at cache offset ``pos + i``,
-    but RoPE rotates it at its tree depth (``pos + depth[b, i]``) and the
-    stepped mask becomes the ancestor mask, so each node's K row and
-    scores match what sequential decode of its root-path would produce
-    (chain-prefix nodes bit-exactly; past a skipped sibling, up to float
-    reduction order — see :func:`attention.verify_attention_int8`)."""
+    :func:`apply_layer_decode` but over ``x`` [B, T, d], the T nodes of a
+    per-slot draft tree (``depth``/``anc`` both [B, T] int32; T = root +
+    drafted nodes).  Node i's K/V row lands at cache offset ``pos + i`` in
+    one multi-token :func:`KV.batched_update` (the SLC append chunked
+    prefill makes too), RoPE rotates it at its tree depth
+    (``pos + depth[b, i]``), and all T queries are scored over the layer in
+    one ancestor-masked pass (:func:`A.verify_attention_int8`).  Each
+    node's K row and scores match what sequential decode of its root-path
+    would produce — chain-prefix nodes bit-exactly (a chain, ``depth[b, i]
+    = i``, is T sequential decode steps), which is what makes greedy
+    speculative decode token-identical to the plain engine; past a skipped
+    sibling, up to float reduction order (see
+    :func:`attention.verify_attention_int8`)."""
     dmvm_dt = rt.dmvm_dtype or jnp.float32
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps)
     if cfg.attn_type == "mla":
         mix, (c_q, c_s) = A.mla_verify(p["attn"], cfg, h, pos, cache["c_q"],
-                                       cache["c_s"], rt.backend, dmvm_dt,
-                                       depth=depth, anc=anc)
+                                       cache["c_s"], depth, anc, rt.backend,
+                                       dmvm_dt)
         new_cache = {"c_q": c_q, "c_s": c_s}
     else:
-        B, T = x.shape[:2]
-        pos_b = KV.slot_positions(pos, B)
-        off = jnp.arange(T)[None, :] if depth is None else depth
-        q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b[:, None] + off,
+        pos_b = KV.slot_positions(pos, x.shape[0])
+        q, rows = A.gqa_decode_rows(p["attn"], cfg, h, pos_b[:, None] + depth,
                                     rt.backend)
 
-        def update_attend(c, r, qq, p, *a):
+        def update_attend(c, r, qq, p, a):
             c = {n: KV.batched_update(c[n], rr, p)
                  for n, rr in zip(_KV_LEAVES, r)}
             return c, A.verify_attention_int8(
-                qq, *(c[n] for n in _KV_LEAVES), p, rt.backend, dmvm_dt,
-                anc=a[0] if a else None)
+                qq, *(c[n] for n in _KV_LEAVES), p, a, rt.backend, dmvm_dt)
         new_cache, o = _kv_attend(update_attend, cache, rows, q, pos_b, rt,
-                                  *(() if anc is None else (anc,)))
+                                  anc)
         mix = A.gqa_decode_out(p["attn"], o, rt.backend)
     x, _ = _ffn(p, x + mix, cfg, rt, None)
     return x, new_cache
 
 
 def verify_step(p: Params, cfg: ModelConfig, state: dict, tokens: jax.Array,
-                rt: Runtime, depth=None, anc=None,
+                rt: Runtime, depth: jax.Array, anc: jax.Array,
                 ) -> tuple[jax.Array, jax.Array, dict]:
-    """Speculative-decode verify: feed ``tokens`` [B, T] (per slot: the last
-    committed token plus T-1 drafted tokens) at each slot's cursor in one
-    batched pass.
+    """Speculative-decode verify: feed ``tokens`` [B, T] — per slot a draft
+    *tree* in topological order (node 0 = root = last committed token) —
+    at each slot's cursor in one batched pass.  ``depth``/``anc`` ([B, T]
+    int32) give each node's tree depth and ancestor-or-self bitmask (bit j
+    set iff node j is an ancestor-or-self of node i); a chain of T-1
+    drafts is ``depth = [0, 1, ...]``, ``anc = [1, 3, 7, ...]``.
 
     Returns ``(logits [B, T, V], hidden [B, T, d], new state)`` — row ``i``
-    of ``logits`` is the model's next-token distribution after consuming
-    ``tokens[:, :i+1]``, exactly what ``i+1`` sequential
-    :func:`decode_step` calls would produce, so greedy acceptance is
-    lossless.  ``hidden`` is the post-``ln_f`` hidden state per position
-    (the MTP drafter's recursion carry).  The returned state has
-    ``pos + T`` and all T K/V rows appended; the caller commits an accepted
-    prefix by *rewinding* the cursor (:func:`rewind_pos`) — rejected-suffix
-    rows stay in the SLC region as dead entries that the position mask
-    hides and the next in-place append overwrites (no erase cycle).
-
-    Tree mode (``depth``/``anc`` both [B, T] int32): ``tokens[:, i]`` is
-    node i of a per-slot draft *tree* in topological order (node 0 = root =
-    last committed token; ``anc[b, i]`` has bit j set iff node j is an
-    ancestor-or-self of node i).  Positions come from tree depth, masks
-    from ancestry, so row i's logits equal what sequential decode of node
-    i's root-path would produce — bit-exactly for chain-prefix nodes,
-    and up to float reduction order (~1 ulp) past a skipped sibling
-    (:func:`repro.models.attention.verify_attention_int8`).  The caller
-    walks the tree host-side and commits the longest accepted root-path
-    with :func:`tree_commit`.
+    of ``logits`` is the model's next-token distribution after node i's
+    root-path, what sequential :func:`decode_step` calls along that path
+    would produce: bit-exactly for chain-prefix nodes, and up to float
+    reduction order (~1 ulp) past a skipped sibling
+    (:func:`repro.models.attention.verify_attention_int8`), so greedy
+    acceptance is lossless.  ``hidden`` is the post-``ln_f`` hidden state
+    per node (the MTP drafter's recursion carry).  The returned state has
+    ``pos + T`` and all T K/V rows appended at ``pos .. pos + T - 1``; the
+    caller walks the tree host-side and commits the longest accepted
+    root-path with :func:`tree_commit` (or, when that path is the chain
+    prefix, by *rewinding* the cursor, :func:`rewind_pos`) — rejected rows
+    stay in the SLC region as dead entries that the position mask hides
+    and the next in-place append overwrites (no erase cycle).
 
     Attention-family stacks only: an SSM layer's recurrent state cannot be
     rewound without checkpointing, so SSM/hybrid engines keep the plain
@@ -703,9 +694,8 @@ def verify_step(p: Params, cfg: ModelConfig, state: dict, tokens: jax.Array,
     pos = jnp.broadcast_to(jnp.asarray(state["pos"], jnp.int32), (B,))
     x = p["embed"]["w"][tokens]
     if not cfg.rope_theta:
-        off = jnp.arange(T)[None, :] if depth is None else depth
-        pp = pos[:, None] + off
-        x = x + _sinusoid_at(pp, cfg.d_model).astype(x.dtype)
+        x = x + _sinusoid_at(pos[:, None] + depth,
+                             cfg.d_model).astype(x.dtype)
     new_groups = []
     for (start, count, period), slots, caches in zip(
             layer_groups(cfg), p["groups"], state["groups"]):
@@ -721,8 +711,7 @@ def verify_step(p: Params, cfg: ModelConfig, state: dict, tokens: jax.Array,
                                                            keepdims=False),
                     full_caches[s])
                 xx, nc = apply_layer_verify(slot_trees[s], cfg, start + s, xx,
-                                            pos, cache_s, rt,
-                                            depth=depth, anc=anc)
+                                            pos, cache_s, rt, depth, anc)
                 new_full.append(jax.tree.map(
                     lambda full, new: jax.lax.dynamic_update_slice_in_dim(
                         full, new[None].astype(full.dtype), idx, 0),
@@ -778,33 +767,6 @@ def _mtp_cell(p: Params, cfg: ModelConfig, h, tok, pos_i, rt: Runtime):
     return _lm_head(p, cfg, hm3[:, 0], rt), hm3[:, 0]
 
 
-def mtp_draft(p: Params, cfg: ModelConfig, hidden: jax.Array,
-              token: jax.Array, pos: jax.Array, k: int,
-              rt: Runtime) -> jax.Array:
-    """Draft ``k`` tokens per slot from the MTP head (DeepSeek-V3's depth-1
-    multi-token-prediction module, applied recursively): step ``i``
-    projects ``[h; embed(tok)]`` through ``mtp_proj``/``mtp_layer`` and
-    takes the greedy argmax, feeding the new hidden state forward.
-
-    ``hidden`` [B, d] is the post-``ln_f`` hidden at the last committed
-    position (from :func:`verify_step`; zeros right after prefill — the
-    head free-runs from the embedding alone there).  The draft is
-    single-position (the MTP layer's attention sees only its own token, no
-    KV cache), so it is cheap but approximate — the verify step makes any
-    draft quality lossless; it only costs acceptance rate."""
-    if not cfg.mtp:
-        raise ValueError(f"{cfg.name} has no MTP head (cfg.mtp is False)")
-    drafts = []
-    h = hidden.astype(jnp.float32)
-    tok = jnp.asarray(token, jnp.int32)
-    pos = jnp.asarray(pos, jnp.int32)
-    for i in range(k):
-        logits, h = _mtp_cell(p, cfg, h, tok, pos + i, rt)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        drafts.append(tok)
-    return jnp.stack(drafts, axis=1)                            # [B, k]
-
-
 def mtp_chain_lengths(n: int, branch: int) -> list[int]:
     """Per-chain node budgets for the MTP draft-tree beam: ``n`` draft
     nodes split across ``min(branch, n)`` root-child chains, earlier
@@ -824,8 +786,15 @@ def mtp_draft_tree(p: Params, cfg: ModelConfig, hidden: jax.Array,
 
     Returns tokens [B, n] in chain-major node order — chain j's nodes are
     consecutive, first node a child of the root.  At ``branch=1`` this is
-    exactly :func:`mtp_draft` (one greedy chain).  The topology is static
-    per (n, branch), so the engine derives parent pointers host-side."""
+    one greedy chain.  The topology is static per (n, branch), so the
+    engine derives parent pointers host-side.
+
+    ``hidden`` [B, d] is the post-``ln_f`` hidden at the last committed
+    position (from :func:`verify_step`; zeros right after prefill — the
+    head free-runs from the embedding alone there).  Each step is
+    single-position (the MTP layer's attention sees only its own token, no
+    KV cache), so the draft is cheap but approximate — the verify step
+    makes any draft quality lossless; it only costs acceptance rate."""
     if not cfg.mtp:
         raise ValueError(f"{cfg.name} has no MTP head (cfg.mtp is False)")
     lens = mtp_chain_lengths(n, branch)

@@ -12,25 +12,26 @@ cheap and approximate.  Two flavours ship:
   loops (which untrained seed params reliably do — the reason synthetic
   traces get non-trivial acceptance).
 * :class:`MTPDrafter` (``kind="model"``) — the DeepSeek-V3 multi-token-
-  prediction head (``cfg.mtp``): a jitted batched recursion over
-  ``mtp_proj``/``mtp_layer`` that drafts ``k`` tokens for every slot at
+  prediction head (``cfg.mtp``): a jitted batched beam over
+  ``mtp_proj``/``mtp_layer`` that drafts a token tree for every slot at
   once from the last verify step's hidden carry
-  (:func:`repro.models.transformer.mtp_draft`).
+  (:func:`repro.models.transformer.mtp_draft_tree`).
 
 ``kind`` tells the engine how to call it: "host" drafters expose
-``draft(context, k) -> list[int]`` per request; "model" drafters expose
-``draft_batch(params, hidden, token, pos) -> [n_slots, k]`` over the whole
-pool.
+``draft_tree(context, n, branch)`` per request (and ``draft(context, k)``,
+the chain a replaying request's tail is padded with); "model" drafters
+expose ``draft_tree_batch(params, hidden, token, pos) -> [n_slots, n]``
+over the whole pool.
 
-Tree drafts (the ``spec_tree`` lane) are ``(tokens, parents)`` pairs in
-*draft space*: ``parents[i]`` is the index of node i's parent among the
-drafted nodes, or -1 for a child of the root (the last committed token —
-the engine holds window index 0 for it).  Parents are topological
-(``parents[i] < i``) and siblings carry distinct tokens, so the engine's
-accept walk is unambiguous.  Host drafters override :meth:`draft_tree`
-(the base class falls back to a linear chain of :meth:`draft`); the model
-drafter beams the MTP head into a static chain-major topology
-(:func:`repro.models.transformer.mtp_draft_tree`).
+Drafts are ``(tokens, parents)`` trees in *draft space*: ``parents[i]`` is
+the index of node i's parent among the drafted nodes, or -1 for a child of
+the root (the last committed token — the engine holds window index 0 for
+it).  Parents are topological (``parents[i] < i``) and siblings carry
+distinct tokens, so the engine's accept walk is unambiguous.  A chain
+(``branch=1``) is ``parents = [-1, 0, 1, ...]``.  Host drafters override
+:meth:`draft_tree` (the base class falls back to a chain of
+:meth:`draft`); the model drafter beams the MTP head into a static
+chain-major topology.
 """
 from __future__ import annotations
 
@@ -52,7 +53,7 @@ def tree_depths_ancestors(parents: list[int]) -> tuple[list[int], list[int]]:
     have length ``len(parents) + 1`` and describe the *window*: entry 0 is
     the root (depth 0, anc bit 0), entry i+1 is draft node i at window
     index i+1 with bit i+1 OR'd onto its parent's mask — the operands
-    :func:`repro.models.transformer.verify_step` takes in tree mode.
+    :func:`repro.models.transformer.verify_step` takes.
     """
     depth = [0]
     anc = [1]
@@ -81,9 +82,6 @@ class Drafter:
         the linear draft as a single chain — any drafter works in the tree
         lane unchanged; branching only raises acceptance."""
         return self.draft(context, n), chain_parents(n)
-
-    def draft_batch(self, params, hidden, token, pos):
-        raise NotImplementedError
 
     def draft_tree_batch(self, params, hidden, token, pos):
         raise NotImplementedError
@@ -158,15 +156,14 @@ class MTPDrafter(Drafter):
     """Batched MTP-head drafting over the slot pool.  ``hidden`` is the
     post-``ln_f`` hidden at each slot's last committed position (zeros
     right after prefill — the head free-runs from the embedding there).
-    With ``tree_branch`` set, :meth:`draft_tree_batch` beams the head
-    instead: top-``branch`` first tokens each root a greedy chain
-    (static chain-major topology exposed as :attr:`tree_parents`)."""
+    :meth:`draft_tree_batch` beams the head: top-``branch`` first tokens
+    each root a greedy chain (static chain-major topology exposed as
+    :attr:`tree_parents`; ``branch=1`` is one greedy chain)."""
 
     name = "mtp"
     kind = "model"
 
-    def __init__(self, cfg: ModelConfig, rt, k: int,
-                 tree_branch: int | None = None):
+    def __init__(self, cfg: ModelConfig, rt, n: int, branch: int):
         if not cfg.mtp:
             raise ValueError(
                 f"{cfg.name} has no MTP head (cfg.mtp is False); "
@@ -174,39 +171,28 @@ class MTPDrafter(Drafter):
         from repro.models import model as M
         from repro.models import transformer as T
 
-        def mtp_draft(p, h, t, pos):
-            return M.mtp_draft(p, cfg, h, t, pos, k, rt)
+        def mtp_draft_tree(p, h, t, pos):
+            return M.mtp_draft_tree(p, cfg, h, t, pos, n, branch, rt)
 
-        self._fn = jax.jit(mtp_draft)
-        self.tree_parents: list[int] | None = None
-        if tree_branch is not None:
-            def mtp_draft_tree(p, h, t, pos):
-                return M.mtp_draft_tree(p, cfg, h, t, pos, k, tree_branch, rt)
-
-            self._tree_fn = jax.jit(mtp_draft_tree)
-            parents = []
-            for clen in T.mtp_chain_lengths(k, tree_branch):
-                prev = -1
-                for _ in range(clen):
-                    parents.append(prev)
-                    prev = len(parents) - 1
-            self.tree_parents = parents
-
-    def draft_batch(self, params, hidden, token, pos):
-        return self._fn(params, jnp.asarray(hidden),
-                        jnp.asarray(token, jnp.int32),
-                        jnp.asarray(pos, jnp.int32))
+        self._fn = jax.jit(mtp_draft_tree)
+        parents: list[int] = []
+        for clen in T.mtp_chain_lengths(n, branch):
+            prev = -1
+            for _ in range(clen):
+                parents.append(prev)
+                prev = len(parents) - 1
+        self.tree_parents = parents
 
     def draft_tree_batch(self, params, hidden, token, pos):
-        return self._tree_fn(params, jnp.asarray(hidden),
+        return self._fn(params, jnp.asarray(hidden),
                              jnp.asarray(token, jnp.int32),
                              jnp.asarray(pos, jnp.int32))
 
 
 def make_drafter(spec: "str | Drafter | None", cfg: ModelConfig, rt,
-                 k: int, tree_branch: int | None = None) -> Drafter:
+                 n: int, branch: int) -> Drafter:
     """``"ngram" | "ngram:N" (max n-gram) | "mtp"`` or a built instance.
-    ``tree_branch`` (engine's ``spec_branch``, tree lane only) pre-builds
+    ``n``/``branch`` (the engine's ``spec_tree``/``spec_branch``) pre-build
     the model drafter's beam topology."""
     if spec is None:
         return NGramDrafter()
@@ -216,5 +202,5 @@ def make_drafter(spec: "str | Drafter | None", cfg: ModelConfig, rt,
     if name == "ngram":
         return NGramDrafter(max_n=int(arg)) if arg else NGramDrafter()
     if name == "mtp":
-        return MTPDrafter(cfg, rt, k, tree_branch=tree_branch)
+        return MTPDrafter(cfg, rt, n, branch)
     raise ValueError(f"unknown drafter {spec!r}; one of ['ngram', 'mtp']")

@@ -35,27 +35,25 @@ pure decode steady state (no queue, no prefill, no replay, all greedy),
 paying one host round-trip per ``m`` tokens; EOS/budget overshoot unwinds
 through the same cursor rewind the speculative lane uses.
 
-With ``spec_k=k`` the continuous engine adds a *speculative decode lane*:
-a drafter proposes ``k`` tokens per decoding slot, one batched verify step
-scores all ``k+1`` positions against the pooled SLC cache, and each slot
-commits its accepted prefix while the rejected suffix rolls back via a
-cursor rewind (SLC writes are in place — rollback is free, no erase).  On
-the paper's bandwidth-bound PIM array every decode step pays a full
-weight-read MVM pass, so verifying ``k+1`` tokens per pass amortizes that
-read cost by the acceptance rate.  Greedy speculative output is
-token-identical to the plain engine (the verify logits are bit-identical
-to sequential decode), and sampled requests stay stream-exact: one RNG
-draw per emitted token, acceptance = "draft equals the sampled token".
-
-With ``spec_tree=n`` the lane drafts a *token tree* instead of a chain
-(``spec_branch`` controls the drafter's branching): the verify window
-carries per-row depths and int32 ancestor bitmasks so the causal mask
-becomes an ancestor mask, the host walks the verified tree for the
-longest accepted root-path, and ``tree_commit`` compacts the accepted
-path's scattered K/V rows into contiguous committed rows before the
-cursor lands past them.  Same draft budget, higher acceptance — a chain
-only survives while every draft matches, a tree survives any drafted
-sibling matching.  ``spec_tree`` takes precedence over ``spec_k``.
+With ``spec_tree=n`` the continuous engine adds a *speculative decode
+lane*: a drafter proposes a token tree of ``n`` nodes per decoding slot
+(``spec_branch`` controls its branching; ``spec_branch=1`` is a plain
+chain), and one batched verify step scores the root (the last committed
+token) and all ``n`` nodes against the pooled SLC cache.  The window
+carries per-row depths and int32 ancestor bitmasks, so the causal mask is
+an ancestor mask; the host walks the verified tree for the longest
+accepted root-path, ``tree_commit`` compacts that path's scattered K/V
+rows into contiguous committed rows, and the cursor lands past them.
+Rejected branches die in place (SLC writes are in place — rollback is
+free, no erase).  When every slot's accepted path is its chain prefix
+(always so for a chain) the gather would be an identity, so the commit is
+the bare cursor rewind.  On the paper's bandwidth-bound PIM array every
+decode step pays a full weight-read MVM pass, so verifying ``n+1`` tokens
+per pass amortizes that read cost by the acceptance rate.  Greedy
+speculative output is token-identical to the plain engine (chain-prefix
+verify logits are bit-identical to sequential decode), and sampled
+requests stay stream-exact: one RNG draw per emitted token, acceptance =
+"node token equals the sampled token".
 """
 from __future__ import annotations
 
@@ -198,17 +196,17 @@ class ContinuousBatchingEngine:
       5. one batched W8A8 decode step over all slots; slots with a
          DECODING resident emit their next token (greedy, or per-request
          temperature/top-k sampling), other slots compute into masked
-         garbage.  With ``spec_k=k`` this decode is a *speculative verify*:
-         a drafter proposes ``k`` tokens per slot, the batched verify step
-         scores all ``k+1`` positions at once (their K/V appended in place
-         at each slot's cursor), accepted prefixes commit and rejected
-         suffixes roll back by rewinding the per-slot cursor — up to
-         ``k+1`` tokens per slot per weight-read pass.  A replaying
-         (preempt-resumed) slot drafts its own recorded tokens, so replay
-         consumes the spec lane at full acceptance and stays
-         token-identical.  SSM/hybrid stacks keep the one-token decode
-         (their recurrent state cannot rewind); ``spec_k`` is ignored for
-         them like ``chunk``.
+         garbage.  With ``spec_tree=n`` this decode is a *speculative
+         verify*: a drafter proposes an ``n``-node token tree per slot,
+         the batched verify step scores all ``n+1`` window rows at once
+         (their K/V appended in place at each slot's cursor), each slot
+         commits its longest accepted root-path and the rest rolls back
+         — up to ``n+1`` tokens per slot per weight-read pass.  A
+         replaying (preempt-resumed) slot drafts its own recorded tokens
+         as a chain, so replay consumes the spec lane at full acceptance
+         and stays token-identical.  SSM/hybrid stacks keep the
+         one-token decode (their recurrent state cannot rewind);
+         ``spec_tree`` is ignored for them like ``chunk``.
 
     Chunked prefill is exact for attention stacks (the carry keeps prefill
     precision), so outputs are token-identical to the unchunked engine for
@@ -238,7 +236,6 @@ class ContinuousBatchingEngine:
                  policy: str | SchedulingPolicy | None = "fifo",
                  chunk: int | None = None,
                  max_step_tokens: int | None = None,
-                 spec_k: int = 0,
                  spec_tree: int = 0,
                  spec_branch: int = 2,
                  drafter: str | Drafter | None = "ngram",
@@ -276,10 +273,8 @@ class ContinuousBatchingEngine:
         self.chunk = None if (chunk is None or self._has_ssm) else int(chunk)
         if self.chunk is not None and self.chunk < 1:
             raise ValueError("chunk must be >= 1")
-        if spec_k < 0:
-            raise ValueError("spec_k must be >= 0 (0 = no speculation)")
         if spec_tree < 0:
-            raise ValueError("spec_tree must be >= 0 (0 = no tree drafts)")
+            raise ValueError("spec_tree must be >= 0 (0 = no speculation)")
         if spec_tree > 30:
             # the ancestor bitmask is one int32 per window row: node w owns
             # bit w, the root owns bit 0, so spec_tree drafted nodes need
@@ -288,8 +283,7 @@ class ContinuousBatchingEngine:
         if spec_branch < 1:
             raise ValueError("spec_branch must be >= 1")
         # SSM/hybrid recurrent state cannot rewind: like `chunk`, the spec
-        # lanes silently fall back to the exact one-token decode there
-        self.spec_k = 0 if self._has_ssm else int(spec_k)
+        # lane silently falls back to the exact one-token decode there
         self.spec_tree = 0 if self._has_ssm else int(spec_tree)
         self.spec_branch = int(spec_branch)
         if multi_step < 1:
@@ -314,7 +308,7 @@ class ContinuousBatchingEngine:
         # attention stacks only — the MLA pool caches the compressed
         # latent (no per-head K/V to seed the warm carry from) and SSM
         # state cannot restart mid-prompt — both silently fall back to
-        # cold prefill, mirroring the `chunk`/`spec_k` discipline.
+        # cold prefill, mirroring the `chunk`/`spec_tree` discipline.
         self._pcache = None
         if prefix_cache and not self._has_ssm and cfg.attn_type != "mla":
             if self.chunk is None:
@@ -330,8 +324,7 @@ class ContinuousBatchingEngine:
         # appends starting at the last live position ever clamp-wrap onto
         # valid rows — the audited rule lives in kvcache.pool_headroom
         self._state_len = max_len + KV.pool_headroom(
-            spec_k=self.spec_k, spec_tree=self.spec_tree,
-            multi_step=self.multi_step)
+            spec_tree=self.spec_tree, multi_step=self.multi_step)
         self.state = M.init_decode_state(cfg, n_slots, self._state_len)
         if drain_stall_limit < 1:
             raise ValueError("drain_stall_limit must be >= 1")
@@ -439,12 +432,11 @@ class ContinuousBatchingEngine:
             # of dropping them; store evictions relay back as drop_cold
             self._pcache.attach_cold_tier(self._demote_leaf_rows,
                                           self._swap.drop)
-        if self.spec_k or self.spec_tree:
+        if self.spec_tree:
             # per-window accepted-length histogram: index = drafted tokens
             # committed by one verify pass (0 .. draft budget), list-valued
             # so it rides the same stats dict as the scalar counters
-            w = self.spec_tree if self.spec_tree else self.spec_k
-            self.stats["spec_accept_hist"] = [0] * (w + 1)
+            self.stats["spec_accept_hist"] = [0] * (self.spec_tree + 1)
 
         # every serve-path step donates its decode-state / carry argument:
         # the [layers, n_slots, S, H, D] int8 K/V pool (and the chunked
@@ -468,18 +460,11 @@ class ContinuousBatchingEngine:
             # donated — the pool stays live for the step's other slots.
             self._gather = jax.jit(T.copy_slot_prefix, donate_argnums=(0,))
             self._warm_carry = jax.jit(fn.warm_prefill_carry)
-        if self.spec_k or self.spec_tree:
-            # the tree lane takes precedence over the linear lane, so the
-            # drafter's budget is whichever window actually runs
-            k_draft = self.spec_tree if self.spec_tree else self.spec_k
-            self._drafter = make_drafter(
-                drafter, cfg, self.rt, k_draft,
-                tree_branch=self.spec_branch if self.spec_tree else None)
+        if self.spec_tree:
+            self._drafter = make_drafter(drafter, cfg, self.rt,
+                                         self.spec_tree, self.spec_branch)
             self._h_last = (np.zeros((n_slots, cfg.d_model), np.float32)
                             if self._drafter.kind == "model" else None)
-        if self.spec_k and not self.spec_tree:
-            self._verify = jax.jit(fn.verify_step, donate_argnums=(1,))
-        if self.spec_tree:
             self._verify_tree = jax.jit(fn.verify_tree, donate_argnums=(1,))
             self._tree_commit = jax.jit(M.tree_commit, donate_argnums=(0,))
         if self.multi_step > 1:
@@ -525,16 +510,12 @@ class ContinuousBatchingEngine:
             return M.multi_decode_step(p, cfg, s, t, self.multi_step, rt,
                                        counts=counts)
 
-        def verify_step(p, s, t):
-            return M.verify_step(p, cfg, s, t, rt)
-
         def verify_tree(p, s, t, dep, a):
             return M.verify_step(p, cfg, s, t, rt, depth=dep, anc=a)
 
         return SimpleNamespace(**{f.__name__: f for f in (
             prefill, init_prefill_carry, prefill_chunk, finalize_write,
-            warm_prefill_carry, decode_step, multi_decode_step, verify_step,
-            verify_tree)})
+            warm_prefill_carry, decode_step, multi_decode_step, verify_tree)})
 
     # -- sharded-serve path -----------------------------------------------
     def _shard_over_mesh(self, fn: SimpleNamespace) -> None:
@@ -577,33 +558,25 @@ class ContinuousBatchingEngine:
                 fn.multi_decode_step,
                 in_shardings=(qsh, ssh, self._io["tokens"]),
                 out_shardings=(self._io["block"], ssh), donate_argnums=(1,))
-        if self.spec_k or self.spec_tree:
-            # the verify step's I/O pins beside the pool so the spec lanes
-            # never migrate the SLC rows (same rule as the decode step)
-            vsh = SH.verify_shardings(self.n_slots, mesh)
-            self._io["verify_tokens"] = vsh["tokens"]
-        if self.spec_k and not self.spec_tree:
-            self._verify = jax.jit(
-                fn.verify_step,
-                in_shardings=(qsh, ssh, vsh["tokens"]),
-                out_shardings=(vsh["logits"], vsh["hidden"], ssh),
-                donate_argnums=(1,))
         if self.spec_tree:
+            # the verify step's I/O pins beside the pool so the spec lane
+            # never migrates the SLC rows (same rule as the decode step);
             # the [B, T] depth/anc window operands shard their slot axis
             # beside the draft tokens; the commit scalars replicate (they
             # feed per-slot dynamic slicing inside the jitted path gather)
-            tsh = SH.tree_verify_shardings(self.n_slots, mesh)
-            self._io["tree_window"] = tsh["window"]
-            self._io["tree_commit"] = tsh["commit"]
+            vsh = SH.verify_shardings(self.n_slots, mesh)
+            self._io["verify_tokens"] = vsh["tokens"]
+            self._io["tree_window"] = vsh["window"]
+            self._io["tree_commit"] = vsh["commit"]
             self._verify_tree = jax.jit(
                 fn.verify_tree,
-                in_shardings=(qsh, ssh, vsh["tokens"], tsh["window"],
-                              tsh["window"]),
+                in_shardings=(qsh, ssh, vsh["tokens"], vsh["window"],
+                              vsh["window"]),
                 out_shardings=(vsh["logits"], vsh["hidden"], ssh),
                 donate_argnums=(1,))
             self._tree_commit = jax.jit(
                 M.tree_commit,
-                in_shardings=(ssh,) + (tsh["commit"],) * 4,
+                in_shardings=(ssh,) + (vsh["commit"],) * 4,
                 out_shardings=ssh, donate_argnums=(0,))
         # admissions write a replicated B=1 row into the sharded pool; the
         # out_shardings pin keeps the pool resident (no migration per admit)
@@ -895,7 +868,7 @@ class ContinuousBatchingEngine:
         # host mirror of the slot cursor (the spec lane's rollback base):
         # after prefill the cache holds exactly the prompt
         self._slot_pos[req.slot] = req.prompt_len
-        if (self.spec_k or self.spec_tree) and self._h_last is not None:
+        if self.spec_tree and self._h_last is not None:
             self._h_last[req.slot] = 0.0      # MTP head free-runs post-prefill
         if req.replay_pos >= len(req.output) and req.should_stop():
             self._retire(req, self._now())            # budget of 1 token
@@ -1159,7 +1132,7 @@ class ContinuousBatchingEngine:
         req.state = RequestState.DECODING
         self._last_tok[req.slot] = req.output[fed]
         self._slot_pos[req.slot] = rows
-        if (self.spec_k or self.spec_tree) and self._h_last is not None:
+        if self.spec_tree and self._h_last is not None:
             self._h_last[req.slot] = 0.0  # MTP head free-runs post-restore
         return True
 
@@ -1318,7 +1291,7 @@ class ContinuousBatchingEngine:
         self.state = state
         self._slot_pos[:] = 0
         self._last_tok[:] = 0
-        if (self.spec_k or self.spec_tree) and self._h_last is not None:
+        if self.spec_tree and self._h_last is not None:
             self._h_last[:] = 0.0
 
     # -- one serving iteration --------------------------------------------
@@ -1464,9 +1437,6 @@ class ContinuousBatchingEngine:
         if self.spec_tree:
             self._spec_tree_decode(dec)
             return True
-        if self.spec_k:
-            self._spec_decode(dec)
-            return True
         if self._can_fuse(dec):
             self._multi_decode(dec)
             return True
@@ -1596,104 +1566,11 @@ class ContinuousBatchingEngine:
 
         return row_token
 
-    def _draft_for(self, req: Request, dr) -> list[int]:
-        """k draft tokens for one slot.  A replaying (preempt-resumed)
-        request drafts its own recorded tokens — perfect drafts, so replay
-        advances k+1 positions per verify step and stays token-identical.
-        The tail past the recorded output comes from the drafter."""
-        k = self.spec_k
-        d = list(req.output[req.replay_pos:req.replay_pos + k])
-        if len(d) < k:
-            if self._drafter.kind == "model":
-                d += [int(t) for t in dr[req.slot, :k - len(d)]]
-            else:
-                ctx = req.prompt + req.output[:req.replay_pos] + d
-                d += self._drafter.draft(ctx, k - len(d))
-        return d
-
-    def _spec_decode(self, dec: list[tuple[int, Request]]) -> None:
-        """One verify pass over the decode pool: feed [last committed token,
-        k drafts] per slot, accept each slot's matching prefix, emit the
-        first non-matching (or bonus) token, and roll back the per-slot
-        cursor to the committed prefix (the SLC lengths rewind — rejected
-        rows die in place, no erase)."""
-        k = self.spec_k
-        toks = np.zeros((self.n_slots, k + 1), np.int32)
-        toks[:, 0] = self._last_tok
-        dr = None
-        if self._drafter.kind == "model":
-            # the draft inputs (hidden carry, last tokens, cursors) cross
-            # explicitly and metered like every other decode-lane transfer
-            rep = self._io and self._io["pos"]     # replicated on the mesh
-            dr = self._fetch(self._dev(
-                self._drafter.draft_batch, self.qparams,
-                self._push(self._h_last, rep, decode=True),
-                self._push(self._last_tok, rep, decode=True),
-                self._push(np.asarray(self._slot_pos, np.int32), rep,
-                           decode=True)), decode=True)
-        drafts: dict[int, list[int]] = {}
-        for slot, req in dec:
-            drafts[slot] = self._draft_for(req, dr)
-            toks[slot, 1:] = drafts[slot]
-        logits, hidden, self.state = self._dev(
-            self._verify, self.qparams, self.state,
-            self._push(toks, self._io and self._io["verify_tokens"],
-                       decode=True))
-        self.stats["verify_steps"] += 1
-        row_token = self._row_token_fn(logits, dec)
-        hid = (self._fetch(hidden, decode=True).astype(np.float32)
-               if self._drafter.kind == "model" else None)
-        with TraceAnnotation("engine.emit", slots=len(dec)):
-            now = self._now()
-            for slot, req in dec:
-                fed = drafts[slot]
-                committed = 0         # accepted K/V rows past toks[:, 0]
-                for i in range(k + 1):
-                    # row i of `rows` is the model's next-token
-                    # distribution after consuming toks[slot, :i+1] — valid
-                    # because reaching row i means every earlier draft was
-                    # accepted
-                    replaying = req.replay_pos < len(req.output)
-                    if replaying:
-                        # the draw still runs (discarded) so a resumed
-                        # sampled request re-consumes one draw per recorded
-                        # token and its stream stays aligned — same rule as
-                        # _next_tokens
-                        if req.temperature > 0:
-                            row_token(req, slot, i)
-                        tok = req.output[req.replay_pos]
-                        req.replay_pos += 1
-                    else:
-                        tok = row_token(req, slot, i)
-                        req.output.append(tok)
-                        req.replay_pos = len(req.output)
-                        self.policy.on_tokens(req, 1)
-                    self._last_tok[slot] = tok
-                    if hid is not None:
-                        self._h_last[slot] = hid[slot, i]
-                    accepted = i < k and tok == fed[i]
-                    if not replaying and i < k:
-                        self.stats["spec_drafted"] += 1
-                        self.stats["spec_accepted"] += int(accepted)
-                    if req.replay_pos >= len(req.output) and req.should_stop():
-                        committed += int(accepted)
-                        self._retire(req, now)
-                        break
-                    if not accepted:
-                        break
-                    committed += 1
-                self.stats["spec_accept_hist"][committed] += 1
-                self._slot_pos[slot] += 1 + committed
-        # rollback: rewind every cursor to its committed prefix; rejected
-        # suffix rows stay as dead in-place entries until overwritten
-        self.state = T.rewind_pos(self.state, self._pos_device())
-
-    # -- tree-draft speculative decode lane ---------------------------------
     def _tree_draft_for(self, req: Request, dr) -> tuple[list[int], list[int]]:
         """(tokens, draft-space parents) for one slot's tree window.
 
         A replaying (preempt-resumed) request drafts its recorded tokens as
-        a linear chain — perfect drafts, so replay advances ``spec_tree + 1``
+        a chain — perfect drafts, so replay advances ``spec_tree + 1``
         positions per window and stays token-identical; the tail past the
         recorded output comes from the drafter (the model drafter's
         chain-0 prefix, or a fresh host chain draft).  Fresh requests get
@@ -1720,8 +1597,8 @@ class ContinuousBatchingEngine:
         per-row depths and ancestor bitmasks, walk the verified tree
         host-side for the longest accepted root-path, then compact the
         accepted path's scattered K/V rows into contiguous committed rows
-        (``tree_commit``) — the rejected branches die in place, exactly
-        like the linear lane's rewound suffix."""
+        (``tree_commit``; a bare cursor rewind when every path is its
+        chain prefix) — the rejected rows die in place."""
         n = self.spec_tree
         Tw = n + 1
         toks = np.zeros((self.n_slots, Tw), np.int32)
@@ -1804,7 +1681,6 @@ class ContinuousBatchingEngine:
                         self.stats["spec_accepted"] += int(nxt is not None)
                     if req.replay_pos >= len(req.output) and req.should_stop():
                         # the stopping token was drafted: commit its row
-                        # like the linear lane's bonus accept
                         if nxt is not None:
                             path.append(nxt)
                         self._retire(req, now)
@@ -1818,6 +1694,13 @@ class ContinuousBatchingEngine:
                 keep[slot] = committed
                 self.stats["spec_accept_hist"][committed] += 1
                 self._slot_pos[slot] += 1 + committed
+        # every accepted path a chain prefix (always so at spec_branch=1):
+        # its rows already sit at base + 1 .., so the gather would be an
+        # identity and the commit is the bare cursor rewind
+        w = np.arange(n)
+        if np.all((sel == w + 1) | (w >= keep[:, None])):
+            self.state = T.rewind_pos(self.state, self._pos_device())
+            return
         # compact: gather each slot's accepted rows (base + sel) into
         # contiguous committed rows at base + 1 and land the new cursors;
         # inactive slots pass keep=0 and their unchanged cursor (no-op)

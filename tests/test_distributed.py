@@ -369,10 +369,11 @@ class TestShardedServe:
         assert out.count("SWAP_PARITY_OK") == 1
 
     def test_sharded_spec_decode_token_identical(self):
-        """Speculative decode over the mesh must match the single-device
+        """Speculative decode over a chain window (spec_tree=4,
+        spec_branch=1) on the mesh must match the single-device
         *non-speculative* engine token-for-token: the verify step's I/O is
-        pinned beside the pool (dist.sharding.verify_shardings) and the
-        cursor rollback is a replicated pos rewrite.  Covers the ngram
+        pinned beside the pool (dist.sharding.verify_shardings) and a
+        chain's commit is a replicated pos rewrite.  Covers the ngram
         drafter on a dense GQA arch (with fair-share preemption riding the
         spec lane) and the MTP drafter on DeepSeek (MLA + MoE + cfg.mtp)."""
         out = _run_with_devices(8, """
@@ -399,7 +400,8 @@ class TestShardedServe:
                              serve_resident_moe=True)
                 eng = ContinuousBatchingEngine(
                     cfg, params, n_slots=4, max_len=32, quantize=quantize,
-                    chunk=4, policy=policy, spec_k=4, drafter=drafter, rt=rt)
+                    chunk=4, policy=policy, spec_tree=4, spec_branch=1,
+                    drafter=drafter, rt=rt)
                 got = eng.generate_all(prompts, budgets)
                 assert got == ref, (arch, got, ref)
                 assert eng.stats["verify_steps"] > 0
@@ -412,7 +414,7 @@ class TestShardedServe:
         """The tree-draft spec lane over the 2x4 mesh must match the
         single-device non-speculative engine token-for-token: the [B, T]
         depth/anc window operands pin beside the draft tokens
-        (dist.sharding.tree_verify_shardings) and the accepted-path
+        (dist.sharding.verify_shardings) and the accepted-path
         compaction (tree_commit) takes the pool in and out at its own
         shardings with replicated scalar operands — the donation-alias
         condition.  Covers the branching ngram drafter on dense GQA (with

@@ -143,65 +143,8 @@ class TestDecodeAttn:
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-6)
 
 
-class TestVerifyAttn:
-    """T>1 per-slot verify kernel (speculative decode): T query tokens per
-    slot, query t masked to keys [0, pos+t]."""
-
-    @pytest.mark.parametrize("b,s,g,rep,d,t", [
-        (2, 256, 2, 2, 64, 4),
-        (1, 300, 4, 1, 64, 5),        # non-aligned seq
-        (3, 512, 1, 4, 128, 3),
-        (2, 128, 2, 2, 64, 1),        # T=1 degenerates to plain decode
-    ])
-    def test_matches_oracle(self, b, s, g, rep, d, t):
-        k1, k2, k3 = jax.random.split(jax.random.key(b * s + g + d + t), 3)
-        q = jax.random.normal(k1, (b, t, g * rep, d))
-        k = jax.random.normal(k2, (b, s, g, d))
-        v = jax.random.normal(k3, (b, s, g, d))
-        k_q, k_s = quant.quantize_kv(k)
-        v_q, v_s = quant.quantize_kv(v)
-        pos = jnp.asarray(np.arange(b) * 7 + s // 2, jnp.int32)
-        want = da_ref.verify_ref(q, k_q, k_s, v_q, v_s, pos)
-        got = da_ops.verify_attention(q, k_q, k_s, v_q, v_s, pos)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=3e-5, atol=3e-6)
-
-    def test_t1_equals_decode_attention(self):
-        """The verify kernel at T=1 is the decode kernel."""
-        b, s, g, d = 2, 128, 2, 64
-        q = jax.random.normal(jax.random.key(3), (b, 1, g, d))
-        k = jax.random.normal(jax.random.key(4), (b, s, g, d))
-        v = jax.random.normal(jax.random.key(5), (b, s, g, d))
-        k_q, k_s = quant.quantize_kv(k)
-        v_q, v_s = quant.quantize_kv(v)
-        ln = jnp.array([40, 90], jnp.int32)
-        a = da_ops.decode_attention(q, k_q, k_s, v_q, v_s, ln)
-        # decode masks keys < length; verify masks keys < pos + t + 1
-        b_ = da_ops.verify_attention(q, k_q, k_s, v_q, v_s, ln - 1)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-6)
-
-    def test_stepped_mask_excludes_future_rows(self):
-        """Poisoning rows past each query's own limit (pos + t) must not
-        change that query's output — the per-row stepped causal mask."""
-        b, s, g, d, t = 1, 128, 2, 64, 3
-        q = jax.random.normal(jax.random.key(6), (b, t, g, d))
-        k = jax.random.normal(jax.random.key(7), (b, s, g, d))
-        v = jax.random.normal(jax.random.key(8), (b, s, g, d))
-        k_q, k_s = quant.quantize_kv(k)
-        v_q, v_s = quant.quantize_kv(v)
-        pos = jnp.array([50], jnp.int32)
-        o1 = da_ops.verify_attention(q, k_q, k_s, v_q, v_s, pos)
-        for qt in range(t):
-            lim = 50 + qt + 1
-            k_q2 = k_q.at[:, lim:].set(127)
-            v_q2 = v_q.at[:, lim:].set(-127)
-            o2 = da_ops.verify_attention(q, k_q2, k_s, v_q2, v_s, pos)
-            np.testing.assert_allclose(np.asarray(o1[:, qt]),
-                                       np.asarray(o2[:, qt]), rtol=1e-6)
-
-
 def _chain_anc(b, t):
-    """Linear-chain ancestor masks: node i's ancestors are 0..i."""
+    """Chain ancestor masks: node i's ancestors are 0..i."""
     row = (1 << (np.arange(t, dtype=np.int64) + 1)) - 1
     return jnp.asarray(np.tile(row.astype(np.int32), (b, 1)))
 
@@ -215,16 +158,22 @@ def _tree_anc(parents):
 
 
 class TestVerifyTreeAttn:
-    """Tree-verify kernel: the stepped limit becomes a per-row ancestor
-    bitmask over the in-window nodes."""
+    """Verify kernel (speculative decode): T query tokens per slot, each a
+    node of a draft tree masked to the committed prefix plus its
+    ancestor-or-self in-window keys; a chain gives the stepped causal mask
+    (query t sees keys [0, pos+t])."""
 
-    @pytest.mark.parametrize("b,s,g,rep,d,t", [
-        (2, 256, 2, 2, 64, 4),
-        (1, 300, 4, 1, 64, 7),        # non-aligned seq
-        (3, 512, 1, 4, 128, 5),
-        (2, 128, 2, 2, 64, 1),        # root-only window
+    @pytest.mark.parametrize("b,s,g,rep,d,t,topology", [
+        (2, 256, 2, 2, 64, 4, "tree"),
+        (1, 300, 4, 1, 64, 7, "tree"),      # non-aligned seq
+        (3, 512, 1, 4, 128, 5, "tree"),
+        (2, 128, 2, 2, 64, 1, "tree"),      # root-only window
+        (2, 256, 2, 2, 64, 4, "chain"),
+        (1, 300, 4, 1, 64, 5, "chain"),     # non-aligned seq
+        (3, 512, 1, 4, 128, 3, "chain"),
+        (2, 128, 2, 2, 64, 1, "chain"),     # T=1 degenerates to plain decode
     ])
-    def test_matches_oracle(self, b, s, g, rep, d, t):
+    def test_matches_oracle(self, b, s, g, rep, d, t, topology):
         k1, k2, k3 = jax.random.split(jax.random.key(b * s + g + d + t), 3)
         q = jax.random.normal(k1, (b, t, g * rep, d))
         k = jax.random.normal(k2, (b, s, g, d))
@@ -232,21 +181,62 @@ class TestVerifyTreeAttn:
         k_q, k_s = quant.quantize_kv(k)
         v_q, v_s = quant.quantize_kv(v)
         pos = jnp.asarray(np.arange(b) * 7 + s // 2, jnp.int32)
-        # per-slot random trees (parents[i] < i), seeded by the shape
-        rng = np.random.RandomState(b * s + t)
-        anc = np.zeros((b, t), np.int64)
-        for bb in range(b):
-            par = [-1] + [rng.randint(0, i) for i in range(1, t)]
-            anc[bb] = np.asarray(_tree_anc(par))[0]
-        anc = jnp.asarray(anc.astype(np.int32))
+        if topology == "chain":
+            anc = _chain_anc(b, t)
+        else:
+            # per-slot random trees (parents[i] < i), seeded by the shape
+            rng = np.random.RandomState(b * s + t)
+            anc = np.zeros((b, t), np.int64)
+            for bb in range(b):
+                par = [-1] + [rng.randint(0, i) for i in range(1, t)]
+                anc[bb] = np.asarray(_tree_anc(par))[0]
+            anc = jnp.asarray(anc.astype(np.int32))
         want = da_ref.verify_tree_ref(q, k_q, k_s, v_q, v_s, pos, anc)
         got = da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s, pos, anc)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=3e-5, atol=3e-6)
 
-    def test_chain_tree_equals_linear_verify(self):
-        """A linear-chain ancestor mask reproduces the stepped verify
-        kernel bit-for-bit (masked scores are identical)."""
+    def test_t1_equals_decode_attention(self):
+        """The verify kernel at T=1 (a root-only chain) is the decode
+        kernel."""
+        b, s, g, d = 2, 128, 2, 64
+        q = jax.random.normal(jax.random.key(3), (b, 1, g, d))
+        k = jax.random.normal(jax.random.key(4), (b, s, g, d))
+        v = jax.random.normal(jax.random.key(5), (b, s, g, d))
+        k_q, k_s = quant.quantize_kv(k)
+        v_q, v_s = quant.quantize_kv(v)
+        ln = jnp.array([40, 90], jnp.int32)
+        a = da_ops.decode_attention(q, k_q, k_s, v_q, v_s, ln)
+        # decode masks keys < length; the root sees keys < pos + 1
+        b_ = da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s, ln - 1,
+                                          _chain_anc(b, 1))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-6)
+
+    def test_stepped_mask_excludes_future_rows(self):
+        """Poisoning rows past each query's own limit (pos + t) must not
+        change that query's output — a chain's per-row stepped causal
+        mask."""
+        b, s, g, d, t = 1, 128, 2, 64, 3
+        q = jax.random.normal(jax.random.key(6), (b, t, g, d))
+        k = jax.random.normal(jax.random.key(7), (b, s, g, d))
+        v = jax.random.normal(jax.random.key(8), (b, s, g, d))
+        k_q, k_s = quant.quantize_kv(k)
+        v_q, v_s = quant.quantize_kv(v)
+        pos = jnp.array([50], jnp.int32)
+        anc = _chain_anc(b, t)
+        o1 = da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s, pos, anc)
+        for qt in range(t):
+            lim = 50 + qt + 1
+            k_q2 = k_q.at[:, lim:].set(127)
+            v_q2 = v_q.at[:, lim:].set(-127)
+            o2 = da_ops.verify_attention_tree(q, k_q2, k_s, v_q2, v_s, pos,
+                                              anc)
+            np.testing.assert_allclose(np.asarray(o1[:, qt]),
+                                       np.asarray(o2[:, qt]), rtol=1e-6)
+
+    def test_chain_window_equals_sequential_decode(self):
+        """A chain window of T nodes at ``pos`` equals T sequential
+        ``decode_attention`` calls, call t at cache length pos + t + 1."""
         b, s, g, d, t = 2, 256, 2, 64, 4
         q = jax.random.normal(jax.random.key(12), (b, t, g, d))
         k = jax.random.normal(jax.random.key(13), (b, s, g, d))
@@ -254,10 +244,13 @@ class TestVerifyTreeAttn:
         k_q, k_s = quant.quantize_kv(k)
         v_q, v_s = quant.quantize_kv(v)
         pos = jnp.array([30, 100], jnp.int32)
-        a = da_ops.verify_attention(q, k_q, k_s, v_q, v_s, pos)
-        b_ = da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s, pos,
-                                          _chain_anc(b, t))
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+        win = da_ops.verify_attention_tree(q, k_q, k_s, v_q, v_s, pos,
+                                           _chain_anc(b, t))
+        for qt in range(t):
+            seq = da_ops.decode_attention(q[:, qt:qt + 1], k_q, k_s, v_q,
+                                          v_s, pos + qt + 1)
+            np.testing.assert_allclose(np.asarray(win[:, qt:qt + 1]),
+                                       np.asarray(seq), rtol=1e-6)
 
     def test_non_ancestor_rows_masked(self):
         """Poisoning the K/V rows of non-ancestor nodes (siblings and the

@@ -289,7 +289,7 @@ class TestLaneDenseAttention:
     """Decode and verify attention read the lane-dense pool as it lies and
     match the per-head dMVM oracle (``kernels/decode_attn/ref.py``)."""
 
-    @pytest.mark.parametrize("backend", ["dense", "pallas"])
+    @pytest.mark.parametrize("backend", ["dense", "fused_int8"])
     @pytest.mark.parametrize("b", [1, 4])
     @LANE_DENSE
     def test_decode_matches_per_head_oracle(self, rep, d, b, backend):
@@ -303,21 +303,18 @@ class TestLaneDenseAttention:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("tree", [False, True], ids=["linear", "tree"])
+    @pytest.mark.parametrize("anc", [[1, 3, 7], [1, 3, 5]],
+                             ids=["chain", "tree"])
     @LANE_DENSE
-    def test_verify_matches_per_head_oracle(self, rep, d, tree):
+    def test_verify_matches_per_head_oracle(self, rep, d, anc):
         from repro.kernels.decode_attn import ref as da_ref
         from repro.models import attention as A
         t = 3
         q, per_head, flat = _lane_dense_case(4, rep, d, seed=rep, t=t)
         pos = jnp.array([S - t, 0, 6, 9], jnp.int32)
-        if tree:
-            anc = jnp.broadcast_to(jnp.array([1, 3, 5], jnp.int32), (4, t))
-            want = da_ref.verify_tree_ref(q, *per_head, pos, anc)
-        else:
-            anc = None
-            want = da_ref.verify_ref(q, *per_head, pos)
-        got = A.verify_attention_int8(q, *flat, pos, anc=anc)
+        anc = jnp.broadcast_to(jnp.array(anc, jnp.int32), (4, t))
+        want = da_ref.verify_tree_ref(q, *per_head, pos, anc)
+        got = A.verify_attention_int8(q, *flat, pos, anc)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 

@@ -157,16 +157,17 @@ class TestEngineMultiStepParity:
             assert eng.stats["multi_blocks"] > 0, m
 
     def test_spec_lane_takes_precedence(self, gqa_setup):
-        """spec_k > 0 and multi_step > 1 together: the spec lane runs (it
-        already amortizes the weight read over k+1 tokens) and output stays
-        token-identical."""
+        """spec_tree > 0 (a chain, spec_branch=1) and multi_step > 1
+        together: the spec lane runs (it already amortizes the weight read
+        over k+1 tokens) and output stays token-identical."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         prompts, budgets = _trace(cfg)
         ref = ContinuousBatchingEngine(
             cfg, params, n_slots=2, max_len=32).generate_all(prompts, budgets)
         eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=32,
-                                       spec_k=4, multi_step=4)
+                                       spec_tree=4, spec_branch=1,
+                                       multi_step=4)
         assert eng.generate_all(prompts, budgets) == ref
         assert eng.stats["verify_steps"] > 0
         assert eng.stats["multi_blocks"] == 0
@@ -358,10 +359,10 @@ class TestTransferDiscipline:
         cfg, params = gqa_setup
         prompts, _ = _trace(cfg, n=4)
 
-        def run(spec_k, pre):
+        def run(spec_tree, pre):
             eng = ContinuousBatchingEngine(
-                cfg, params, n_slots=2, max_len=32, spec_k=spec_k,
-                topk_preselect=pre)
+                cfg, params, n_slots=2, max_len=32, spec_tree=spec_tree,
+                spec_branch=1, topk_preselect=pre)
             reqs = [eng.submit(p, 6, temperature=0.8, top_k=16,
                                seed=100 + i)
                     for i, p in enumerate(prompts)]

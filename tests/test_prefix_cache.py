@@ -283,8 +283,9 @@ class TestWarmColdParity:
     def test_spec_decode_lane(self, llama):
         cfg, params = llama
         prompts = _shared_prompts(cfg)
-        ref = _engine(cfg, params, spec_k=4).generate_all(prompts, [6] * 4)
-        warm = _engine(cfg, params, spec_k=4, prefix_cache=True)
+        kw = dict(spec_tree=4, spec_branch=1)
+        ref = _engine(cfg, params, **kw).generate_all(prompts, [6] * 4)
+        warm = _engine(cfg, params, prefix_cache=True, **kw)
         assert warm.generate_all(prompts, [6] * 4) == ref
         assert warm.stats["prefix_hits"] > 0
         _assert_slots_consistent(warm)
@@ -365,7 +366,7 @@ class TestEngineGating:
     def test_mla_and_ssm_silently_disable(self, arch):
         """Latent (MLA) pools can't seed a per-head carry without weights
         and SSM state can't restart mid-prompt — the flag degrades to a
-        cold engine, mirroring the chunk/spec_k fallbacks."""
+        cold engine, mirroring the chunk/spec_tree fallbacks."""
         from repro.models import model as M
         cfg = ARCHS[arch].reduced()
         params = M.init_params(jax.random.key(0), cfg)

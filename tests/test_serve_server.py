@@ -75,7 +75,7 @@ class TestStreamParity:
         interleaving or verify/rollback."""
         cfg, params = setup
         prompts, budgets = _trace(cfg, seed=5)
-        kw = dict(chunk=3, spec_k=4, policy="sjf")
+        kw = dict(chunk=3, spec_tree=4, spec_branch=1, policy="sjf")
         ref = _engine(cfg, params, **kw).generate_all(prompts, budgets)
         eng = _engine(cfg, params, **kw)
         got = _stream_all(eng, prompts, budgets, stream_buffer=2)
@@ -134,9 +134,9 @@ class TestDisconnect:
     def test_cancel_between_spec_windows(self, setup):
         cfg, params = setup
         pA, pB = [2, 4, 6, 8, 10, 12], [11, 3, 5, 9]
-        ref = _engine(cfg, params, n_slots=1,
-                      spec_k=4).generate_all([pB], [5])[0]
-        eng = _engine(cfg, params, n_slots=1, spec_k=4)
+        ref = _engine(cfg, params, n_slots=1, spec_tree=4,
+                      spec_branch=1).generate_all([pB], [5])[0]
+        eng = _engine(cfg, params, n_slots=1, spec_tree=4, spec_branch=1)
         rA = eng.submit(pA, 12)
         rB = eng.submit(pB, 5)
         while len(rA.output) < 2:                # at least one verify window

@@ -14,6 +14,7 @@ import pytest
 from jax.profiler import TraceAnnotation
 
 from repro.configs.registry import ARCHS
+from repro.serve.drafter import Drafter, chain_parents
 from repro.serve.engine import ContinuousBatchingEngine, Engine
 
 jax.config.update("jax_platform_name", "cpu")
@@ -24,7 +25,7 @@ if str(ROOT) not in sys.path:          # the benchmark's trace reader
 # the device trace's module names, one per serve-path program
 PROGRAMS = {"prefill", "prefill_chunk", "init_prefill_carry",
             "finalize_write", "copy_slot_prefix", "warm_prefill_carry",
-            "decode_step", "multi_decode_step", "verify_step", "verify_tree",
+            "decode_step", "multi_decode_step", "verify_tree",
             "tree_commit", "write_slot", "read_slot", "topk"}
 SPANS = {"engine.step", "engine.schedule", "engine.prefill", "engine.push",
          "engine.dispatch", "engine.fetch", "engine.emit", "server.admit",
@@ -49,6 +50,51 @@ def _prompts(cfg, n=5, seed=0):
             for _ in range(n)]
 
 
+def _serve_prompts(eng, prompts):
+    """The first two requests run alone (a prefix cache then holds the
+    first one's rows in one slot, and the second lands in the other), the
+    rest together, the last one sampled with a bounded top-k (the device
+    pre-select)."""
+    reqs = []
+    for p in prompts[:2]:
+        reqs.append(eng.submit(p, 6))
+        eng.drain()
+    reqs += [eng.submit(p, 6) for p in prompts[2:-1]]
+    reqs.append(eng.submit(prompts[-1], 6, temperature=0.8, top_k=5,
+                           seed=1))
+    eng.drain()
+    return reqs
+
+
+class _SiblingDrafter(Drafter):
+    """Drafts the reference continuation as the root's *second* child,
+    behind a first child that is never right: every accepted path skips a
+    sibling, so each window that accepts a draft commits through
+    ``tree_commit`` (a chain-prefix path would only rewind the cursor)."""
+    name = "sibling"
+
+    def __init__(self, table, vocab):
+        self.table, self.vocab = table, vocab
+
+    def _cont(self, context, k):
+        prompt, out = max(((p, o) for p, o in self.table
+                           if context[:len(p)] == p),
+                          key=lambda po: len(po[0]), default=((), []))
+        done = len(context) - len(prompt)
+        return out[done:done + k] if prompt else []
+
+    def draft(self, context, k):
+        return (self._cont(context, k) + [context[-1]] * k)[:k]
+
+    def draft_tree(self, context, n, branch):
+        cont = self._cont(context, n - 1)
+        if n < 2 or not cont:
+            return [context[-1]] * n, chain_parents(n)
+        cont = (cont + [cont[-1]] * n)[:n - 1]
+        junk = (cont[0] + 1) % self.vocab
+        return [junk] + cont, [-1] + chain_parents(n - 1)
+
+
 def _one_device_mesh_rt():
     from repro.launch.mesh import make_mesh
     from repro.models.transformer import Runtime
@@ -64,16 +110,20 @@ LANES = {
                "decode_step", "topk"}),
     "prefix_cache": ({"chunk": 4, "prefix_cache": True},
                      {"copy_slot_prefix", "warm_prefill_carry"}),
-    "spec": ({"spec_k": 2}, {"verify_step", "topk"}),
-    "tree": ({"spec_tree": 3}, {"verify_tree", "tree_commit", "topk"}),
+    "spec": ({"spec_tree": 2, "spec_branch": 1}, {"verify_tree", "topk"}),
+    "tree": ({"spec_tree": 3, "drafter": "sibling"},
+             {"verify_tree", "tree_commit", "topk"}),
     "multi_step": ({"multi_step": 2}, {"multi_decode_step"}),
     "kv_swap": ({"chunk": 4, "kv_swap": True, "policy": "fair:3"},
                 {"read_slot", "write_slot"}),
     "mesh": ({"chunk": 4, "prefix_cache": True, "spec_tree": 3,
-              "kv_swap": True, "policy": "fair:3"},
+              "drafter": "sibling", "kv_swap": True, "policy": "fair:3"},
              {"prefill_chunk", "finalize_write", "verify_tree",
               "tree_commit", "warm_prefill_carry"}),
 }
+# programs a case's run must not dispatch: a chain window's accepted path
+# is its chain prefix, so it commits by cursor rewind
+NEVER = {"spec": {"tree_commit"}}
 
 
 @pytest.mark.parametrize("lane", list(LANES))
@@ -82,8 +132,14 @@ def test_every_serve_jit_lowers_to_its_name(llama, lane):
     the run dispatches lowers to ``module @jit_<name>``."""
     cfg, params = llama
     opts, must_run = LANES[lane]
+    prompts = _prompts(cfg)
     if lane == "mesh":
         opts = dict(opts, rt=_one_device_mesh_rt())
+    if opts.get("drafter") == "sibling":
+        ref = _serve_prompts(ContinuousBatchingEngine(
+            cfg, params, n_slots=2, max_len=48), prompts)
+        opts = dict(opts, drafter=_SiblingDrafter(
+            [(p, r.output) for p, r in zip(prompts, ref)], cfg.vocab_size))
     eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=48,
                                    **opts)
     lowered = {}
@@ -96,18 +152,7 @@ def test_every_serve_jit_lowers_to_its_name(llama, lane):
         return dispatch(fn, *args)
 
     eng._dev = lowering_dev
-    prompts = _prompts(cfg)
-    # the first two requests run alone: a prefix cache then holds the
-    # first one's rows in one slot, and the second lands in the other
-    reqs = []
-    for p in prompts[:2]:
-        reqs.append(eng.submit(p, 6))
-        eng.drain()
-    reqs += [eng.submit(p, 6) for p in prompts[2:-1]]
-    # one sampled request with a bounded top-k: the device pre-select
-    reqs.append(eng.submit(prompts[-1], 6, temperature=0.8, top_k=5,
-                           seed=1))
-    eng.drain()
+    reqs = _serve_prompts(eng, prompts)
     assert all(r.error is None for r in reqs)
 
     built = [v for v in vars(eng).values() if hasattr(v, "lower")]
@@ -117,6 +162,7 @@ def test_every_serve_jit_lowers_to_its_name(llama, lane):
     assert names == {f"jit_{f.__name__}" for f in built
                      if id(f) in lowered}
     assert {f"jit_{n}" for n in must_run} <= names, names
+    assert not {f"jit_{n}" for n in NEVER.get(lane, ())} & names, names
     assert not {"jit__lambda", "jit__unknown"} & names
 
 
@@ -161,7 +207,7 @@ def test_server_run_writes_the_program_spans(llama, tmp_path):
 
 
 @pytest.mark.parametrize("opts", [{}, {"chunk": 4}, {"multi_step": 2},
-                                  {"spec_k": 2}],
+                                  {"spec_tree": 2, "spec_branch": 1}],
                          ids=["atomic", "chunk", "multi_step", "spec"])
 def test_host_counters_add_up(llama, opts):
     cfg, params = llama

@@ -6,22 +6,22 @@ Covers:
 
 * the n-gram (prompt-lookup) drafter proposes continuations of repeated
   context and falls back to repeat-last;
-* ``verify_step`` logits are bit-identical to sequential ``decode_step``
-  calls (the acceptance test's foundation), and a rewound verify state
-  decodes on identically (rollback exactness);
-* spec decode outputs equal the non-speculative engine for every policy,
-  chunked and unchunked, at several draft lengths — with a worst-case
+* ``verify_step`` over a chain window is bit-identical to sequential
+  ``decode_step`` calls (the acceptance test's foundation), and a rewound
+  verify state decodes on identically (rollback exactness); over a tree it
+  matches sequential decode along every root-path;
+* draft-tree topology helpers and ``tree_commit``/``path_gather``
+  compaction are exact;
+* spec decode (``spec_tree``; a chain is ``spec_branch=1``) outputs equal
+  the non-speculative engine for every policy, chunked and unchunked, at
+  several window sizes and branch factors — with a worst-case
   (never-right) and an oracle (always-right) drafter bounding both ends;
+  a chain window commits by cursor rewind, never by ``tree_commit``;
 * preempt-resume replay rides the spec lane (recorded tokens as perfect
-  drafts) and reproduces the uncontended run;
+  chain drafts) and reproduces the uncontended run;
 * sampled requests stay stream-exact: one RNG draw per emitted token, so
   seeded sampling with and without speculation emits the same tokens;
-* the MTP drafter (DeepSeek head) drafts batched and stays lossless;
-* the tree lane (``spec_tree``): draft-tree topology helpers, the
-  ancestor-masked ``verify_step`` is bit-identical to sequential decode
-  along every root-path, ``tree_commit``/``path_gather`` compaction is
-  exact, and the engine-level tree lane reproduces the plain engine
-  across policies, branches, preemption, sampling and the MTP beam.
+* the MTP drafter (DeepSeek head) beams batched drafts and stays lossless.
 """
 import jax
 import jax.numpy as jnp
@@ -96,14 +96,15 @@ class TestNGramDrafter:
 
     def test_make_drafter_parsing(self):
         cfg = ARCHS["llama3-8b"].reduced()
-        assert isinstance(make_drafter("ngram", cfg, None, 4), NGramDrafter)
-        assert make_drafter("ngram:5", cfg, None, 4).max_n == 5
+        assert isinstance(make_drafter("ngram", cfg, None, 4, 2),
+                          NGramDrafter)
+        assert make_drafter("ngram:5", cfg, None, 4, 2).max_n == 5
         inst = NGramDrafter()
-        assert make_drafter(inst, cfg, None, 4) is inst
+        assert make_drafter(inst, cfg, None, 4, 2) is inst
         with pytest.raises(ValueError):
-            make_drafter("oracle", cfg, None, 4)
+            make_drafter("oracle", cfg, None, 4, 2)
         with pytest.raises(ValueError):
-            make_drafter("mtp", cfg, None, 4)    # llama has no MTP head
+            make_drafter("mtp", cfg, None, 4, 2)  # llama has no MTP head
 
 
 # ---------------------------------------------------------------------------
@@ -134,10 +135,11 @@ def _eos_at(full, start=2):
 class TestVerifyStep:
     @pytest.mark.parametrize("arch", ["llama3-8b", "deepseek-v3-671b"])
     def test_verify_logits_match_sequential_decode(self, arch):
-        """Row i of the verify logits must equal the i-th sequential decode
-        step's logits bit-for-bit (GQA int8 path and absorbed MLA), and the
-        rewound verify state must decode on identically to the sequential
-        state — the rollback-exactness property."""
+        """Over a chain window (``depth``/``anc`` of a chain), row i of the
+        verify logits must equal the i-th sequential decode step's logits
+        bit-for-bit (GQA int8 path and absorbed MLA), and the rewound
+        verify state must decode on identically to the sequential state —
+        the rollback-exactness property."""
         from repro.models import model as M
         from repro.models import transformer as T
         from repro.models.transformer import Runtime
@@ -161,7 +163,12 @@ class TestVerifyStep:
         greedy = [np.argmax(l, -1) for l in seq_logits]
         fed = jnp.asarray(np.stack(
             [[3, 5, 7]] + greedy[:steps - 1], axis=1), jnp.int32)
-        vlog, hidden, vstate = M.verify_step(params, cfg, state, fed, rt)
+        depth_l, anc_l = tree_depths_ancestors(chain_parents(steps - 1))
+        assert depth_l == [0, 1, 2, 3] and anc_l == [1, 3, 7, 15]
+        depth = jnp.tile(jnp.asarray(depth_l, jnp.int32)[None], (B, 1))
+        anc = jnp.tile(jnp.asarray(anc_l, jnp.int32)[None], (B, 1))
+        vlog, hidden, vstate = M.verify_step(params, cfg, state, fed, rt,
+                                             depth, anc)
         vlog = np.asarray(vlog)
         for i in range(steps):
             np.testing.assert_array_equal(vlog[:, i], seq_logits[i])
@@ -180,9 +187,10 @@ class TestVerifyStep:
         cfg = ARCHS["mamba2-2.7b"].reduced()
         params = M.init_params(jax.random.key(0), cfg)
         state = M.init_decode_state(cfg, 2, 16)
+        depth = jnp.tile(jnp.arange(3, dtype=jnp.int32)[None], (2, 1))
         with pytest.raises(NotImplementedError):
-            M.verify_step(params, cfg, state,
-                          jnp.zeros((2, 3), jnp.int32), Runtime())
+            M.verify_step(params, cfg, state, jnp.zeros((2, 3), jnp.int32),
+                          Runtime(), depth, 2 * (1 << depth) - 1)
 
     @pytest.mark.parametrize("arch", ["llama3-8b", "deepseek-v3-671b"])
     def test_tree_verify_matches_sequential_and_commits_exactly(self, arch):
@@ -312,10 +320,10 @@ class TestTreeTopology:
     def test_pool_headroom_rule(self):
         from repro.core import kvcache as KV
         assert KV.pool_headroom() == 0
-        assert KV.pool_headroom(spec_k=4) == 4
         assert KV.pool_headroom(spec_tree=6) == 6
         assert KV.pool_headroom(multi_step=4) == 3
-        assert KV.pool_headroom(spec_k=2, spec_tree=5, multi_step=4) == 5
+        assert KV.pool_headroom(spec_tree=5, multi_step=4) == 5
+        assert KV.pool_headroom(spec_tree=2, multi_step=4) == 3
         with pytest.raises(ValueError):
             KV.pool_headroom(multi_step=0)
 
@@ -353,11 +361,22 @@ class _OracleDrafter(Drafter):
         return [context[-1]] * k
 
 
-class TestSpecParity:
-    def test_all_policies_chunked_and_not(self, gqa_setup):
-        """Acceptance: greedy spec decode is token-identical to the
-        non-speculative engine for all four policies, chunked and
-        unchunked, at spec_k in {2, 4, 8}."""
+def _no_tree_commit(*_):
+    raise AssertionError("a chain window dispatched tree_commit")
+
+
+BRANCHES = pytest.mark.parametrize("branch", [1, 2], ids=["chain", "tree"])
+
+
+class TestTreeSpecParity:
+    @BRANCHES
+    def test_all_policies_chunked_and_not(self, gqa_setup, branch):
+        """Acceptance: greedy spec decode, chain (``spec_branch=1``) and
+        tree, is token-identical to the non-speculative engine for all
+        four policies, chunked and unchunked, at several window sizes; the
+        accept histogram covers every verify pass.  A chain's accepted
+        path is always its chain prefix, so it commits by cursor rewind
+        and never dispatches ``tree_commit``."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         prompts, budgets = _trace(cfg)
@@ -367,171 +386,22 @@ class TestSpecParity:
             for chunk in (None, 4):
                 eng = ContinuousBatchingEngine(
                     cfg, params, n_slots=2, max_len=32, policy=policy,
-                    chunk=chunk, spec_k=4)
+                    chunk=chunk, spec_tree=4, spec_branch=branch)
+                if branch == 1:
+                    eng._tree_commit = _no_tree_commit
                 assert eng.generate_all(prompts, budgets) == ref, \
                     (policy, chunk)
                 assert eng.stats["verify_steps"] > 0
                 assert eng.stats["spec_drafted"] > 0
-        for k in (2, 8):
-            eng = ContinuousBatchingEngine(
-                cfg, params, n_slots=2, max_len=32, spec_k=k)
-            assert eng.generate_all(prompts, budgets) == ref, k
-
-    def test_worst_and_best_case_drafters(self, gqa_setup):
-        """A never-right drafter only costs verify passes; an oracle drafter
-        accepts (nearly) everything and cuts verify steps by ~(k+1)x.  Both
-        stay token-identical — draft quality is a pure performance knob."""
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, budgets = _trace(cfg)
-        ref_eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=32)
-        ref = ref_eng.generate_all(prompts, budgets)
-        base_steps = ref_eng.stats["decode_steps"]
-
-        worst = ContinuousBatchingEngine(
-            cfg, params, n_slots=2, max_len=32, spec_k=4,
-            drafter=_ConstantDrafter(tok=cfg.vocab_size - 1))
-        assert worst.generate_all(prompts, budgets) == ref
-
-        oracle = ContinuousBatchingEngine(
-            cfg, params, n_slots=2, max_len=32, spec_k=4,
-            drafter=_OracleDrafter(list(zip(prompts, ref))))
-        assert oracle.generate_all(prompts, budgets) == ref
-        assert oracle.acceptance_rate > 0.9
-        assert oracle.stats["verify_steps"] < base_steps / 2
-
-    def test_eos_inside_verify_window(self, gqa_setup):
-        """An accepted draft that equals eos must stop the request exactly
-        where the non-speculative engine would — no tokens past eos leak
-        from the window, and the slot backfills."""
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, _ = _trace(cfg)
-        full = ContinuousBatchingEngine(
-            cfg, params, n_slots=1, max_len=32).generate_all([prompts[0]], [8])[0]
-        eng = ContinuousBatchingEngine(
-            cfg, params, n_slots=1, max_len=32, spec_k=4,
-            drafter=_OracleDrafter([(prompts[0], full)]))
-        i = _eos_at(full)
-        r_eos = eng.submit(prompts[0], 8, eos_id=full[i])
-        r_next = eng.submit(list(reversed(prompts[0])), 3)
-        eng.drain()
-        assert r_eos.output == full[:i + 1]
-        assert len(r_next.output) == 3
-
-    def test_spec_k_ignored_for_ssm(self):
-        from repro.models import model as M
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg = ARCHS["mamba2-2.7b"].reduced()
-        params = M.init_params(jax.random.key(0), cfg)
-        eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=32,
-                                       spec_k=4)
-        assert eng.spec_k == 0               # recurrent state cannot rewind
-        prompts, budgets = _trace(cfg, n=3)
-        ref = ContinuousBatchingEngine(
-            cfg, params, n_slots=2, max_len=32).generate_all(prompts, budgets)
-        assert eng.generate_all(prompts, budgets) == ref
-
-
-class TestSpecPreemptionAndSampling:
-    def test_preempted_request_reproduces_unpreempted_output(self, gqa_setup):
-        """Preempt-resume under the spec lane: replayed tokens ride the
-        verify window as perfect drafts; the resumed output equals the
-        uncontended run token-for-token."""
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, _ = _trace(cfg)
-        solo = ContinuousBatchingEngine(
-            cfg, params, n_slots=1, max_len=48).generate_all([prompts[0]], [14])[0]
-        eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48,
-                                       policy="fair:3", chunk=4, spec_k=4)
-        r1 = eng.submit(prompts[0], 14, user="A")
-        r2 = eng.submit(prompts[1], 6, user="B")
-        eng.drain()
-        assert r1.n_preemptions >= 1
-        assert r1.output == solo
-        assert len(r2.output) == 6
-
-    def test_preemptive_priority_unchunked(self, gqa_setup):
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, _ = _trace(cfg)
-        solo = ContinuousBatchingEngine(
-            cfg, params, n_slots=1, max_len=48).generate_all([prompts[2]], [10])[0]
-        eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48,
-                                       policy="priority:preempt", spec_k=2)
-        lo = eng.submit(prompts[2], 10, priority=0)
-        for _ in range(3):
-            eng.step()
-        hi = eng.submit(prompts[3], 3, priority=9)
-        eng.drain()
-        assert lo.n_preemptions >= 1
-        assert lo.output == solo
-        assert len(hi.output) == 3
-
-    def test_sampled_request_preempted_under_spec_reproduces_solo(
-            self, gqa_setup):
-        """Regression: spec-lane replay rows must still consume one RNG
-        draw per recorded token (like the non-spec replay path), or a
-        sampled request that is preempted and resumed under spec_k>0
-        diverges from its uncontended run."""
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, _ = _trace(cfg)
-        solo_eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48)
-        solo = solo_eng.submit(prompts[0], 14, temperature=0.8, top_k=16,
-                               seed=7)
-        solo_eng.drain()
-        eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48,
-                                       policy="fair:3", chunk=4, spec_k=4)
-        r1 = eng.submit(prompts[0], 14, temperature=0.8, top_k=16, seed=7,
-                        user="A")
-        r2 = eng.submit(prompts[1], 6, user="B")
-        eng.drain()
-        assert r1.n_preemptions >= 1
-        assert r1.output == solo.output
-
-    def test_sampling_is_stream_exact_under_speculation(self, gqa_setup):
-        """One RNG draw per emitted token and acceptance = 'draft equals
-        the sampled token', so seeded sampling emits identical streams with
-        and without the spec lane."""
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, _ = _trace(cfg, n=4)
-
-        def run(k):
-            eng = ContinuousBatchingEngine(cfg, params, n_slots=2,
-                                           max_len=32, spec_k=k)
-            reqs = [eng.submit(p, 6, temperature=0.8, top_k=16, seed=100 + i)
-                    for i, p in enumerate(prompts)]
-            eng.drain()
-            return [r.output for r in reqs]
-
-        assert run(0) == run(4)
-
-
-class TestTreeSpecParity:
-    def test_all_policies_chunked_and_not(self, gqa_setup):
-        """Acceptance: greedy tree-spec decode is token-identical to the
-        non-speculative engine for all four policies, chunked and
-        unchunked; the accept histogram covers every verify pass."""
-        from repro.serve.engine import ContinuousBatchingEngine
-        cfg, params = gqa_setup
-        prompts, budgets = _trace(cfg)
-        ref = ContinuousBatchingEngine(
-            cfg, params, n_slots=2, max_len=32).generate_all(prompts, budgets)
-        for policy in ("fifo", "priority", "sjf", "fair"):
-            for chunk in (None, 4):
-                eng = ContinuousBatchingEngine(
-                    cfg, params, n_slots=2, max_len=32, policy=policy,
-                    chunk=chunk, spec_tree=4)
-                assert eng.generate_all(prompts, budgets) == ref, \
-                    (policy, chunk)
-                assert eng.stats["verify_steps"] > 0
                 hist = eng.stats["spec_accept_hist"]
                 assert len(hist) == 5
                 # one histogram entry per active slot per verify pass
                 assert sum(hist) >= eng.stats["verify_steps"]
+        for n in (2, 8):
+            eng = ContinuousBatchingEngine(
+                cfg, params, n_slots=2, max_len=32, spec_tree=n,
+                spec_branch=branch)
+            assert eng.generate_all(prompts, budgets) == ref, n
 
     def test_branch_sweep_and_window_sizes(self, gqa_setup):
         """spec_branch only redistributes the draft budget across chains —
@@ -547,24 +417,21 @@ class TestTreeSpecParity:
                 spec_branch=branch)
             assert eng.generate_all(prompts, budgets) == ref, (n, branch)
 
-    def test_tree_takes_precedence_over_linear_lane(self, gqa_setup):
-        """With both knobs set the tree lane runs: no linear verify fn is
-        built, the drafter budget is spec_tree, and parity still holds."""
+    def test_spec_k_option_is_gone(self, gqa_setup):
+        """A chain is ``spec_tree=k, spec_branch=1``: the old linear-lane
+        option is refused, not silently ignored."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
-        prompts, budgets = _trace(cfg)
-        ref = ContinuousBatchingEngine(
-            cfg, params, n_slots=2, max_len=32).generate_all(prompts, budgets)
-        eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=32,
-                                       spec_k=2, spec_tree=4)
-        assert getattr(eng, "_verify", None) is None
-        assert eng.generate_all(prompts, budgets) == ref
-        assert len(eng.stats["spec_accept_hist"]) == 5
+        with pytest.raises(TypeError):
+            ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=32,
+                                     spec_k=4)
 
-    def test_worst_and_best_case_drafters(self, gqa_setup):
-        """Draft quality stays a pure performance knob in the tree lane:
-        a never-right drafter and a (chain-fallback) oracle drafter both
-        reproduce the reference; the oracle collapses verify steps."""
+    @BRANCHES
+    def test_worst_and_best_case_drafters(self, gqa_setup, branch):
+        """Draft quality is a pure performance knob: a never-right drafter
+        only costs verify passes, and an oracle drafter (a chain from the
+        base ``draft_tree``) accepts (nearly) everything and cuts verify
+        steps by ~(k+1)x.  Both stay token-identical."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         prompts, budgets = _trace(cfg)
@@ -574,19 +441,23 @@ class TestTreeSpecParity:
 
         worst = ContinuousBatchingEngine(
             cfg, params, n_slots=2, max_len=32, spec_tree=4,
+            spec_branch=branch,
             drafter=_ConstantDrafter(tok=cfg.vocab_size - 1))
         assert worst.generate_all(prompts, budgets) == ref
 
         oracle = ContinuousBatchingEngine(
             cfg, params, n_slots=2, max_len=32, spec_tree=4,
+            spec_branch=branch,
             drafter=_OracleDrafter(list(zip(prompts, ref))))
         assert oracle.generate_all(prompts, budgets) == ref
         assert oracle.acceptance_rate > 0.9
         assert oracle.stats["verify_steps"] < base_steps / 2
 
-    def test_eos_inside_tree_window(self, gqa_setup):
-        """An accepted tree node that equals eos stops the request exactly
-        where the plain engine would — no committed tokens past eos."""
+    @BRANCHES
+    def test_eos_inside_tree_window(self, gqa_setup, branch):
+        """An accepted window node that equals eos stops the request
+        exactly where the plain engine would — no committed tokens past
+        eos leak from the window, and the slot backfills."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         prompts, _ = _trace(cfg)
@@ -594,6 +465,7 @@ class TestTreeSpecParity:
             cfg, params, n_slots=1, max_len=32).generate_all([prompts[0]], [8])[0]
         eng = ContinuousBatchingEngine(
             cfg, params, n_slots=1, max_len=32, spec_tree=4,
+            spec_branch=branch,
             drafter=_OracleDrafter([(prompts[0], full)]))
         i = _eos_at(full)
         r_eos = eng.submit(prompts[0], 8, eos_id=full[i])
@@ -602,13 +474,14 @@ class TestTreeSpecParity:
         assert r_eos.output == full[:i + 1]
         assert len(r_next.output) == 3
 
-    def test_spec_tree_ignored_for_ssm(self):
+    @BRANCHES
+    def test_spec_tree_ignored_for_ssm(self, branch):
         from repro.models import model as M
         from repro.serve.engine import ContinuousBatchingEngine
         cfg = ARCHS["mamba2-2.7b"].reduced()
         params = M.init_params(jax.random.key(0), cfg)
         eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=32,
-                                       spec_tree=4)
+                                       spec_tree=4, spec_branch=branch)
         assert eng.spec_tree == 0            # recurrent state cannot rewind
         prompts, budgets = _trace(cfg, n=3)
         ref = ContinuousBatchingEngine(
@@ -630,9 +503,11 @@ class TestTreeSpecParity:
 
 
 class TestTreeSpecPreemptionAndSampling:
-    def test_preempted_request_reproduces_unpreempted_output(self, gqa_setup):
-        """Preempt-resume under the tree lane: replay drafts the recorded
-        tokens as a linear chain; the resumed output equals the
+    @BRANCHES
+    def test_preempted_request_reproduces_unpreempted_output(self, gqa_setup,
+                                                             branch):
+        """Preempt-resume under the spec lane: replay drafts the recorded
+        tokens as a chain of perfect drafts; the resumed output equals the
         uncontended run token-for-token."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
@@ -640,7 +515,8 @@ class TestTreeSpecPreemptionAndSampling:
         solo = ContinuousBatchingEngine(
             cfg, params, n_slots=1, max_len=48).generate_all([prompts[0]], [14])[0]
         eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48,
-                                       policy="fair:3", chunk=4, spec_tree=4)
+                                       policy="fair:3", chunk=4, spec_tree=4,
+                                       spec_branch=branch)
         r1 = eng.submit(prompts[0], 14, user="A")
         r2 = eng.submit(prompts[1], 6, user="B")
         eng.drain()
@@ -648,10 +524,31 @@ class TestTreeSpecPreemptionAndSampling:
         assert r1.output == solo
         assert len(r2.output) == 6
 
+    def test_preemptive_priority_unchunked(self, gqa_setup):
+        from repro.serve.engine import ContinuousBatchingEngine
+        cfg, params = gqa_setup
+        prompts, _ = _trace(cfg)
+        solo = ContinuousBatchingEngine(
+            cfg, params, n_slots=1, max_len=48).generate_all([prompts[2]], [10])[0]
+        eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48,
+                                       policy="priority:preempt", spec_tree=2,
+                                       spec_branch=1)
+        lo = eng.submit(prompts[2], 10, priority=0)
+        for _ in range(3):
+            eng.step()
+        hi = eng.submit(prompts[3], 3, priority=9)
+        eng.drain()
+        assert lo.n_preemptions >= 1
+        assert lo.output == solo
+        assert len(hi.output) == 3
+
+    @BRANCHES
     def test_sampled_request_preempted_under_tree_reproduces_solo(
-            self, gqa_setup):
-        """Replay rows in the tree walk must still consume one RNG draw
-        per recorded token, or a preempted sampled request diverges."""
+            self, gqa_setup, branch):
+        """Regression: replay rows in the accept walk must still consume
+        one RNG draw per recorded token (like the non-spec replay path),
+        or a sampled request that is preempted and resumed under the spec
+        lane diverges from its uncontended run."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         prompts, _ = _trace(cfg)
@@ -660,7 +557,8 @@ class TestTreeSpecPreemptionAndSampling:
                                seed=7)
         solo_eng.drain()
         eng = ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=48,
-                                       policy="fair:3", chunk=4, spec_tree=4)
+                                       policy="fair:3", chunk=4, spec_tree=4,
+                                       spec_branch=branch)
         r1 = eng.submit(prompts[0], 14, temperature=0.8, top_k=16, seed=7,
                         user="A")
         r2 = eng.submit(prompts[1], 6, user="B")
@@ -668,17 +566,20 @@ class TestTreeSpecPreemptionAndSampling:
         assert r1.n_preemptions >= 1
         assert r1.output == solo.output
 
-    def test_sampling_is_stream_exact_under_tree_speculation(self, gqa_setup):
+    @BRANCHES
+    def test_sampling_is_stream_exact_under_tree_speculation(self, gqa_setup,
+                                                              branch):
         """One RNG draw per emitted token and acceptance = 'node token
         equals the sampled token', so seeded sampling emits identical
-        streams with and without the tree lane."""
+        streams with and without the spec lane."""
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         prompts, _ = _trace(cfg, n=4)
 
         def run(n):
             eng = ContinuousBatchingEngine(cfg, params, n_slots=2,
-                                           max_len=32, spec_tree=n)
+                                           max_len=32, spec_tree=n,
+                                           spec_branch=branch)
             reqs = [eng.submit(p, 6, temperature=0.8, top_k=16, seed=100 + i)
                     for i, p in enumerate(prompts)]
             eng.drain()
@@ -690,8 +591,9 @@ class TestTreeSpecPreemptionAndSampling:
 class TestMTPDrafter:
     def test_mtp_drafts_and_stays_lossless(self):
         """DeepSeek (MLA + MoE + cfg.mtp): the MTP head drafts a [B, k]
-        batch and greedy outputs stay identical to the plain engine (the
-        untrained head drafts near-randomly; verification absorbs it)."""
+        chain (``spec_branch=1``) and greedy outputs stay identical to the
+        plain engine (the untrained head drafts near-randomly;
+        verification absorbs it)."""
         from repro.models import model as M
         from repro.serve.engine import ContinuousBatchingEngine
         cfg = ARCHS["deepseek-v3-671b"].reduced()
@@ -705,7 +607,7 @@ class TestMTPDrafter:
             quantize=False).generate_all(prompts, budgets)
         eng = ContinuousBatchingEngine(
             cfg, params, n_slots=2, max_len=32, quantize=False,
-            spec_k=3, drafter="mtp", chunk=4)
+            spec_tree=3, spec_branch=1, drafter="mtp", chunk=4)
         assert eng.generate_all(prompts, budgets) == ref
         assert eng.stats["verify_steps"] > 0
 
@@ -717,8 +619,8 @@ class TestMTPDrafter:
         h = jnp.zeros((3, cfg.d_model))
         tok = jnp.array([1, 2, 3], jnp.int32)
         pos = jnp.array([4, 5, 6], jnp.int32)
-        a = M.mtp_draft(params, cfg, h, tok, pos, 4, Runtime())
-        b = M.mtp_draft(params, cfg, h, tok, pos, 4, Runtime())
+        a = M.mtp_draft_tree(params, cfg, h, tok, pos, 4, 1, Runtime())
+        b = M.mtp_draft_tree(params, cfg, h, tok, pos, 4, 1, Runtime())
         assert a.shape == (3, 4)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         assert (np.asarray(a) >= 0).all() and \
@@ -748,9 +650,11 @@ class TestMTPDrafter:
 
     def test_mtp_draft_tree_shape_and_branch1_equals_chain(self):
         """mtp_draft_tree returns [B, n] chain-major tokens, is
-        deterministic, and at branch=1 degenerates to mtp_draft exactly;
-        the host-side parent pointers match the static topology."""
+        deterministic, and at branch=1 is exactly the greedy recursion of
+        the MTP head; the host-side parent pointers match the static
+        topology."""
         from repro.models import model as M
+        from repro.models import transformer as T
         from repro.models.transformer import Runtime, mtp_chain_lengths
         from repro.serve.drafter import MTPDrafter
         cfg = ARCHS["deepseek-v3-671b"].reduced()
@@ -764,17 +668,23 @@ class TestMTPDrafter:
         assert a.shape == (3, 4)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         chain = M.mtp_draft_tree(params, cfg, h, tok, pos, 4, 1, rt)
-        lin = M.mtp_draft(params, cfg, h, tok, pos, 4, rt)
-        np.testing.assert_array_equal(np.asarray(chain), np.asarray(lin))
+        hh, tt, greedy = h.astype(jnp.float32), tok, []
+        for i in range(4):
+            lg, hh = T._mtp_cell(params, cfg, hh, tt, pos + i, rt)
+            tt = jnp.argmax(lg, -1).astype(jnp.int32)
+            greedy.append(tt)
+        np.testing.assert_array_equal(np.asarray(chain),
+                                      np.asarray(jnp.stack(greedy, axis=1)))
         # drafter wrapper exposes the matching draft-space parents:
         # chains of lengths [2, 2] -> [-1, 0, -1, 2]
-        d = MTPDrafter(cfg, rt, 4, tree_branch=2)
+        d = MTPDrafter(cfg, rt, 4, 2)
         assert mtp_chain_lengths(4, 2) == [2, 2]
         assert d.tree_parents == [-1, 0, -1, 2]
+        assert MTPDrafter(cfg, rt, 4, 1).tree_parents == chain_parents(4)
 
     def test_mtp_requires_mtp_head(self, gqa_setup):
         from repro.serve.engine import ContinuousBatchingEngine
         cfg, params = gqa_setup
         with pytest.raises(ValueError):
             ContinuousBatchingEngine(cfg, params, n_slots=1, max_len=32,
-                                     spec_k=2, drafter="mtp")
+                                     spec_tree=2, drafter="mtp")

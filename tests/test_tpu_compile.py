@@ -10,6 +10,7 @@ worker that runs this file loads the TPU library.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -101,23 +102,26 @@ def test_decode_attention_compiles(one_chip):
     _assert_mosaic(compiled)
 
 
-@pytest.mark.parametrize("tree", [False, True], ids=["linear", "tree"])
+@pytest.mark.parametrize("tree", [False, True], ids=["chain", "tree"])
 def test_verify_attention_compiles(one_chip, tree):
-    """T=8 verify windows (spec_k / spec_tree 7) over a pool with the
-    matching headroom rows — a ragged last key block."""
+    """T=8 verify windows (spec_tree 7) over a pool with the matching
+    headroom rows — a ragged last key block.  The chain case fixes the
+    ancestor masks to a chain's constants; the tree case takes them as an
+    operand."""
     cfg = registry.get("phi3-mini-3.8b")
     T = 8
     seq = MAX_LEN + KV.pool_headroom(spec_tree=T - 1)
     k_q, k_s = _pool(one_chip, seq, cfg.n_kv_heads, cfg.head_dim)
     q = _sds(one_chip, (SLOTS, T, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
     pos = _sds(one_chip, (SLOTS,), jnp.int32)
+    args = (q, k_q, k_s, k_q, k_s, pos)
     if tree:
-        anc = _sds(one_chip, (SLOTS, T), jnp.int32)
+        args += (_sds(one_chip, (SLOTS, T), jnp.int32),)
         fn = lambda *a: da_ops.verify_attention_tree(*a, interpret=False)
-        args = (q, k_q, k_s, k_q, k_s, pos, anc)
     else:
-        fn = lambda *a: da_ops.verify_attention(*a, interpret=False)
-        args = (q, k_q, k_s, k_q, k_s, pos)
+        chain = np.tile((1 << np.arange(1, T + 1)) - 1, (SLOTS, 1))
+        fn = lambda *a: da_ops.verify_attention_tree(
+            *a, chain.astype(np.int32), interpret=False)
     _assert_mosaic(jax.jit(fn).lower(*args).compile())
 
 
@@ -136,9 +140,13 @@ def test_lane_dense_attention_dots_are_exact(one_chip, name, T):
     scales = _sds(one_chip, (SLOTS, MAX_LEN, G, 1), jnp.float32)
     q = _sds(one_chip, (SLOTS, T, cfg.n_heads, D), jnp.bfloat16)
     pos = _sds(one_chip, (SLOTS,), jnp.int32)
-    fn = A.decode_attention_int8 if T == 1 else A.verify_attention_int8
+    if T == 1:
+        fn, args = A.decode_attention_int8, (pos,)
+    else:
+        fn = A.verify_attention_int8
+        args = (pos, _sds(one_chip, (SLOTS, T), jnp.int32))
     hlo = jax.jit(fn).lower(q, rows, scales, rows, scales,
-                            pos).compile().as_text()
+                            *args).compile().as_text()
     types = dict(re.findall(r"%([\w.\-]+) = (\w+)\[", hlo))
     dots = [(l, [types[n] for n in re.search(
                 r" (?:convolution|dot)\(%([\w.\-]+), %([\w.\-]+)\)",
